@@ -43,6 +43,17 @@ def synthetic_call_chain_hlo(n_comps: int = 150) -> str:
     )
 
 
+def force_host_devices(n: int) -> None:
+    """Give the CPU backend ``n`` devices, so that a multi-device path runs
+    in this one process. Only the CPU backend reads the flag, and only when
+    JAX starts: call it before anything imports jax. On a chip it changes
+    nothing (the chips are the devices)."""
+    flag = f"--xla_force_host_platform_device_count={n}"
+    if "jax" in sys.modules:
+        raise RuntimeError(f"{flag} must be set before jax is imported")
+    os.environ["XLA_FLAGS"] = f"{os.environ.get('XLA_FLAGS', '')} {flag}".strip()
+
+
 def save_result(name: str, payload: dict) -> None:
     os.makedirs(RESULTS_DIR, exist_ok=True)
     with open(os.path.join(RESULTS_DIR, f"{name}.json"), "w") as f:

@@ -34,7 +34,7 @@ from repro.train.train import TrainConfig, init_state, make_train_step
 
 def _setup(steps: int):
     cfg = smoke_config("tinyllama-1.1b")
-    mesh = make_host_mesh()
+    mesh = make_host_mesh(devices=jax.devices()[:1])
     tcfg = TrainConfig()
     st = init_state(cfg, tcfg, jax.random.PRNGKey(0))
     state = {"params": st.params, "opt_state": st.opt_state, "step": st.step}

@@ -119,6 +119,16 @@ def main() -> None:
     if "--check" in sys.argv[1:]:
         sys.exit(check())
 
+    # every benchmark runs in this process (none starts a child that would
+    # need the device); the scaling tables want four devices, which the CPU
+    # backend only gets if asked before jax is imported
+    root, src = _repo_paths()
+    for p in (src, root):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    from benchmarks.common import force_host_devices
+
+    force_host_devices(4)
     from benchmarks import (
         overhead,
         postprocessing,

@@ -1,84 +1,82 @@
-"""Paper Tables 6/7 — weak and strong scaling-efficiency tables from REAL
-multi-device executions of the mini-app.
+"""Paper Tables 6/7 — weak and strong scaling-efficiency tables from real
+executions of the training job at 1, 2 and 4 devices.
 
-Runs the reduced-config training job in subprocesses with 1/2/4 forced host
-devices (the only way to change the device count after jax init), collects
-the TALP JSONs, and builds both tables. The weak run scales the global batch
-with devices; the strong run keeps it fixed.
+Every device count runs in this one process, on a mesh over
+``jax.devices()[:n]``: a child process could not reach a chip this process
+already holds. On the CPU the devices are forced host devices, which must
+be set before jax is imported (running this file does that). The weak run
+scales the global batch with devices; the strong run keeps it fixed.
+
+    PYTHONPATH=src:. python benchmarks/scaling_tables.py
 """
 
 from __future__ import annotations
 
-import json
 import os
 import shutil
-import subprocess
-import sys
-import textwrap
 
-from benchmarks.common import csv_line, save_result
-from repro.core import build_table, render_text, scan
+from benchmarks.common import RESULTS_DIR, csv_line, force_host_devices, save_result
 
-_WORKER = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={ndev}"
-import sys
-sys.path.insert(0, {src!r})
-import jax
-from repro.configs import smoke_config
-from repro.data.pipeline import DataConfig, SyntheticLM
-from repro.launch.mesh import make_host_mesh
-from repro.train.loop import LoopConfig, TrainLoop
-from repro.train.train import TrainConfig
-
-cfg = smoke_config("tinyllama-1.1b")
-data = SyntheticLM(DataConfig(global_batch={batch}, seq_len=64,
-                              vocab=cfg.vocab, pad_fraction=0.1))
-loop = TrainLoop(cfg, make_host_mesh(), TrainConfig(), data,
-                 LoopConfig(steps={steps}, lb_sample_every=1,
-                            monitor_app_name="miniapp"))
-loop.run()
-run = loop.finalize_run()
-run.save({out!r})
-print("done", run.resources.label)
-"""
+DEVICE_COUNTS = (1, 2, 4)
 
 
-def _run_config(ndev: int, batch: int, steps: int, out: str) -> None:
-    code = _WORKER.format(
-        ndev=ndev, batch=batch, steps=steps, out=out,
-        src=os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src")),
-    )
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=900)
-    if r.returncode != 0:
-        raise RuntimeError(f"worker {ndev}dev failed:\n{r.stderr[-3000:]}")
+def measure(cfg, counts, *, global_batch, seq_len: int, steps: int,
+            out_dir: str, pad_fraction: float = 0.1) -> list[dict]:
+    """Train ``cfg`` for ``steps`` steps on a mesh over the first ``n``
+    devices, for each ``n`` in ``counts``, and save each run record as
+    ``out_dir/talp_1x{n}.json``. ``global_batch(n)`` is the batch at ``n``
+    devices. Returns per count: ``devices``, the step ``losses``, the ids of
+    the devices holding the final train state (``state_devices``) and the
+    run ``record``."""
+    import jax
+
+    from repro.launch.mesh import make_host_mesh
+    from repro.launch.train import build_loop
+
+    results = []
+    for n in counts:
+        devices = jax.devices()[:n]
+        if len(devices) < n:
+            raise RuntimeError(
+                f"{n} devices asked for, {len(jax.devices())} present"
+            )
+        loop = build_loop(
+            cfg, make_host_mesh(devices=devices), steps=steps,
+            global_batch=global_batch(n), seq_len=seq_len,
+            pad_fraction=pad_fraction, app_name="miniapp",
+        )
+        loop.run()
+        record = loop.finalize_run()
+        record.save(os.path.join(out_dir, f"talp_1x{n}.json"))
+        held = {
+            d.id for leaf in jax.tree_util.tree_leaves(loop.final_state)
+            for d in leaf.sharding.device_set
+        }
+        results.append({
+            "devices": n,
+            "losses": [h["loss"] for h in loop.metrics_history],
+            "state_devices": sorted(held),
+            "record": record,
+        })
+        # free this count's train state before the next count allocates
+        del loop
+    return results
 
 
-def run(root: str = "/tmp/repro_scaling", steps: int = 10) -> dict:
+def run(root: str = os.path.join(RESULTS_DIR, "scaling"), steps: int = 10) -> dict:
+    from repro.configs import smoke_config
+    from repro.core import build_table, render_text
+
+    cfg = smoke_config("tinyllama-1.1b")
     shutil.rmtree(root, ignore_errors=True)
-    for ndev in (1, 2, 4):
-        _run_config(ndev, batch=8, steps=steps,
-                    out=os.path.join(root, "strong_scaling", f"talp_1x{ndev}.json"))
-        _run_config(ndev, batch=4 * ndev, steps=steps,
-                    out=os.path.join(root, "weak_scaling", f"talp_1x{ndev}.json"))
-
-    tables = {}
-    text = {}
-    for exp in scan(root):
-        kind = "strong" if "strong" in exp.rel_path else "weak"
-        table = build_table(exp.runs)
-        tables[kind] = table
-        text[kind] = render_text(table)
-
-    result = {
-        "strong_mode_detected": tables["strong"].mode,
-        "weak_mode_detected": tables["weak"].mode,
-        "strong_table": tables["strong"].to_json(),
-        "weak_table": tables["weak"].to_json(),
-        "strong_text": text["strong"],
-        "weak_text": text["weak"],
-    }
+    result = {}
+    for kind, batch in (("strong", lambda n: 8), ("weak", lambda n: 4 * n)):
+        runs = measure(cfg, DEVICE_COUNTS, global_batch=batch, seq_len=64,
+                       steps=steps, out_dir=os.path.join(root, f"{kind}_scaling"))
+        table = build_table([r["record"] for r in runs])
+        result[f"{kind}_mode_detected"] = table.mode
+        result[f"{kind}_table"] = table.to_json()
+        result[f"{kind}_text"] = render_text(table)
     save_result("tables67_scaling", result)
     return result
 
@@ -98,4 +96,5 @@ def main() -> list[str]:
 
 
 if __name__ == "__main__":
+    force_host_devices(max(DEVICE_COUNTS))
     print("\n".join(main()))

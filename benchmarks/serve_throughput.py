@@ -101,7 +101,7 @@ def _build(cfg_name: str = "tinyllama-1.1b"):
     from repro.models import transformer as T
 
     cfg = smoke_config(cfg_name)
-    mesh = make_host_mesh()
+    mesh = make_host_mesh(devices=jax.devices()[:1])
     params = init_params(T.model_params(cfg), jax.random.PRNGKey(0),
                          cfg.param_dtype)
     return cfg, mesh, params

@@ -16,60 +16,58 @@ Workflow, listings 5/6) end to end on the mini-app:
 import json
 import os
 import shutil
-import subprocess
 import sys
+import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.core.pages import main as talp_cli
+# Both resource configurations run in this one process, on meshes over
+# jax.devices()[:n] (a child process could not reach a chip this process
+# holds). The CPU backend only gets two devices if asked before jax is
+# imported; on a chip the flag changes nothing.
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=2"
+).strip()
+
+import jax  # noqa: E402
+
+from repro.configs import smoke_config  # noqa: E402
+from repro.core.pages import main as talp_cli  # noqa: E402
+from repro.launch.mesh import make_host_mesh  # noqa: E402
+from repro.launch.train import build_loop  # noqa: E402
 
 ROOT = "results/ci_workflow"
-SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
-
-_JOB = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={ndev}"
-import sys; sys.path.insert(0, {src!r})
-import time
-from repro.configs import smoke_config
-from repro.data.pipeline import DataConfig, SyntheticLM
-from repro.launch.mesh import make_host_mesh
-from repro.train.loop import LoopConfig, TrainLoop
-from repro.train.train import TrainConfig
-
-cfg = smoke_config("tinyllama-1.1b")
-data = SyntheticLM(DataConfig(global_batch=4, seq_len=64, vocab=cfg.vocab))
-loop = TrainLoop(cfg, make_host_mesh(), TrainConfig(), data,
-                 LoopConfig(steps=6, lb_sample_every=1, monitor_app_name="miniapp"))
-if {slow}:  # this commit has a host-stall bug
-    _obs = loop.session.observe_step
-    def slow_obs(*a, **k):
-        time.sleep(0.03)
-        return _obs(*a, **k)
-    loop.session.observe_step = slow_obs
-loop.run()
-run = loop.finalize_run()
-if run is None:
-    raise SystemExit("ci_workflow needs collection enabled — unset TALP_ENABLE=0")
-run.metadata.update({{"git_commit_short": {commit!r},
-                      "git_commit_timestamp": {ts!r}}})
-run.timestamp = {ts!r}
-run.save({out!r})
-print("performance job done:", run.resources.label)
-"""
 
 
 def performance_job(commit: str, ts: str, slow: bool, pipeline_dir: str):
     """The paper's matrix job: one run per resource configuration."""
     for ndev in (1, 2):
-        out = os.path.join(pipeline_dir, "talp", "salpha", "strong_scaling",
-                           f"talp_1x{ndev}_{commit}.json")
-        code = _JOB.format(ndev=ndev, src=SRC, commit=commit, ts=ts, out=out,
-                           slow="True" if slow else "False")
-        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                           text=True, timeout=600)
-        if r.returncode != 0:
-            raise RuntimeError(r.stderr[-2000:])
+        loop = build_loop(
+            smoke_config("tinyllama-1.1b"),
+            make_host_mesh(devices=jax.devices()[:ndev]),
+            steps=6, global_batch=4, seq_len=64, pad_fraction=0.0,
+            app_name="miniapp",
+        )
+        if slow:  # this commit has a host-stall bug
+            observe = loop.session.observe_step
+
+            def slow_observe(*a, _observe=observe, **k):
+                time.sleep(0.03)
+                return _observe(*a, **k)
+
+            loop.session.observe_step = slow_observe
+        loop.run()
+        run = loop.finalize_run()
+        if run is None:
+            raise SystemExit(
+                "ci_workflow needs collection enabled — unset TALP_ENABLE=0"
+            )
+        run.metadata.update({"git_commit_short": commit,
+                             "git_commit_timestamp": ts})
+        run.timestamp = ts
+        run.save(os.path.join(pipeline_dir, "talp", "salpha", "strong_scaling",
+                              f"talp_1x{ndev}_{commit}.json"))
+        print("performance job done:", run.resources.label)
 
 
 def main():

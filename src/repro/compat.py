@@ -1,115 +1,46 @@
-"""JAX version-portability layer.
+"""The one place this repo touches JAX APIs that move between releases.
 
-Every version-gated JAX attribute access in this repo lives HERE and only
-here. The CI host pins whatever JAX it pins (0.4.x today); the framework
-must run unmodified on old and new releases alike, because a performance
-monitor that crashes on the installed toolchain measures nothing
-(ISSUE 1 / ROADMAP "as fast as the hardware allows").
+Written against the installed JAX (0.9). Every access to an attribute that
+has changed shape across releases — the mesh constructor's ``axis_types``,
+the ambient-mesh context, the compiled-executable accessors and the
+experimental Pallas namespaces — lives HERE, so that the next API move is a
+one-file change. The tier-1 suite greps for violations
+(tests/test_compat.py).
 
-Shimmed surfaces, each feature-detected at import time (not version-string
-compared — point releases backport features):
-
-* ``make_mesh``        — ``jax.make_mesh`` grew an ``axis_types=`` kwarg and
-                         ``jax.sharding.AxisType`` in 0.5+; 0.4.x has
-                         neither, and very old releases lack ``jax.make_mesh``
-                         entirely (fall back to ``mesh_utils``).
-* ``use_mesh``         — the ambient-mesh context: ``jax.sharding.use_mesh``
-                         (0.5+) or the classic ``with mesh:`` context
-                         manager (0.4.x).
-* ``named_sharding``   — trivial today, but isolates the constructor import.
+* ``make_mesh``        — ``jax.make_mesh`` with every axis marked
+                         ``AxisType.Auto`` (the GSPMD behaviour this
+                         codebase is written against).
+* ``use_mesh``         — ``jax.set_mesh`` used as a context manager.
+* ``named_sharding``   — NamedSharding from a PartitionSpec or axis tuple.
 * ``device_put``       — placement with an optional sharding.
 * ``cost_analysis`` /
-  ``memory_stats``     — ``compiled.cost_analysis()`` returned a one-element
-                         list in old JAX and a dict in new JAX;
-                         ``memory_analysis()`` raises on some backends.
+  ``memory_stats``     — flat str->float views of the compiled executable's
+                         analyses (some backends raise; those give ``{}``).
 * ``compiled_text``    — optimized-HLO text of a compiled executable.
 * ``pallas`` /
-  ``pallas_tpu``       — the Pallas kernel namespaces live under the
-                         *experimental* tree, whose layout and availability
-                         move between releases (and CPU-only builds may lack
-                         the TPU submodule). Kernel code imports the modules
-                         through these accessors; everything else must stay
-                         behind the ``repro.kernels`` ops wrappers, whose
-                         ``impl="reference"`` path needs no Pallas at all.
-
-Policy (recorded for future PRs): new code MUST import these helpers
-instead of touching ``jax.sharding.AxisType``-style attributes directly;
-the tier-1 suite greps for violations (tests/test_compat.py).
+  ``pallas_tpu``       — the Pallas kernel namespaces (``pl``/``pltpu``).
+                         Kernel code imports them through these accessors;
+                         everything else stays behind the ``repro.kernels``
+                         ops wrappers.
 """
 
 from __future__ import annotations
 
-import contextlib
-import inspect
 from typing import Any, Sequence
 
 import jax
-
-# ---------------------------------------------------------------------------
-# feature detection (once, at import)
-# ---------------------------------------------------------------------------
-
-#: jax.sharding.AxisType.Auto on releases that have it, else None.
-AXIS_TYPE_AUTO = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-
-HAS_AXIS_TYPES = AXIS_TYPE_AUTO is not None
-
-
-def _make_mesh_accepts_axis_types() -> bool:
-    fn = getattr(jax, "make_mesh", None)
-    if fn is None:
-        return False
-    try:
-        return "axis_types" in inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        return False
-
-
-MAKE_MESH_HAS_AXIS_TYPES = _make_mesh_accepts_axis_types()
-
-try:  # experimental namespace: presence and layout are version-dependent
-    from jax.experimental import pallas as _pallas_mod
-except Exception:  # pragma: no cover - exercised on builds without Pallas
-    _pallas_mod = None
-try:
-    from jax.experimental.pallas import tpu as _pallas_tpu_mod
-except Exception:  # pragma: no cover - e.g. minimal CPU wheels
-    _pallas_tpu_mod = None
-
-HAS_PALLAS = _pallas_mod is not None
-HAS_PALLAS_TPU = _pallas_tpu_mod is not None
+from jax.experimental import pallas as _pallas_mod
+from jax.experimental.pallas import tpu as _pallas_tpu_mod
 
 
 def pallas():
-    """The Pallas core module (``pl`` by convention), feature-detected."""
-    if _pallas_mod is None:
-        raise ImportError(
-            "this JAX build has no Pallas; use the kernels' impl='reference' "
-            "path (pure jnp oracles) instead of the Pallas kernels"
-        )
+    """The Pallas core module (``pl`` by convention)."""
     return _pallas_mod
 
 
 def pallas_tpu():
-    """The Pallas TPU module (``pltpu`` by convention), feature-detected."""
-    if _pallas_tpu_mod is None:
-        raise ImportError(
-            "this JAX build has no Pallas TPU support; use the kernels' "
-            "impl='reference' path instead"
-        )
+    """The Pallas TPU module (``pltpu`` by convention)."""
     return _pallas_tpu_mod
-
-
-def jax_version() -> tuple[int, ...]:
-    """Best-effort numeric version tuple (diagnostics only — never use for
-    feature gating; feature-detect instead)."""
-    out = []
-    for part in jax.__version__.split("."):
-        digits = "".join(ch for ch in part if ch.isdigit())
-        if not digits:
-            break
-        out.append(int(digits))
-    return tuple(out)
 
 
 # ---------------------------------------------------------------------------
@@ -123,50 +54,18 @@ def make_mesh(
     *,
     devices: Sequence[Any] | None = None,
 ):
-    """``jax.make_mesh`` portable across the axis_types API change.
-
-    On releases with ``AxisType`` every axis is marked Auto (the classic
-    GSPMD behavior this codebase is written against); on older releases
-    Auto is the only behavior, so the kwarg is simply omitted.
-    """
-    fn = getattr(jax, "make_mesh", None)
-    if fn is not None:
-        if MAKE_MESH_HAS_AXIS_TYPES and HAS_AXIS_TYPES:
-            return fn(
-                tuple(axis_shapes), tuple(axis_names),
-                axis_types=(AXIS_TYPE_AUTO,) * len(tuple(axis_names)),
-                **({"devices": devices} if devices is not None else {}),
-            )
-        return fn(
-            tuple(axis_shapes), tuple(axis_names),
-            **({"devices": devices} if devices is not None else {}),
-        )
-    # ancient JAX: no jax.make_mesh at all
-    from jax.experimental import mesh_utils
-
-    devs = mesh_utils.create_device_mesh(
-        tuple(axis_shapes), devices=list(devices) if devices is not None else None
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+        devices=devices,
     )
-    return jax.sharding.Mesh(devs, tuple(axis_names))
 
 
-@contextlib.contextmanager
 def use_mesh(mesh):
-    """Enter ``mesh`` as the ambient mesh, whatever this JAX calls that.
-
-    ``jax.sharding.use_mesh`` (0.5+) when present, else the classic
-    ``with mesh:`` context (0.4.x). ``jax.set_mesh`` is deliberately NOT
-    probed: on releases where it is a plain global setter rather than a
-    context manager, merely calling it to find out would leak the ambient
-    mesh past this block.
-    """
-    factory = getattr(jax.sharding, "use_mesh", None)
-    if factory is not None:
-        with factory(mesh):
-            yield mesh
-        return
-    with mesh:
-        yield mesh
+    """Enter ``mesh`` as the ambient mesh (``with compat.use_mesh(m):``)."""
+    return jax.set_mesh(mesh)
 
 
 def named_sharding(mesh, spec):
@@ -198,28 +97,18 @@ def _is_num(v) -> bool:
 
 
 def cost_analysis(compiled) -> dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across JAX versions.
-
-    Old JAX returns ``[{...}]`` (one dict per partition), new JAX a plain
-    dict; some backends raise. Always returns a (possibly empty) flat
-    str->float dict.
-    """
+    """``compiled.cost_analysis()`` as a flat str->float dict (``{}`` on a
+    backend that cannot produce one)."""
     try:
         ca = compiled.cost_analysis()
     except Exception:
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    try:
-        items = dict(ca).items()
-    except (TypeError, ValueError):
-        return {}
-    return {str(k): float(v) for k, v in items if _is_num(v)}
+    return {str(k): float(v) for k, v in dict(ca or {}).items() if _is_num(v)}
 
 
 def memory_stats(compiled) -> dict[str, float]:
-    """Normalize ``compiled.memory_analysis()`` (absent/raising on some
-    backends) to a flat str->float dict of the stable field names."""
+    """``compiled.memory_analysis()`` (absent/raising on some backends) as a
+    flat str->float dict of the stable field names."""
     try:
         ms = compiled.memory_analysis()
     except Exception:

@@ -1,13 +1,18 @@
-"""Hardware target models.
+"""Hardware specs: the peaks every roofline term, modeled communication
+time and "hardware counter" analogue (the PAPI replacement, see DESIGN.md
+§3) is derived against.
 
-The container runs on CPU; TPU v5e is the *target*. All roofline terms,
-modeled communication times and "hardware counter" analogues (the PAPI
-replacement, see DESIGN.md §3) are derived against these specs.
+On a TPU the spec is looked up by the chip's ``device_kind``
+(``SPECS_BY_KIND``); a kind missing from the table is an error, never a
+default. Off the chip (CPU tests, the 512-device dry-run) nothing is
+measured on a chip, so the factors are computed against ``DEFAULT_TARGET``
+and the record says so (``hardware_basis = "modeled"``).
 
-Numbers come from the task spec: 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI. DCN bandwidth is an estimate for pod-to-pod traffic and
-only enters the multi-pod communication model, never the required roofline
-table (which is single-pod / ICI only).
+v5e peaks: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16 per
+chip, 16 GB HBM at 819 GB/s; ~50 GB/s/link ICI. DCN bandwidth is an
+estimate for pod-to-pod traffic and only enters the multi-pod
+communication model, never the required roofline table (which is
+single-pod / ICI only).
 """
 
 from __future__ import annotations
@@ -55,6 +60,46 @@ TPU_V5P = ChipSpec(
 
 TARGETS = {s.name: s for s in (TPU_V5E, TPU_V5P)}
 DEFAULT_TARGET = TPU_V5E
+
+
+#: ``device.device_kind`` as JAX reports it (the names JAX's own
+#: ``mesh_utils`` knows) -> that chip's spec. A v5e reports "TPU v5 lite".
+SPECS_BY_KIND = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+    "TPU v5p": TPU_V5P,
+}
+
+
+def spec_for_kind(kind: str) -> ChipSpec:
+    """The spec of a TPU by its ``device_kind``; an unknown kind raises."""
+    try:
+        return SPECS_BY_KIND[kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak table for device_kind {kind!r}; known: "
+            f"{sorted(SPECS_BY_KIND)} (add it to SPECS_BY_KIND with its source)"
+        ) from None
+
+
+def resolve_target(name: str | None) -> tuple[str, str]:
+    """``(target name, basis)`` for a run record.
+
+    An explicit ``name`` is used as given (basis ``"configured"``). Without
+    one, the attached device decides: a TPU is looked up by its
+    ``device_kind`` (basis ``"device_kind=<kind>"``); any other platform
+    measures no chip, so the default target is a modeled one (basis
+    ``"modeled"``).
+    """
+    if name is not None:
+        get_target(name)
+        return name, "configured"
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        return spec_for_kind(dev.device_kind).name, f"device_kind={dev.device_kind}"
+    return DEFAULT_TARGET.name, "modeled"
 
 
 def get_target(name: str | None) -> ChipSpec:

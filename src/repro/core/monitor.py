@@ -35,6 +35,7 @@ from typing import Any, Callable
 import numpy as np
 
 from repro.core import factors as _factors
+from repro.core.hardware import resolve_target
 from repro.core.profile import StepProfile
 from repro.core.records import (
     DEFAULT_TOP_COMPUTATIONS,
@@ -57,7 +58,9 @@ def _block(tree) -> None:
 @dataclasses.dataclass
 class MonitorConfig:
     app_name: str = "app"
-    hardware: str = "tpu_v5e"
+    # peak table the factors use; None = the attached chip by device_kind
+    # (modeled v5e off the chip), see core.hardware.resolve_target
+    hardware: str | None = None
     sync_regions: bool = True
     lb_sample_every: int = 10
     overlap_fraction: float = 0.0  # modeled compute/comm overlap for comm-eff
@@ -352,17 +355,18 @@ class TalpMonitor:
                     self.config.top_computations,
                 )
 
+        hardware, basis = resolve_target(self.config.hardware)
         run = RunRecord(
             app_name=self.config.app_name,
             resources=self.resources,
             timestamp=_dt.datetime.now(_dt.timezone.utc).isoformat(),
             regions=regions,
-            metadata=dict(self.metadata),
-            hardware=self.config.hardware,
+            metadata={**self.metadata, "hardware_basis": basis},
+            hardware=hardware,
         )
         for r in run.regions.values():
             r.pop = _factors.compute_pop(
-                r, run.resources, self.config.hardware,
+                r, run.resources, hardware,
                 overlap_fraction=self.config.overlap_fraction,
             )
         return run

@@ -57,8 +57,8 @@ def _flash_kernel(
 
     def body(ki, carry):
         o, m, l = carry
-        k = pl.load(k_ref, (pl.dslice(ki * block_kv, block_kv), pl.dslice(None)))
-        v = pl.load(v_ref, (pl.dslice(ki * block_kv, block_kv), pl.dslice(None)))
+        k = k_ref[pl.ds(ki * block_kv, block_kv), :]
+        v = v_ref[pl.ds(ki * block_kv, block_kv), :]
         s = q @ k.astype(jnp.float32).T  # (block_q, block_kv)
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
@@ -92,7 +92,7 @@ def flash_attention_pallas(
     causal: bool = True, window: int | None = None,
     softcap: float | None = None,
     block_q: int = 128, block_kv: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """q: (B,Sq,Hq,D); k,v: (B,Sk,Hkv,D). Returns (B,Sq,Hq,D).
 

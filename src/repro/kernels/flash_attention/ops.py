@@ -1,9 +1,8 @@
 """jit'd public wrapper for the flash attention kernel.
 
 ``flash_attention(...)`` routes to the Pallas kernel on TPU (or in
-interpret mode when asked) and to the pure-jnp oracle otherwise. The model
-stack can swap its chunked-scan attention for this op via
-``ModelConfig.use_pallas`` on real hardware.
+interpret mode when asked) and to the pure-jnp oracle otherwise. No model
+path calls it yet.
 """
 
 from __future__ import annotations
@@ -12,14 +11,8 @@ import functools
 
 import jax
 
+from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_reference
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 @functools.partial(
@@ -36,15 +29,11 @@ def flash_attention(
     impl: str = "auto",  # auto | pallas | interpret | reference
 ):
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "reference"
+        impl = "pallas" if jax.default_backend() == "tpu" else "reference"
     if impl == "reference":
         return attention_reference(
             q, k, v, causal=causal, window=window, softcap=softcap
         )
-    # lazy: the kernel module needs Pallas at import time, and the
-    # reference path must stay usable on builds without it
-    from repro.kernels.flash_attention.kernel import flash_attention_pallas
-
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window, softcap=softcap,
         block_q=block_q, block_kv=block_kv,

@@ -162,7 +162,7 @@ def _paged_prefill_kernel(
 def paged_prefill_attention_pallas(
     q, k_pages, v_pages, block_tables, *, q_positions, cache_len,
     causal: bool = True, window: int | None = None,
-    softcap: float | None = None, interpret: bool = True,
+    softcap: float | None = None, interpret: bool = False,
 ):
     """q: (B,C,Hq,D) one prefill chunk per row; k_pages/v_pages:
     (P, page, Hkv, D); block_tables: (B, n_logical) int32 (``-1`` =
@@ -242,7 +242,7 @@ def paged_prefill_attention_pallas(
 def paged_attention_pallas(
     q, k_pages, v_pages, block_tables, *, q_position, cache_len,
     window: int | None = None, softcap: float | None = None,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """q: (B,1,Hq,D); k_pages/v_pages: (P, page, Hkv, D); block_tables:
     (B, n_logical) int32 (``-1`` = unallocated). Returns (B,1,Hq,D).

@@ -14,17 +14,14 @@ import functools
 
 import jax
 
+from repro.kernels.paged_attention.kernel import (
+    paged_attention_pallas,
+    paged_prefill_attention_pallas,
+)
 from repro.kernels.paged_attention.ref import (
     paged_attention_reference,
     paged_prefill_attention_reference,
 )
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 @functools.partial(
@@ -44,17 +41,13 @@ def paged_attention(
     or (B,). Returns (B,1,Hq,D) in q.dtype.
     """
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "reference"
+        impl = "pallas" if jax.default_backend() == "tpu" else "reference"
     if impl == "reference":
         return paged_attention_reference(
             q, k_pages, v_pages, block_tables,
             q_position=q_position, cache_len=cache_len,
             window=window, softcap=softcap,
         )
-    # lazy: the kernel module needs Pallas at import time, and the
-    # reference path must stay usable on builds without it
-    from repro.kernels.paged_attention.kernel import paged_attention_pallas
-
     return paged_attention_pallas(
         q, k_pages, v_pages, block_tables,
         q_position=q_position, cache_len=cache_len,
@@ -89,7 +82,7 @@ def paged_prefill_attention(
     kernel streams pages and ignores them. Returns (B,C,Hq,D) in q.dtype.
     """
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "reference"
+        impl = "pallas" if jax.default_backend() == "tpu" else "reference"
     if impl == "reference":
         return paged_prefill_attention_reference(
             q, k_pages, v_pages, block_tables,
@@ -97,10 +90,6 @@ def paged_prefill_attention(
             causal=causal, window=window, softcap=softcap,
             q_chunk=q_chunk, kv_chunk=kv_chunk,
         )
-    from repro.kernels.paged_attention.kernel import (
-        paged_prefill_attention_pallas,
-    )
-
     return paged_prefill_attention_pallas(
         q, k_pages, v_pages, block_tables,
         q_positions=q_positions, cache_len=cache_len,

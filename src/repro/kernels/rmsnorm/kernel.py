@@ -33,7 +33,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float, true_d: int,
 
 def rmsnorm_pallas(
     x, scale, eps: float = 1e-6, zero_centered: bool = False,
-    block_rows: int = 256, interpret: bool = True,
+    block_rows: int = 256, interpret: bool = False,
 ):
     """x: (..., d); scale: (d,). Returns same shape/dtype as x."""
     orig_shape = x.shape

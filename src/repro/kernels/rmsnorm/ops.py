@@ -6,6 +6,7 @@ import functools
 
 import jax
 
+from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
 from repro.kernels.rmsnorm.ref import rmsnorm_reference
 
 
@@ -20,10 +21,6 @@ def rmsnorm(
         impl = "pallas" if jax.default_backend() == "tpu" else "reference"
     if impl == "reference":
         return rmsnorm_reference(x, scale, eps, zero_centered)
-    # lazy: the kernel module needs Pallas at import time, and the
-    # reference path must stay usable on builds without it
-    from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
-
     return rmsnorm_pallas(
         x, scale, eps, zero_centered, block_rows=block_rows,
         interpret=(impl == "interpret"),

@@ -4,8 +4,8 @@
 importing this module does not touch jax device initialization — the
 dry-run must set XLA_FLAGS before any device query.
 
-All mesh construction goes through :mod:`repro.compat` so the same code
-runs on JAX releases with and without the ``axis_types``/``AxisType`` API.
+All mesh construction goes through :mod:`repro.compat`, the one module
+that touches the mesh API.
 """
 
 from __future__ import annotations
@@ -26,12 +26,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     return compat.make_mesh(shape, axes)
 
 
-def make_host_mesh(model: int | None = None):
-    """Small mesh over the locally available devices (tests/examples)."""
-    n = len(jax.devices())
+def make_host_mesh(model: int | None = None, devices=None):
+    """(data, model) mesh over ``devices`` (default: every local device).
+
+    A device subset (``jax.devices()[:n]``) lets one process measure the
+    same job at several device counts — the strong-scaling table — without
+    a child process per count.
+    """
+    devices = list(jax.devices() if devices is None else devices)
     model = model or 1
-    data = n // model
-    return compat.make_mesh((data, model), ("data", "model"))
+    return compat.make_mesh(
+        (len(devices) // model, model), ("data", "model"), devices=devices
+    )
 
 
 def devices_per_pod(mesh) -> int | None:

@@ -128,12 +128,14 @@ def main(argv=None) -> int:
     from repro import compat
     from repro.configs import get_config, smoke_config
     from repro.core import ResourceConfig
+    from repro.launch.cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.layers.common import init_params
     from repro.models import transformer as T
     from repro.serve.serve import BatchScheduler, ServeConfig
     from repro.session import PerfSession, SessionConfig
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.encoder_only:
         raise SystemExit(f"{args.arch} is encoder-only: no decode serving")

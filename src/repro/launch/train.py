@@ -3,17 +3,42 @@
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
         --smoke --steps 10 --ckpt-dir /tmp/ckpt --talp-out talp/case/strong
 
-On a real TPU slice this runs under the standard multi-host JAX bootstrap
-(jax.distributed.initialize is called automatically when the TPU env vars
-are present); on this container use --smoke (reduced config, host devices).
-Re-running with the same --ckpt-dir resumes from the latest checkpoint
-(crash = restart the process; the data pipeline is step-indexed).
+Runs on the devices of this one host (``--smoke`` gives a reduced config
+that runs on the CPU). Re-running with the same --ckpt-dir resumes from the
+latest checkpoint (crash = restart the process; the data pipeline is
+step-indexed).
 """
 
 from __future__ import annotations
 
 import argparse
-import os
+
+
+def build_loop(cfg, mesh, *, steps: int, global_batch: int, seq_len: int,
+               accum: int = 1, lr: float = 3e-4, pad_fraction: float = 0.05,
+               ckpt_dir: str = "", ckpt_every: int = 0,
+               app_name: str | None = None):
+    """The ``TrainLoop`` this launcher runs: synthetic LM data, AdamW, the
+    monitored train step. Shared with ``chip_smoke.py`` and the scaling
+    benchmark so that they drive the same objects."""
+    from repro.data.pipeline import DataConfig, SyntheticLM
+    from repro.optim import AdamWConfig
+    from repro.train.loop import LoopConfig, TrainLoop
+    from repro.train.train import TrainConfig
+
+    data = SyntheticLM(DataConfig(
+        global_batch=global_batch, seq_len=seq_len,
+        vocab=cfg.vocab, accum_steps=accum, pad_fraction=pad_fraction,
+        frontend_tokens=cfg.n_frontend_tokens if cfg.frontend == "vlm" else 0,
+        d_model=cfg.d_model,
+    ))
+    return TrainLoop(
+        cfg, mesh,
+        TrainConfig(optimizer=AdamWConfig(lr=lr), total_steps=steps),
+        data,
+        LoopConfig(steps=steps, ckpt_every=ckpt_every, ckpt_dir=ckpt_dir,
+                   lb_sample_every=1, monitor_app_name=app_name or cfg.name),
+    )
 
 
 def main(argv=None) -> int:
@@ -36,21 +61,11 @@ def main(argv=None) -> int:
                     help="model-parallel degree of the host mesh")
     args = ap.parse_args(argv)
 
-    try:  # multi-host TPU bootstrap (no-op on CPU)
-        import jax
-
-        if os.environ.get("TPU_WORKER_HOSTNAMES"):
-            jax.distributed.initialize()
-    except Exception as e:  # pragma: no cover
-        print(f"[launch] distributed init skipped: {e}")
-
     from repro.configs import get_config, optimized_config, smoke_config
-    from repro.data.pipeline import DataConfig, SyntheticLM
+    from repro.launch.cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
-    from repro.optim import AdamWConfig
-    from repro.train.loop import LoopConfig, TrainLoop
-    from repro.train.train import TrainConfig
 
+    enable_compile_cache()
     if args.smoke:
         cfg = smoke_config(args.arch)
     elif args.optimized:
@@ -58,19 +73,11 @@ def main(argv=None) -> int:
     else:
         cfg = get_config(args.arch)
 
-    data = SyntheticLM(DataConfig(
+    loop = build_loop(
+        cfg, make_host_mesh(model=args.model_axis), steps=args.steps,
         global_batch=args.global_batch, seq_len=args.seq_len,
-        vocab=cfg.vocab, accum_steps=args.accum, pad_fraction=0.05,
-        frontend_tokens=cfg.n_frontend_tokens if cfg.frontend == "vlm" else 0,
-        d_model=cfg.d_model,
-    ))
-    loop = TrainLoop(
-        cfg, make_host_mesh(model=args.model_axis),
-        TrainConfig(optimizer=AdamWConfig(lr=args.lr), total_steps=args.steps),
-        data,
-        LoopConfig(steps=args.steps, ckpt_every=args.ckpt_every,
-                   ckpt_dir=args.ckpt_dir, lb_sample_every=1,
-                   monitor_app_name=args.arch),
+        accum=args.accum, lr=args.lr, ckpt_dir=args.ckpt_dir,
+        ckpt_every=args.ckpt_every, app_name=args.arch,
     )
     loop.run()
     h = loop.metrics_history

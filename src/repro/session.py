@@ -44,6 +44,7 @@ import tempfile
 import time
 from typing import Any, Callable, Protocol, runtime_checkable
 
+from repro.core.hardware import resolve_target
 from repro.core.records import (
     DEFAULT_TOP_COMPUTATIONS,
     ResourceConfig,
@@ -86,7 +87,7 @@ class SessionConfig:
 
     app_name: str = "app"
     backend: str = "null"  # "monitor" | "tracer" | "null"
-    hardware: str = "tpu_v5e"
+    hardware: str | None = None  # None = the attached chip (core.hardware)
     sync_regions: bool = True
     lb_sample_every: int = 10
     overlap_fraction: float = 0.0
@@ -256,10 +257,12 @@ class TracerCollector:
         # post_process knows nothing of session-level knobs; re-derive the
         # factors under the session's hardware/overlap model so both
         # backends answer through one contract
-        run.hardware = self._config.hardware
+        run.hardware, run.metadata["hardware_basis"] = resolve_target(
+            self._config.hardware
+        )
         for reg in run.regions.values():
             reg.pop = _factors.compute_pop(
-                reg, run.resources, self._config.hardware,
+                reg, run.resources, run.hardware,
                 overlap_fraction=self._config.overlap_fraction,
             )
         return run

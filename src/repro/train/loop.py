@@ -149,6 +149,12 @@ class TrainLoop:
         compiled, call = compile_train_step(
             self.cfg, self.mesh, self.tcfg, state_tree, example
         )
+        # lay the state out as the step takes it, here: init_state builds it
+        # on one device, and the first step would otherwise reshard it inside
+        # the train_step region (seconds on a multi-chip mesh)
+        state_tree = jax.block_until_ready(
+            jax.device_put(state_tree, compiled.input_shardings[0][0])
+        )
         from repro.models.flops import train_step_model_flops
 
         step_fn = self.session.wrap_step(

@@ -1,7 +1,6 @@
-"""JAX version-compat layer: both sides of every feature-detected shim are
-exercised by monkeypatching the detection flags — the suite stays meaningful
-no matter which JAX the CI host pins — plus the repo-wide policy check that
-version-gated attribute access lives only in compat.py."""
+"""JAX compat layer: each helper against the installed JAX, plus the
+repo-wide policy check that version-gated attribute access lives only in
+compat.py."""
 
 import contextlib
 import pathlib
@@ -14,7 +13,7 @@ from repro import compat
 
 
 # ---------------------------------------------------------------------------
-# make_mesh across API generations
+# mesh construction and the ambient mesh
 # ---------------------------------------------------------------------------
 
 
@@ -22,130 +21,51 @@ def _mesh_fingerprint(mesh):
     return (tuple(mesh.axis_names), tuple(mesh.devices.shape))
 
 
-def test_make_mesh_old_api_omits_axis_types(monkeypatch):
-    real = jax.make_mesh
-    seen = {}
-
-    def fake(shape, names, **kw):
-        seen["kw"] = dict(kw)
-        return real(shape, names, **kw)
-
-    monkeypatch.setattr(jax, "make_mesh", fake)
-    monkeypatch.setattr(compat, "MAKE_MESH_HAS_AXIS_TYPES", False)
-    monkeypatch.setattr(compat, "HAS_AXIS_TYPES", False)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    assert "axis_types" not in seen["kw"]
-    assert _mesh_fingerprint(mesh) == (("data", "model"), (1, 1))
-
-
-def test_make_mesh_new_api_passes_auto_axis_types(monkeypatch):
-    real = jax.make_mesh
-    sentinel = object()
-    seen = {}
-
-    def fake(shape, names, *, axis_types=None, **kw):
-        seen["axis_types"] = axis_types
-        return real(shape, names, **kw)
-
-    monkeypatch.setattr(jax, "make_mesh", fake)
-    monkeypatch.setattr(compat, "MAKE_MESH_HAS_AXIS_TYPES", True)
-    monkeypatch.setattr(compat, "HAS_AXIS_TYPES", True)
-    monkeypatch.setattr(compat, "AXIS_TYPE_AUTO", sentinel)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    assert seen["axis_types"] == (sentinel, sentinel)
-    assert _mesh_fingerprint(mesh) == (("data", "model"), (1, 1))
-
-
-def test_make_mesh_old_and_new_paths_build_identical_mesh(monkeypatch):
-    real = jax.make_mesh
-
-    monkeypatch.setattr(compat, "MAKE_MESH_HAS_AXIS_TYPES", False)
-    monkeypatch.setattr(compat, "HAS_AXIS_TYPES", False)
-    old = compat.make_mesh((1, 1), ("data", "model"))
-
-    monkeypatch.setattr(jax, "make_mesh",
-                        lambda shape, names, *, axis_types=None, **kw: real(shape, names, **kw))
-    monkeypatch.setattr(compat, "MAKE_MESH_HAS_AXIS_TYPES", True)
-    monkeypatch.setattr(compat, "HAS_AXIS_TYPES", True)
-    monkeypatch.setattr(compat, "AXIS_TYPE_AUTO", object())
-    new = compat.make_mesh((1, 1), ("data", "model"))
-
-    assert _mesh_fingerprint(old) == _mesh_fingerprint(new)
-    assert [d.id for d in old.devices.flat] == [d.id for d in new.devices.flat]
-
-
-def test_make_mesh_without_jax_make_mesh_falls_back(monkeypatch):
-    monkeypatch.delattr(jax, "make_mesh")
+def test_make_mesh_new_api_passes_auto_axis_types():
     mesh = compat.make_mesh((1, 1), ("data", "model"))
     assert _mesh_fingerprint(mesh) == (("data", "model"), (1, 1))
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * 2
 
 
-# ---------------------------------------------------------------------------
-# use_mesh
-# ---------------------------------------------------------------------------
-
-
-def test_use_mesh_falls_back_to_mesh_context(monkeypatch):
-    monkeypatch.setattr(jax.sharding, "use_mesh", None, raising=False)
-    monkeypatch.setattr(jax, "set_mesh", None, raising=False)
-    events = []
-
-    class FakeMesh:
-        def __enter__(self):
-            events.append("enter")
-            return self
-
-        def __exit__(self, *exc):
-            events.append("exit")
-            return False
-
-    with compat.use_mesh(FakeMesh()):
-        assert events == ["enter"]
-    assert events == ["enter", "exit"]
+def test_make_mesh_over_a_device_subset():
+    dev = jax.devices()[:1]
+    mesh = compat.make_mesh((1, 1), ("data", "model"), devices=dev)
+    assert [d.id for d in mesh.devices.flat] == [dev[0].id]
 
 
 def test_use_mesh_prefers_new_api(monkeypatch):
     used = []
 
     @contextlib.contextmanager
-    def fake_use_mesh(mesh):
+    def fake_set_mesh(mesh):
         used.append(mesh)
         yield mesh
 
-    monkeypatch.setattr(jax.sharding, "use_mesh", fake_use_mesh, raising=False)
+    monkeypatch.setattr(jax, "set_mesh", fake_set_mesh)
     mesh = object()  # never entered directly -> no __enter__ needed
     with compat.use_mesh(mesh) as m:
         assert m is mesh
     assert used == [mesh]
 
 
-def test_use_mesh_does_not_swallow_body_exceptions(monkeypatch):
-    monkeypatch.setattr(jax.sharding, "use_mesh", None, raising=False)
-    monkeypatch.setattr(jax, "set_mesh", None, raising=False)
-
-    class FakeMesh:
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            return False
-
+def test_use_mesh_does_not_swallow_body_exceptions():
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     with pytest.raises(TypeError, match="from the body"):
-        with compat.use_mesh(FakeMesh()):
+        with compat.use_mesh(mesh):
             raise TypeError("from the body")
+
+
+def test_use_mesh_sets_and_restores_the_ambient_mesh():
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    before = jax.sharding.get_abstract_mesh()
+    with compat.use_mesh(mesh):
+        assert jax.sharding.get_abstract_mesh().axis_names == ("data", "model")
+    assert jax.sharding.get_abstract_mesh() == before
 
 
 # ---------------------------------------------------------------------------
 # compiled-executable accessors
 # ---------------------------------------------------------------------------
-
-
-def test_cost_analysis_normalizes_old_list_format():
-    class C:
-        def cost_analysis(self):
-            return [{"flops": 3, "utilization": "n/a"}]
-
-    assert compat.cost_analysis(C()) == {"flops": 3.0}
 
 
 def test_cost_analysis_normalizes_dict_and_errors():
@@ -236,8 +156,8 @@ def test_pallas_imported_only_via_compat():
     """Kernel code (flash_attention, rmsnorm, paged_attention, and whatever
     comes next) reaches the Pallas modules through ``compat.pallas()`` /
     ``compat.pallas_tpu()`` — the experimental namespace moves between JAX
-    releases and may be absent on minimal builds, so the import is a
-    version-gated access like any other and lives only in compat.py."""
+    releases, so the import is a version-gated access like any other and
+    lives only in compat.py."""
     root = pathlib.Path(__file__).resolve().parent.parent
     gated = re.compile(
         r"from\s+jax\.experimental\s+import\s+pallas|jax\.experimental\.pallas"
@@ -252,21 +172,9 @@ def test_pallas_imported_only_via_compat():
     assert not offenders, f"Pallas imported outside compat.py: {offenders}"
 
 
-def test_pallas_accessors_raise_informatively_when_absent(monkeypatch):
-    monkeypatch.setattr(compat, "_pallas_mod", None)
-    monkeypatch.setattr(compat, "_pallas_tpu_mod", None)
-    with pytest.raises(ImportError, match="reference"):
-        compat.pallas()
-    with pytest.raises(ImportError, match="reference"):
-        compat.pallas_tpu()
-
-
 def test_pallas_accessors_return_modules_when_present():
-    if not compat.HAS_PALLAS:
-        pytest.skip("no Pallas in this JAX build")
     assert hasattr(compat.pallas(), "pallas_call")
-    if compat.HAS_PALLAS_TPU:
-        assert hasattr(compat.pallas_tpu(), "PrefetchScalarGridSpec")
+    assert hasattr(compat.pallas_tpu(), "PrefetchScalarGridSpec")
 
 
 # ---------------------------------------------------------------------------

@@ -104,6 +104,25 @@ print("resumed_step", step_no, "loss", float(metrics["loss"]),
     assert "resumed_step 1" in out and "finite True" in out
 
 
+def test_initialize_lays_out_state_for_the_step():
+    """The state leaves the initialize region sharded as the compiled step
+    takes it, so the first step does not reshard it inside the train_step
+    region (a strong-scaling table would count that as step time)."""
+    out = _run(4, """
+import jax
+from repro.configs import smoke_config
+from repro.launch.mesh import make_host_mesh
+from repro.launch.train import build_loop
+loop = build_loop(smoke_config("tinyllama-1.1b"), make_host_mesh(), steps=1,
+                  global_batch=4, seq_len=32)
+state, _, _ = loop._initialize()
+held = {d.id for leaf in jax.tree_util.tree_leaves(state)
+        for d in leaf.sharding.device_set}
+print("held", sorted(held))
+""")
+    assert "held [0, 1, 2, 3]" in out
+
+
 def test_host_lb_measured_on_multidevice_mesh():
     """Host load-balance observables flow end-to-end on a multi-device mesh."""
     out = _run(8, """

@@ -142,3 +142,24 @@ def test_monitor_overhead_is_o1_memory():
     assert len(mon._regions) == size_10  # no per-step state
     st = mon._regions["step"]
     assert isinstance(st.data_lb.total, float)  # scalar accumulators only
+
+
+def test_peaks_are_looked_up_by_device_kind():
+    from repro.core import hardware as HW
+
+    # a v5e chip reports itself as "TPU v5 lite"
+    assert HW.spec_for_kind("TPU v5 lite") is HW.TPU_V5E
+    with pytest.raises(KeyError, match="TPU v99"):
+        HW.spec_for_kind("TPU v99")
+
+
+def test_record_off_the_chip_names_its_target_as_modeled():
+    ses, t = clocked_session()
+    ses.start()
+    t[0] = 1.0
+    run = ses.finalize()
+    assert run.hardware == "tpu_v5e"
+    assert run.metadata["hardware_basis"] == "modeled"
+    ses, t = clocked_session(hardware="tpu_v5p")
+    ses.start()
+    assert ses.finalize().metadata["hardware_basis"] == "configured"

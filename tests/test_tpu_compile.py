@@ -1,0 +1,98 @@
+"""The paged-attention Pallas kernels compile for a described TPU v5e chip
+at real serving widths, and the compiled program holds the kernel.
+
+Nothing runs: the TPU compiler is given shapes for a chip that is described,
+not attached, so these tests guard against what interpret mode cannot see
+(unaligned slices, too much fast memory, unpartitionable kernels) at no
+chip time. The topology is described inside a fixture, never while a
+module is imported; every test that needs it lives in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.paged_attention.kernel import (
+    paged_attention_pallas,
+    paged_prefill_attention_pallas,
+)
+
+# serving shapes: 8 slots, max_len 2048 in 16-token pages, a 1024-page
+# pool, 32-token prefill chunks (the ServeConfig default)
+BATCH, PAGE, N_LOGICAL, POOL, CHUNK = 8, 16, 2048 // 16, 1024, 32
+
+WIDTHS = {
+    # name: (Hq, Hkv, D, window, softcap)
+    "tinyllama-1.1b": (32, 4, 64, None, None),
+    "gemma2-2b": (8, 4, 256, 4096, 50.0),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep the cache off here."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(kernel, q_len, widths, one_chip):
+    Hq, Hkv, D, window, softcap = widths
+    pages = _shape((POOL, PAGE, Hkv, D), jnp.bfloat16, one_chip)
+    if kernel is paged_attention_pallas:
+        pos_name, pos_shape = "q_position", (BATCH,)
+    else:
+        pos_name, pos_shape = "q_positions", (BATCH, q_len)
+
+    def call(q, k_pages, v_pages, tables, lens, pos):
+        return kernel(q, k_pages, v_pages, tables, cache_len=lens,
+                      window=window, softcap=softcap, **{pos_name: pos})
+
+    return jax.jit(call).lower(
+        _shape((BATCH, q_len, Hq, D), jnp.bfloat16, one_chip), pages, pages,
+        _shape((BATCH, N_LOGICAL), jnp.int32, one_chip),
+        _shape((BATCH,), jnp.int32, one_chip),
+        _shape(pos_shape, jnp.int32, one_chip),
+    ).compile()
+
+
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_paged_decode_kernel_compiles_for_v5e(arch, one_chip, no_compile_cache):
+    compiled = _compile(paged_attention_pallas, 1, WIDTHS[arch], one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_paged_prefill_kernel_compiles_for_v5e(arch, one_chip, no_compile_cache):
+    compiled = _compile(paged_prefill_attention_pallas, CHUNK, WIDTHS[arch],
+                        one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
