@@ -26,6 +26,7 @@ from repro.distributed import sharding as SH
 from repro.layers.common import LogicalConstraints
 from repro.models import transformer as T
 from repro.serve.spec import draft_tokens
+from repro.session import span
 
 # the static fields ``_serve_step_fns`` keys its lru cache on — see
 # ServeConfig.step_statics() for what belongs here (and what must not)
@@ -1249,7 +1250,6 @@ class BatchScheduler:
             self._fingerprint = _F.page_fingerprint_step()
         self.stats = {
             "ticks": 0, "decode_steps": 0, "prefill_chunks": 0,
-            "readbacks": 0,
             # overlap accounting: "overlap_ticks" counts ticks where a
             # prefill was in flight alongside >=1 decoding slot (proof the
             # two phases actually co-existed); "decode_after_prefill_ticks"
@@ -2200,11 +2200,17 @@ class BatchScheduler:
 
     def _dispatch_prefill_chunk(self) -> None:
         """Dispatch one ``prefill_chunk``-token chunk for the oldest
-        in-flight prefill (asynchronous: no host sync here)."""
+        in-flight prefill (asynchronous: no host sync here), in the span
+        ``repro.serve.prefill`` with the chunk's ``start`` and ``tokens``."""
         task = self._prefills[0]
+        start = task["done"]
+        L = min(self.scfg.prefill_chunk, len(task["prompt"]) - start)
+        with span("serve.prefill", start=start, tokens=L):
+            self._prefill_chunk(task, start, L)
+
+    def _prefill_chunk(self, task: dict, start: int, L: int) -> None:
         C = self.scfg.prefill_chunk
-        prompt, start = task["prompt"], task["done"]
-        L = min(C, len(prompt) - start)
+        prompt = task["prompt"]
         chunk = np.zeros((1, C), np.int32)
         chunk[0, :L] = prompt[start : start + L]
         if self.scfg.paged:
@@ -2271,8 +2277,8 @@ class BatchScheduler:
                 # spec mode has no deferred-readback pipeline (the accept
                 # count syncs every tick anyway): materialize the first
                 # token here — this is the TTFT point regardless
-                tok_h, bad_h = jax.device_get([next_tok, bad])
-                self.stats["readbacks"] += 1
+                with span("serve.readback"):
+                    tok_h, bad_h = jax.device_get([next_tok, bad])
                 if bool(bad_h[0]):
                     # poisoned prefill: nothing was emitted — retry from
                     # the (empty) clean history via the standard path
@@ -2320,11 +2326,15 @@ class BatchScheduler:
         goes through ``_fault_retry`` instead of streaming poison."""
         if not self._pending:
             return
+        with span("serve.flush"):
+            self._flush_pending()
+
+    def _flush_pending(self) -> None:
         pending, self._pending = self._pending, []
-        host = jax.device_get(
-            [[toks, bad] for toks, bad, _ in pending]
-        )  # single transfer
-        self.stats["readbacks"] += 1
+        with span("serve.readback"):
+            host = jax.device_get(
+                [[toks, bad] for toks, bad, _ in pending]
+            )  # single transfer
         poisoned: list[dict] = []
         poisoned_ids: set = set()
         for (toks, bad), (_, _, reqmap) in zip(host, pending):
@@ -2479,16 +2489,20 @@ class BatchScheduler:
         ``spec_k+1`` tokens."""
         plans = self._plan_drafts()
         if self.scfg.paged:
-            for slot, plan in plans.items():
-                req = self.active[slot]
-                if req is None:
-                    continue  # a pressure round below evicted this slot
-                try:
-                    self._ensure_pages(
-                        slot, int(self.pos[slot]) + len(plan["drafts"]), req
-                    )
-                except _PoolPressure as e:
-                    self._handle_pressure(slot, e)
+            with span("serve.pages"):
+                for slot, plan in plans.items():
+                    req = self.active[slot]
+                    if req is None:
+                        continue  # a pressure round below evicted this slot
+                    try:
+                        self._ensure_pages(
+                            slot, int(self.pos[slot]) + len(plan["drafts"]),
+                            req,
+                        )
+                    except _PoolPressure as e:
+                        self._handle_pressure(slot, e)
+                if any(r is not None for r in self.active):
+                    self._tables_device()  # the upload, for the verify
         decoding = list(self.active)
         plans = {s: p for s, p in plans.items() if decoding[s] is not None}
         if bool(self._prefills) and plans:
@@ -2517,18 +2531,19 @@ class BatchScheduler:
         if self._hang_pending:
             time.sleep(self._hang_pending)
             self._hang_pending = 0.0
-        out_dev, acc_dev, bad_dev, self.caches = self.verify(
-            self.params, jnp.asarray(chunk), pos_now,
-            jnp.asarray(length), self.caches, self._tables_device(),
-            self.rng_keys, fault_mask,
-        )
+        with span("serve.decode"):
+            out_dev, acc_dev, bad_dev, self.caches = self.verify(
+                self.params, jnp.asarray(chunk), pos_now,
+                jnp.asarray(length), self.caches, self._tables_device(),
+                self.rng_keys, fault_mask,
+            )
         dispatch_s = time.perf_counter() - t0
         self.stats["decode_steps"] += 1
         self.stats["spec_dispatches"] += 1
         if self.stats["prefill_chunks"] > chunks_at_tick_start:
             self.stats["decode_after_prefill_ticks"] += 1
-        out, acc, bad = jax.device_get([out_dev, acc_dev, bad_dev])
-        self.stats["readbacks"] += 1
+        with span("serve.readback"):
+            out, acc, bad = jax.device_get([out_dev, acc_dev, bad_dev])
         poisoned: list[dict] = []
         eos = self.scfg.eos_id
         for slot, plan in plans.items():
@@ -2592,9 +2607,39 @@ class BatchScheduler:
 
     def step(self) -> int:
         """One scheduler tick: decode dispatch for all decoding slots, then
-        at most one prefill chunk dispatch. Returns #busy slots."""
+        at most one prefill chunk dispatch. Returns #busy slots.
+
+        The tick is the profiler span ``repro.serve.tick``, its arguments
+        the counters at its start (``_tick_counters``); its phases are the
+        spans ``repro.serve.{attach,pages,decode,prefill,flush,readback}``.
+        """
+        # counted only while a capture records them: the backlog is a sum
+        # over every waiting request
+        counters = (self._tick_counters()
+                    if jax.profiler.TraceAnnotation.is_enabled() else {})
+        with span("serve.tick", **counters):
+            return self._tick()
+
+    def _tick_counters(self) -> dict:
+        """Waiting requests (queued and parked), in-flight prefills, prompt
+        tokens not yet prefilled over all of them, decoding slots and free
+        pages (paged only)."""
+        waiting = self.queue + self._parked
+        out = {
+            "queued": len(waiting),
+            "prefilling": len(self._prefills),
+            "backlog_tok": sum(len(r["prompt"]) for r in waiting) + sum(
+                len(t["prompt"]) - t["done"] for t in self._prefills),
+            "decoding": sum(r is not None for r in self.active),
+        }
+        if self._alloc is not None:
+            out["free_pages"] = self._alloc.free_pages
+        return out
+
+    def _tick(self) -> int:
         self.stats["ticks"] += 1
-        self._attach()
+        with span("serve.attach"):
+            self._attach()
         if self.faults is not None or self._spike_holds:
             self._apply_faults()
         chunks_at_tick_start = self.stats["prefill_chunks"]
@@ -2620,13 +2665,16 @@ class BatchScheduler:
                 # any page boundary being crossed BEFORE snapshotting the
                 # active set — pool pressure here can preempt (remove) a
                 # victim slot mid-loop, or park the requesting slot itself
-                for slot in range(self.scfg.batch):
-                    req = self.active[slot]
-                    if req is not None:
-                        try:
-                            self._ensure_pages(slot, int(self.pos[slot]), req)
-                        except _PoolPressure as e:
-                            self._handle_pressure(slot, e)
+                with span("serve.pages"):
+                    for slot in range(self.scfg.batch):
+                        req = self.active[slot]
+                        if req is not None:
+                            try:
+                                self._ensure_pages(slot, int(self.pos[slot]), req)
+                            except _PoolPressure as e:
+                                self._handle_pressure(slot, e)
+                    if any(r is not None for r in self.active):
+                        self._tables_device()  # the upload, for the decode
             if not self.scfg.spec_decode:
                 decoding = list(self.active)
             if bool(self._prefills) and any(r is not None for r in decoding):
@@ -2662,10 +2710,11 @@ class BatchScheduler:
                     # wall time where the watchdog measures it
                     time.sleep(self._hang_pending)
                     self._hang_pending = 0.0
-                self.tokens, bad_dev, self.caches = self.decode(
-                    self.params, self.tokens, pos_now,
-                    *args, self.rng_keys, fault_mask,
-                )
+                with span("serve.decode"):
+                    self.tokens, bad_dev, self.caches = self.decode(
+                        self.params, self.tokens, pos_now,
+                        *args, self.rng_keys, fault_mask,
+                    )
                 dispatch_s = time.perf_counter() - t0
                 self.stats["decode_steps"] += 1
                 if self.stats["prefill_chunks"] > chunks_at_tick_start:

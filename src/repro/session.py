@@ -30,6 +30,14 @@ Surface:
   session.observe_step(...)       manual per-step observation
   session.finalize(out_dir)       stop, build the RunRecord, inject git
                                   metadata, save into the CI folder layout
+  span(name, **counters)          a profiler span ``repro.<name>``
+
+Spans. With a non-null backend every region visit is also the profiler
+span ``repro.talp.<region>``, and each observed step the span
+``repro.talp.observe`` inside it; the serving scheduler adds its phases as
+``repro.serve.*``. They land on the host plane of a
+``jax.profiler.trace`` capture, on the clock of the device events, and
+cost about a microsecond each while no capture runs.
 
 Legacy ``TalpMonitor``/``TraceRecorder`` construction via ``repro.core``
 still works for one release but emits a ``DeprecationWarning``.
@@ -59,6 +67,17 @@ ENV_OUT = "TALP_OUT"
 BACKENDS = ("monitor", "tracer", "null")
 
 _FALSY = {"0", "false", "no", "off", ""}
+
+SPAN_PREFIX = "repro."
+
+
+def span(name: str, **counters: Any):
+    """The profiler span ``repro.<name>``, a context manager; ``counters``
+    become the span's arguments in the trace. Every span of the program
+    goes through here."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(SPAN_PREFIX + name, **counters)
 
 
 def env_backend(default: str | None = None) -> str | None:
@@ -304,22 +323,31 @@ _NULL_REGION = _NullRegion()
 
 
 class _Region:
-    __slots__ = ("_session", "name", "sync")
+    """A region visit, wrapped in the span ``repro.talp.<name>``."""
+
+    __slots__ = ("_session", "name", "sync", "_spans")
 
     def __init__(self, session: "PerfSession", name: str, sync: Any = None):
         self._session = session
         self.name = name
         self.sync = sync
+        self._spans: list = []  # open spans of this handle, innermost last
 
     def __enter__(self) -> "PerfSession":
         ses = self._session
         if not ses._started:
             ses.start()
         ses._collector.region_enter(self.name)
+        sp = span("talp." + self.name)
+        sp.__enter__()
+        self._spans.append(sp)
         return ses
 
     def __exit__(self, *exc) -> bool:
-        self._session._collector.region_exit(self.name, self.sync)
+        try:
+            self._session._collector.region_exit(self.name, self.sync)
+        finally:
+            self._spans.pop().__exit__(*exc)
         return False
 
     def __call__(self, fn: Callable) -> Callable:
@@ -450,8 +478,11 @@ class PerfSession:
     # -- per-step hooks (thin passthroughs; patchable per instance) -----
 
     def observe_step(self, outputs: Any = None, **aux: Any) -> None:
+        """Record one step (the collector blocks on ``outputs``), in the
+        span ``repro.talp.observe``."""
         if self.enabled:
-            self._collector.observe_step(outputs, **aux)
+            with span("talp.observe"):
+                self._collector.observe_step(outputs, **aux)
 
     def mark_device(self) -> None:
         if self.enabled:
@@ -521,12 +552,14 @@ class PerfSession:
         state = {"pending": pending_lower}
         sync_outputs = self.config.sync_regions
         obs_fn = observe or _default_observe
-        # one region handle reused across calls (it keeps no per-entry
-        # state): a serving scheduler dispatches through wrapped steps tens
-        # of thousands of times per second, and a per-call allocation on the
-        # dispatch path is exactly the overhead the paper's Table 1 budgets
-        # against. Several wrapped steps on one session (e.g. the
-        # scheduler's decode + prefill regions) each hold their own handle.
+        # one region handle reused across calls (its only per-entry state
+        # is its stack of open spans): a serving scheduler dispatches
+        # through wrapped steps tens of thousands of times per second, and
+        # a per-call allocation on the dispatch path is exactly the
+        # overhead the paper's Table 1 budgets against (the span, about a
+        # microsecond, is the one left). Several wrapped steps on one
+        # session (e.g. the scheduler's decode + prefill regions) each
+        # hold their own handle.
         handle = _Region(self, region)
 
         @functools.wraps(fn)
