@@ -2636,6 +2636,19 @@ class BatchScheduler:
             out["free_pages"] = self._alloc.free_pages
         return out
 
+    def _decode_pages(self) -> dict:
+        """Pages the paged decode kernel walks in this tick's dispatch:
+        each slot's allocated pages below its ``cache_len`` (``pos + 1``),
+        summed (``live_pages``; a sliding window walks fewer); and
+        ``grid_pages``, every table entry of every slot."""
+        n_slots, n_logical = self._tables.shape
+        psize = self.scfg.page_size
+        below = np.arange(n_logical)[None, :] < (
+            (self.pos[:, None] + psize) // psize)
+        live = below & (self._tables >= 0)
+        return {"live_pages": int(live.sum()),
+                "grid_pages": n_slots * n_logical}
+
     def _tick(self) -> int:
         self.stats["ticks"] += 1
         with span("serve.attach"):
@@ -2710,7 +2723,10 @@ class BatchScheduler:
                     # wall time where the watchdog measures it
                     time.sleep(self._hang_pending)
                     self._hang_pending = 0.0
-                with span("serve.decode"):
+                # counted only while a capture records them, as the tick's
+                pages = (self._decode_pages() if self.scfg.paged
+                         and jax.profiler.TraceAnnotation.is_enabled() else {})
+                with span("serve.decode", **pages):
                     self.tokens, bad_dev, self.caches = self.decode(
                         self.params, self.tokens, pos_now,
                         *args, self.rng_keys, fault_mask,

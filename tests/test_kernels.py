@@ -86,53 +86,105 @@ def test_flash_matches_model_flash_path():
 # ---------------------------------------------------------------------------
 
 
-def _paged_case(B, Hq, Hkv, D, psize, nL, P, lens, dtype, seed=0):
-    """Random pool + a scrambled (non-identity) block table + ragged lens."""
+def _paged_case(B, Hq, Hkv, D, psize, nL, P, lens, dtype, seed=0, free=(),
+                deal=False, poison=(), hole=None):
+    """Random pool + a scrambled (non-identity) block table + ragged lens.
+
+    Slots in ``free`` keep their ``lens`` but own no page (a table of -1,
+    as a released slot). ``deal`` hands the shuffled pages out one at a
+    time round the slots, so that each slot's pages interleave with the
+    others' across the pool; otherwise each slot takes a run of the
+    shuffled order. The values of the slots in ``poison`` are NaN, but
+    for physical page 0, which the oracle reads for every -1. ``hole``,
+    a (slot, logical page), is unallocated below that slot's length."""
     rng = np.random.default_rng(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, 1, Hq, D), jnp.float32).astype(dtype)
     k_pages = jax.random.normal(ks[1], (P, psize, Hkv, D), jnp.float32).astype(dtype)
     v_pages = jax.random.normal(ks[2], (P, psize, Hkv, D), jnp.float32).astype(dtype)
-    perm = rng.permutation(P)
+    perm = iter(rng.permutation(P))
+    need = [0 if b in free else -(-ln // psize) for b, ln in enumerate(lens)]
     tbl = np.full((B, nL), -1, np.int32)
-    used = 0
-    for b, ln in enumerate(lens):
-        n = -(-ln // psize)
-        tbl[b, :n] = perm[used : used + n]
-        used += n
+    if deal:
+        for j in range(max(need)):
+            for b in range(B):
+                if j < need[b]:
+                    tbl[b, j] = next(perm)
+    else:
+        for b, n in enumerate(need):
+            tbl[b, :n] = [next(perm) for _ in range(n)]
+    for b in poison:
+        bad = tbl[b, :need[b]]
+        v_pages = v_pages.at[bad[bad != 0]].set(jnp.nan)
+    if hole is not None:
+        tbl[hole] = -1
     lens = jnp.asarray(lens, jnp.int32)
     return q, k_pages, v_pages, jnp.asarray(tbl), lens, lens - 1
 
 
 PAGED_CASES = [
-    # B, Hq, Hkv, D, psize, nL, P, lens, window, softcap
+    # B, Hq, Hkv, D, psize, nL, P, lens, window, softcap[, options]
     (3, 4, 2, 64, 4, 4, 12, (6, 3, 11), None, None),
     (2, 4, 4, 64, 16, 4, 9, (50, 17), None, None),
     (2, 2, 1, 64, 4, 8, 20, (29, 13), 6, None),     # window crosses pages
     (2, 8, 2, 32, 8, 3, 8, (20, 9), None, 30.0),    # softcap (gemma2)
     (1, 2, 2, 100, 8, 4, 6, (27,), 11, 50.0),       # D padding + win + cap
+    # 16 pages (256 rows) a loop step at psize 16: several blocks, partial
+    # last blocks and pages, 36 logical pages that 16 does not divide
+    (3, 8, 2, 64, 16, 36, 80, (530, 257, 17), None, None),
+    (3, 4, 2, 64, 8, 6, 12, (0, 1, 20), None, None),  # cache_len 0 and 1
+    # released slots (tables all -1) at cache_len 1 and a stale 77
+    (4, 4, 2, 64, 16, 8, 20, (40, 1, 100, 77), None, None, {"free": (1, 3)}),
+    (2, 4, 1, 64, 8, 10, 12, (9, 30), None, None),    # long -1 tails
+    # window of 40 at 600 and 300 tokens: 35 and 16 leading pages (two
+    # and one whole blocks) are never visited; 40 logical pages
+    (2, 4, 2, 64, 16, 40, 80, (600, 300), 40, None),
+    (2, 8, 2, 128, 16, 20, 48, (310, 45), None, 30.0),  # softcap, 2 blocks
+    # pages dealt round the slots: interleaved, shuffled, none shared
+    (3, 4, 2, 64, 16, 24, 64, (370, 129, 200), None, None, {"deal": True}),
+    # slot 0's NaN values fill both buffer halves; slot 1's unfetched
+    # pages must not carry them into its output
+    (2, 4, 2, 64, 16, 40, 64, (640, 40), None, None, {"poison": (0,)}),
+    # a -1 entry below cache_len inside a block: not fetched, not attended
+    (2, 4, 2, 64, 16, 24, 64, (300, 150), None, None, {"hole": (0, 5)}),
 ]
 
 
 @pytest.mark.parametrize("case", PAGED_CASES, ids=[str(c[:7]) for c in PAGED_CASES])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_matches_reference(case, dtype):
-    """Kernel-vs-ref parity in interpret mode: the in-kernel block-table
-    gather + online softmax must agree with the gather oracle across GQA,
-    ragged lengths, windows that straddle page boundaries, softcap, and
-    head-dim padding."""
-    B, Hq, Hkv, D, psize, nL, P, lens, window, softcap = case
+    """Kernel-vs-ref parity in interpret mode: the kernel's walk of each
+    slot's live pages (double-buffered copies, several pages a step) and
+    online softmax must agree with the gather oracle across GQA, ragged
+    lengths over several blocks, windows that straddle pages or drop
+    whole blocks, softcap, head-dim padding, -1 tails and holes, and
+    scattered pages. A slot with no live key (cache_len 0, or a released
+    slot whose table is all -1) gets zeros, where the oracle attends junk."""
+    B, Hq, Hkv, D, psize, nL, P, lens, window, softcap, *opt = case
+    opt = opt[0] if opt else {}
     q, kp, vp, tbl, lens, qpos = _paged_case(B, Hq, Hkv, D, psize, nL, P,
-                                             lens, dtype)
+                                             lens, dtype, **opt)
+    owned = np.asarray(tbl)[np.asarray(tbl) >= 0]
+    assert len(set(owned.tolist())) == len(owned)  # no page in two slots
     out = paged_attention_pallas(
         q, kp, vp, tbl, q_position=qpos, cache_len=lens,
         window=window, softcap=softcap, interpret=True,
     )
+    ref_tbl, ref_lens = np.array(tbl), np.array(lens)
+    if "hole" in opt:
+        # the oracle attends page 0 at a -1 below cache_len; attention over
+        # a set of keys ignores their order, so the oracle over the table
+        # without that page is what the kernel must give
+        b, j = opt["hole"]
+        ref_tbl[b] = np.append(np.delete(ref_tbl[b], j), -1)
+        ref_lens[b] -= psize
     ref = paged_attention_reference(
-        q, kp, vp, tbl, q_position=qpos, cache_len=lens,
-        window=window, softcap=softcap,
+        q, kp, vp, jnp.asarray(ref_tbl), q_position=qpos,
+        cache_len=jnp.asarray(ref_lens), window=window, softcap=softcap,
     )
     assert out.shape == ref.shape and out.dtype == ref.dtype
+    dead = (np.asarray(lens) == 0) | np.isin(np.arange(B), opt.get("free", ()))
+    ref = jnp.where(dead[:, None, None, None], 0.0, ref).astype(ref.dtype)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),

@@ -94,6 +94,19 @@ def test_tick_counters(serve_spans):
         (0, 32), (32, 8), (0, 32), (32, 32), (64, 6)]
 
 
+def test_decode_pages_counters(serve_spans):
+    """Each decode dispatch counts the pages the kernel walks and the
+    table entries the whole grid holds (2 slots x 8 pages)."""
+    _, spans = serve_spans
+    decodes = named(spans, "repro.serve.decode")
+    # the first prompt decodes at 41 and 42 tokens (3 pages) while the
+    # second's first chunk has 0, then 1 page below its cache_len of 1;
+    # then the second at 71 and 72 tokens (5 pages) beside the released
+    # first slot, whose stale position walks none of its -1 entries
+    assert [a for *_, a in decodes] == [
+        {"live_pages": n, "grid_pages": 16} for n in (3, 4, 5, 5)]
+
+
 def test_phases_nest_in_their_tick_or_flush(serve_spans):
     sched, spans = serve_spans
     ticks = named(spans, "repro.serve.tick")

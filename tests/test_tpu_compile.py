@@ -28,7 +28,13 @@ WIDTHS = {
     # name: (Hq, Hkv, D, window, softcap)
     "tinyllama-1.1b": (32, 4, 64, None, None),
     "gemma2-2b": (8, 4, 256, 4096, 50.0),
+    "glm4-9b": (32, 2, 128, None, None),
 }
+
+# the decode kernel at a serving cell's own shape where one is benchmarked:
+# (slots, logical pages, pool pages); GLM-4-9B's is 32 slots, max_len 5120
+# in 16-token pages and a 7,680-page pool
+DECODE_SHAPES = {"glm4-9b": (32, 5120 // 16, 7680)}
 
 
 @pytest.fixture(scope="module")
@@ -65,30 +71,39 @@ def _shape(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(kernel, q_len, widths, one_chip):
+def _compile(kernel, q_len, widths, one_chip,
+             shape=(BATCH, N_LOGICAL, POOL)):
     Hq, Hkv, D, window, softcap = widths
-    pages = _shape((POOL, PAGE, Hkv, D), jnp.bfloat16, one_chip)
+    batch, n_logical, pool = shape
+    pages = _shape((pool, PAGE, Hkv, D), jnp.bfloat16, one_chip)
     if kernel is paged_attention_pallas:
-        pos_name, pos_shape = "q_position", (BATCH,)
+        pos_name, pos_shape = "q_position", (batch,)
     else:
-        pos_name, pos_shape = "q_positions", (BATCH, q_len)
+        pos_name, pos_shape = "q_positions", (batch, q_len)
 
     def call(q, k_pages, v_pages, tables, lens, pos):
         return kernel(q, k_pages, v_pages, tables, cache_len=lens,
                       window=window, softcap=softcap, **{pos_name: pos})
 
     return jax.jit(call).lower(
-        _shape((BATCH, q_len, Hq, D), jnp.bfloat16, one_chip), pages, pages,
-        _shape((BATCH, N_LOGICAL), jnp.int32, one_chip),
-        _shape((BATCH,), jnp.int32, one_chip),
+        _shape((batch, q_len, Hq, D), jnp.bfloat16, one_chip), pages, pages,
+        _shape((batch, n_logical), jnp.int32, one_chip),
+        _shape((batch,), jnp.int32, one_chip),
         _shape(pos_shape, jnp.int32, one_chip),
     ).compile()
 
 
 @pytest.mark.parametrize("arch", sorted(WIDTHS))
 def test_paged_decode_kernel_compiles_for_v5e(arch, one_chip, no_compile_cache):
-    compiled = _compile(paged_attention_pallas, 1, WIDTHS[arch], one_chip)
-    assert "tpu_custom_call" in compiled.as_text()
+    """One kernel a call, named ``paged_attention`` wherever it is called
+    from: the profiler's op events carry that name."""
+    compiled = _compile(paged_attention_pallas, 1, WIDTHS[arch], one_chip,
+                        **({"shape": DECODE_SHAPES[arch]}
+                           if arch in DECODE_SHAPES else {}))
+    calls = [line.split(" = ", 1)[0].strip().lstrip("%")
+             for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    assert len(calls) == 1 and calls[0].startswith("paged_attention"), calls
 
 
 @pytest.mark.parametrize("arch", sorted(WIDTHS))
