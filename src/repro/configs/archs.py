@@ -1,5 +1,5 @@
-"""The 10 assigned architectures (exact configs from the assignment table)
-plus reduced smoke variants. ``[source; tier]`` noted per arch.
+"""The 10 assigned architectures (exact configs from the assignment table),
+granite-4.0-h-small, and reduced smoke variants. ``[source; tier]`` noted per arch.
 
 Deviations from upstream checkpoints (documented in DESIGN.md §5):
   * hubert uses RoPE instead of its conv relative positional embedding
@@ -133,6 +133,28 @@ def llava_next_mistral_7b() -> ModelConfig:
     )
 
 
+def granite_4_h_small() -> ModelConfig:
+    # [hf:ibm-granite/granite-4.0-h-small; hf] 36 Mamba2 + 4 NoPE GQA
+    # mixers, each followed by a 72-expert top-10 MoE and a shared expert
+    return ModelConfig(
+        name="granite-4.0-h-small",
+        d_model=4096, n_heads=32, n_kv_heads=8, d_ff=0, vocab=100352,
+        head_dim=128,
+        pattern=("mamba2_moe",) * 5 + ("moe",) + ("mamba2_moe",) * 4,
+        repeats=4,  # 40 layers, attention at 5, 15, 25, 35
+        ssm=SSMConfig(d_state=128, d_conv=4, expand=2, head_dim=64,
+                      n_groups=1, chunk=256),
+        moe=MoEConfig(n_experts=72, top_k=10, d_ff=768, n_shared_experts=2,
+                      normalize_topk=True),  # shared expert 2 x 768 = 1536
+        rope_theta=0.0,  # NoPE
+        sm_scale=0.0078125,  # attention_multiplier
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=16.0,
+        tie_embeddings=True,
+        norm_eps=1e-5,
+    )
+
+
 def xlstm_350m() -> ModelConfig:
     # [arXiv:2405.04517; unverified] 7:1 mLSTM:sLSTM blocks; d_ff=0 ->
     # projections live inside the cells (xLSTM pre-up-projection blocks)
@@ -158,6 +180,7 @@ ARCHS = {
     "command-r-35b": command_r_35b,
     "llava-next-mistral-7b": llava_next_mistral_7b,
     "xlstm-350m": xlstm_350m,
+    "granite-4.0-h-small": granite_4_h_small,
 }
 
 

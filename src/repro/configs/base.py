@@ -1,12 +1,13 @@
 """Model / run configuration schema.
 
-One ``ModelConfig`` describes any of the 10 assigned architectures through a
-*block pattern*: the layer stack is ``pattern`` (a short period of block
-kinds) repeated ``repeats`` times — scanned over ``repeats`` so the HLO stays
-compact (period blocks are materialized once, stacked over repeats).
+One ``ModelConfig`` describes any architecture in ``configs/archs.py``
+through a *block pattern*: the layer stack is ``pattern`` (a short period of
+block kinds) repeated ``repeats`` times — scanned over ``repeats`` so the HLO
+stays compact (period blocks are materialized once, stacked over repeats).
 
 Block kinds: "attn" (global attention + FFN), "local_attn" (sliding-window +
-FFN), "moe" (attention + MoE FFN), "mamba2", "mlstm", "slstm".
+FFN), "moe" (attention + MoE FFN), "mamba2", "mamba2_moe" (Mamba2 mixer +
+MoE FFN), "mlstm", "slstm".
 """
 
 from __future__ import annotations
@@ -17,7 +18,10 @@ from typing import Any
 
 import jax.numpy as jnp
 
-BLOCK_KINDS = ("attn", "local_attn", "moe", "mamba2", "mlstm", "slstm")
+BLOCK_KINDS = ("attn", "local_attn", "moe", "mamba2", "mamba2_moe", "mlstm",
+               "slstm")
+MOE_KINDS = ("moe", "mamba2_moe")
+RECURRENT_KINDS = ("mamba2", "mamba2_moe", "mlstm", "slstm")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,6 +33,21 @@ class MoEConfig:
     capacity_factor: float = 1.25
     normalize_topk: bool = True
     gated: bool = True
+    # the experts this chip holds, (first, count); None: every expert. The
+    # router keeps all n_experts outputs; pairs routed elsewhere add nothing
+    held: tuple[int, int] | None = None
+
+    def __post_init__(self):
+        if self.held is not None:   # any pair, e.g. a JSON list; kept hashable
+            object.__setattr__(self, "held", tuple(self.held))
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.held is None else self.held[1]
+
+    @property
+    def first_held(self) -> int:
+        return 0 if self.held is None else self.held[0]
 
     def capacity(self, n_tokens: int) -> int:
         c = math.ceil(n_tokens * self.top_k * self.capacity_factor / self.n_experts)
@@ -84,6 +103,7 @@ class ModelConfig:
     final_softcap: float | None = None
     attn_bias: bool = False
     qk_norm: bool = False
+    sm_scale: float | None = None      # score scale; None: 1/sqrt(head_dim)
 
     # families
     moe: MoEConfig | None = None
@@ -96,6 +116,12 @@ class ModelConfig:
     n_frontend_tokens: int = 0         # patches/frames provided by the stub
     tie_embeddings: bool = False
     embed_scale: bool = False          # gemma-style sqrt(d) embedding scale
+    # granite-style scalars: the embedding times embedding_multiplier, each
+    # sublayer's output into the residual times residual_multiplier, the
+    # logits divided by logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     zero_centered_norm: bool = False   # gemma-style (1+scale) rmsnorm
     act: str = "swiglu"
     norm_eps: float = 1e-6
@@ -165,8 +191,8 @@ class ModelConfig:
             return total
         m = self.moe
         per_expert = 3 * self.d_model * m.d_ff if m.gated else 2 * self.d_model * m.d_ff
-        n_moe_layers = sum(1 for k in self.pattern if k == "moe") * self.repeats
-        inactive = n_moe_layers * per_expert * (m.n_experts - m.top_k)
+        n_moe_layers = sum(1 for k in self.pattern if k in MOE_KINDS) * self.repeats
+        inactive = n_moe_layers * per_expert * (m.n_held - m.top_k)
         return total - inactive
 
 

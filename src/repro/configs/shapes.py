@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import dataclasses
 
+from repro.configs.base import RECURRENT_KINDS
+
 
 @dataclasses.dataclass(frozen=True)
 class InputShape:
@@ -31,7 +33,7 @@ def skip_reason(cfg, shape: InputShape) -> str | None:
     if cfg.encoder_only and shape.mode == "decode":
         return "encoder-only architecture has no decode step"
     if shape.name == "long_500k":
-        sub_quadratic = any(k in ("mamba2", "mlstm", "slstm") for k in cfg.pattern)
+        sub_quadratic = any(k in RECURRENT_KINDS for k in cfg.pattern)
         if not sub_quadratic:
             return (
                 "pure full-attention arch: O(L^2) prefill and 500k-token KV "

@@ -283,12 +283,14 @@ def paged_prefill_attention_pallas(
     q, k_pages, v_pages, block_tables, *, q_positions, cache_len,
     causal: bool = True, window: int | None = None,
     softcap: float | None = None, interpret: bool = False,
+    sm_scale: float | None = None,
 ):
     """q: (B,C,Hq,D) one prefill chunk per row; k_pages/v_pages:
     (P, page, Hkv, D); block_tables: (B, n_logical) int32 (``-1`` =
     unallocated); q_positions: (B,C) with row c at ``q_positions[:,0]+c``
     (the chunked-prefill contract: chunks are contiguous); cache_len: ()
-    or (B,) written tokens incl. this chunk. Returns (B,C,Hq,D).
+    or (B,) written tokens incl. this chunk; sm_scale: the score scale,
+    1/sqrt(D) when None. Returns (B,C,Hq,D).
 
     Grid is ``(batch, kv_heads, logical_pages)``: the chunk's queries
     stream every owned page once through the block-table index map
@@ -298,7 +300,8 @@ def paged_prefill_attention_pallas(
     P, page, Hkv, _ = k_pages.shape
     nL = block_tables.shape[-1]
     G = Hq // Hkv
-    sm_scale = 1.0 / math.sqrt(D)
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(D)
     d_pad = -(-D // 128) * 128
 
     qh = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, d_pad - D)))
@@ -361,12 +364,12 @@ def paged_prefill_attention_pallas(
 def paged_attention_pallas(
     q, k_pages, v_pages, block_tables, *, q_position, cache_len,
     window: int | None = None, softcap: float | None = None,
-    interpret: bool = False,
+    interpret: bool = False, sm_scale: float | None = None,
 ):
     """q: (B,1,Hq,D); k_pages/v_pages: (P, page, Hkv, D); block_tables:
-    (B, n_logical) int32 (``-1`` = unallocated). Returns (B,1,Hq,D); a
-    slot with no live key (``cache_len`` 0, or no allocated page below
-    it) gets zeros.
+    (B, n_logical) int32 (``-1`` = unallocated); sm_scale: the score
+    scale, 1/sqrt(D) when None. Returns (B,1,Hq,D); a slot with no live
+    key (``cache_len`` 0, or no allocated page below it) gets zeros.
 
     Head dim is padded to the 128-lane width; the pool is transposed to
     (Hkv, P, page, d) so one copy fetches a page of every KV head. The
@@ -377,7 +380,8 @@ def paged_attention_pallas(
     P, page, Hkv, _ = k_pages.shape
     nL = block_tables.shape[-1]
     G = Hq // Hkv
-    sm_scale = 1.0 / math.sqrt(D)
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(D)
     d_pad = -(-D // 128) * 128
     pps = pages_per_step(page, nL, Hkv, d_pad, G, k_pages.dtype.itemsize)
 

@@ -25,7 +25,7 @@ from repro.kernels.paged_attention.ref import (
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "softcap", "impl")
+    jax.jit, static_argnames=("window", "softcap", "impl", "sm_scale")
 )
 def paged_attention(
     q, k_pages, v_pages, block_tables, *,
@@ -33,12 +33,14 @@ def paged_attention(
     window: int | None = None,
     softcap: float | None = None,
     impl: str = "auto",  # auto | pallas | interpret | reference
+    sm_scale: float | None = None,
 ):
     """Single-position attention against a paged KV pool.
 
     q: (B,1,Hq,D); k_pages/v_pages: (P, page_size, Hkv, D); block_tables:
     (B, n_logical) int32, ``-1`` = unallocated; q_position/cache_len: ()
-    or (B,). Returns (B,1,Hq,D) in q.dtype.
+    or (B,); ``sm_scale``: the score scale, 1/sqrt(D) when None. Returns
+    (B,1,Hq,D) in q.dtype.
     """
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "reference"
@@ -46,12 +48,12 @@ def paged_attention(
         return paged_attention_reference(
             q, k_pages, v_pages, block_tables,
             q_position=q_position, cache_len=cache_len,
-            window=window, softcap=softcap,
+            window=window, softcap=softcap, sm_scale=sm_scale,
         )
     return paged_attention_pallas(
         q, k_pages, v_pages, block_tables,
         q_position=q_position, cache_len=cache_len,
-        window=window, softcap=softcap,
+        window=window, softcap=softcap, sm_scale=sm_scale,
         interpret=(impl == "interpret"),
     )
 
@@ -59,7 +61,7 @@ def paged_attention(
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "window", "softcap", "q_chunk", "kv_chunk",
-                     "impl"),
+                     "impl", "sm_scale"),
 )
 def paged_prefill_attention(
     q, k_pages, v_pages, block_tables, *,
@@ -70,6 +72,7 @@ def paged_prefill_attention(
     q_chunk: int = 512,
     kv_chunk: int = 1024,
     impl: str = "auto",  # auto | pallas | interpret | reference
+    sm_scale: float | None = None,
 ):
     """Multi-token (S>1) chunked-prefill attention against a paged KV pool.
 
@@ -79,7 +82,8 @@ def paged_prefill_attention(
     are the reference path's flash chunk sizes — pass the model's so the
     reference stays bitwise identical to the dense-gather prefill (the
     sharing-on/off and paged-vs-dense token-identity guarantees); the
-    kernel streams pages and ignores them. Returns (B,C,Hq,D) in q.dtype.
+    kernel streams pages and ignores them. ``sm_scale``: the score scale,
+    1/sqrt(D) when None. Returns (B,C,Hq,D) in q.dtype.
     """
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "reference"
@@ -88,11 +92,11 @@ def paged_prefill_attention(
             q, k_pages, v_pages, block_tables,
             q_positions=q_positions, cache_len=cache_len,
             causal=causal, window=window, softcap=softcap,
-            q_chunk=q_chunk, kv_chunk=kv_chunk,
+            q_chunk=q_chunk, kv_chunk=kv_chunk, sm_scale=sm_scale,
         )
     return paged_prefill_attention_pallas(
         q, k_pages, v_pages, block_tables,
         q_positions=q_positions, cache_len=cache_len,
-        causal=causal, window=window, softcap=softcap,
+        causal=causal, window=window, softcap=softcap, sm_scale=sm_scale,
         interpret=(impl == "interpret"),
     )

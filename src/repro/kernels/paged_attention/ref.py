@@ -38,6 +38,7 @@ def paged_prefill_attention_reference(
     q, k_pages, v_pages, block_tables, *, q_positions, cache_len,
     causal: bool = True, window: int | None = None,
     softcap: float | None = None, q_chunk: int = 512, kv_chunk: int = 1024,
+    sm_scale: float | None = None,
 ):
     """Multi-token (S>1) chunked-prefill attention against a paged cache.
 
@@ -63,21 +64,22 @@ def paged_prefill_attention_reference(
         q, k_cache, v_cache, q_positions=q_positions,
         k_positions=k_positions, causal=causal, window=window,
         softcap=softcap, kv_len=cache_len,
-        q_chunk=q_chunk, kv_chunk=kv_chunk, causal_skip=False,
+        q_chunk=q_chunk, kv_chunk=kv_chunk, causal_skip=False, sm_scale=sm_scale,
     )
 
 
 def paged_attention_reference(
     q, k_pages, v_pages, block_tables, *, q_position, cache_len,
     window: int | None = None, softcap: float | None = None,
+    sm_scale: float | None = None,
 ):
     """Single-position attention against a paged cache.
 
     q: (B,1,Hq,D); k_pages/v_pages: (P, page, Hkv, D); block_tables:
     (B, n_logical) int32 (logical page j of slot b lives in physical page
     ``block_tables[b, j]``); cache_len: () or (B,) valid token count;
-    q_position: () or (B,) query position (window masking).
-    Returns (B,1,Hq,D) in q.dtype.
+    q_position: () or (B,) query position (window masking); sm_scale: the
+    score scale, 1/sqrt(D) when None. Returns (B,1,Hq,D) in q.dtype.
     """
     k_cache = gather_pages(k_pages, block_tables)
     v_cache = gather_pages(v_pages, block_tables)
@@ -85,7 +87,7 @@ def paged_attention_reference(
     B, _, Hq, D = q.shape
     _, Sk, Hkv, _ = k_cache.shape
     G = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if sm_scale is None else sm_scale
     qg = q.reshape(B, 1, Hkv, G, D).transpose(0, 3, 2, 1, 4)  # (B,G,Hkv,1,D)
     kg = k_cache.transpose(0, 2, 1, 3)  # (B,Hkv,Sk,D)
     vg = v_cache.transpose(0, 2, 1, 3)

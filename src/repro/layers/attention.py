@@ -108,6 +108,7 @@ def flash_attention(
     q_chunk: int = 512,
     kv_chunk: int = 1024,
     causal_skip: bool = False,
+    sm_scale: float | None = None,
 ):
     """Online-softmax chunked attention.
 
@@ -117,6 +118,7 @@ def flash_attention(
     partially written KV cache this way).
     ``causal_skip`` bounds the kv scan per q-chunk (skips fully-future
     blocks) — a beyond-paper compute optimization toggled by the perf pass.
+    ``sm_scale``: the score scale, 1/sqrt(D) when None.
     Returns (B,Sq,Hq,D) in q.dtype.
     """
     B, Sq, Hq, D = q.shape
@@ -125,7 +127,7 @@ def flash_attention(
         kv_len = jnp.asarray(kv_len).reshape(-1, 1, 1, 1, 1)
     _, Sk, Hkv, _ = k.shape
     G = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if sm_scale is None else sm_scale
     q_chunk = min(q_chunk, Sq)
     kv_chunk = min(kv_chunk, Sk)
     nq = -(-Sq // q_chunk)
@@ -259,7 +261,7 @@ def _pad_axis(x, axis, size, value=0):
 def decode_attention_paged(
     q, k_pages, v_pages, block_tables, *, q_position, cache_len,
     window: int | None = None, softcap: float | None = None,
-    impl: str = "auto",
+    impl: str = "auto", sm_scale: float | None = None,
 ):
     """Single-position attention against a paged KV pool.
 
@@ -274,23 +276,25 @@ def decode_attention_paged(
     return paged_attention(
         q, k_pages, v_pages, block_tables,
         q_position=q_position, cache_len=cache_len,
-        window=window, softcap=softcap, impl=impl,
+        window=window, softcap=softcap, impl=impl, sm_scale=sm_scale,
     )
 
 
 def decode_attention(
     q, k_cache, v_cache, *, q_position, cache_len,
     window: int | None = None, softcap: float | None = None,
+    sm_scale: float | None = None,
 ):
     """Single-position attention against a cache.
 
     q: (B,1,Hq,D); caches: (B,Smax,Hkv,D); cache_len: () or (B,) valid length
     (positions [0, cache_len) are real; q_position = cache_len typically).
+    ``sm_scale``: the score scale, 1/sqrt(D) when None.
     """
     B, _, Hq, D = q.shape
     _, Sk, Hkv, _ = k_cache.shape
     G = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if sm_scale is None else sm_scale
     qg = q.reshape(B, 1, Hkv, G, D).transpose(0, 3, 2, 1, 4)  # (B,G,Hkv,1,D)
     kg = k_cache.transpose(0, 2, 1, 3)  # (B,Hkv,Sk,D)
     vg = v_cache.transpose(0, 2, 1, 3)
@@ -414,7 +418,7 @@ def attention_block(
                 q, new_cache["k_pages"], new_cache["v_pages"], block_tables,
                 q_position=pos0, cache_len=cache_len,
                 window=window, softcap=cfg.attn_softcap,
-                impl=cfg.paged_attn_impl,
+                impl=cfg.paged_attn_impl, sm_scale=cfg.sm_scale,
             )
         else:
             # chunked prefill: attend the block table directly (multi-token
@@ -430,7 +434,7 @@ def attention_block(
                 q_positions=positions, cache_len=cache_len,
                 causal=causal, window=window, softcap=cfg.attn_softcap,
                 q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
-                impl=cfg.paged_attn_impl,
+                impl=cfg.paged_attn_impl, sm_scale=cfg.sm_scale,
             )
     elif cache is not None:
         # write current k/v at each row's own positions, then attend against
@@ -455,7 +459,7 @@ def attention_block(
         if S == 1:
             o = decode_attention(
                 q, kc, vc, q_position=pos0, cache_len=cache_len,
-                window=window, softcap=cfg.attn_softcap,
+                window=window, softcap=cfg.attn_softcap, sm_scale=cfg.sm_scale,
             )
         elif cache_attend:
             # chunked prefill: this chunk's queries see every cache line
@@ -467,7 +471,7 @@ def attention_block(
                 causal=causal, window=window, softcap=cfg.attn_softcap,
                 kv_len=cache_len,
                 q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
-                causal_skip=False,
+                causal_skip=False, sm_scale=cfg.sm_scale,
             )
         else:  # full prefill from position 0: in-chunk attention
             o = flash_attention(
@@ -475,14 +479,14 @@ def attention_block(
                 k_positions=positions, causal=causal, window=window,
                 softcap=cfg.attn_softcap,
                 q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
-                causal_skip=cfg.causal_skip,
+                causal_skip=cfg.causal_skip, sm_scale=cfg.sm_scale,
             )
     else:
         o = flash_attention(
             q, k, v, q_positions=positions, k_positions=positions,
             causal=causal, window=window, softcap=cfg.attn_softcap,
             q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
-            causal_skip=cfg.causal_skip,
+            causal_skip=cfg.causal_skip, sm_scale=cfg.sm_scale,
         )
     o = lc(o, "batch", "seq_q", "heads", None)
     out = o.reshape(B, S, hq * hd) @ params["wo"].astype(compute)

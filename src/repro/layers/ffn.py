@@ -11,6 +11,10 @@ Dropped tokens (beyond capacity) fall through via the residual connection —
 the paper-standard "token dropping" behaviour; capacity_factor controls it.
 The router also returns per-expert token counts: the monitor's
 **expert load-balance** factor (DESIGN.md §3) reads exactly this.
+
+Serving uses ``moe_dropless`` instead: grouped matmuls over exactly the
+pairs routed to the experts this chip holds, so a served token never
+depends on what else is in the batch.
 """
 
 from __future__ import annotations
@@ -74,12 +78,12 @@ def mlp_block(params, x, cfg, lc: LogicalConstraints = NULL_CONSTRAINTS):
 
 def moe_params(cfg) -> dict:
     m = cfg.moe
-    d, f, e = cfg.d_model, m.d_ff, m.n_experts
+    d, f, e, h = cfg.d_model, m.d_ff, m.n_experts, m.n_held
     p = {
         "router": ParamSpec((d, e), ("embed", None), scale=0.02),
-        "wi_gate": ParamSpec((e, d, f), ("experts", "embed", "expert_mlp")),
-        "wi_up": ParamSpec((e, d, f), ("experts", "embed", "expert_mlp")),
-        "wo": ParamSpec((e, f, d), ("experts", "expert_mlp", "embed_out"),
+        "wi_gate": ParamSpec((h, d, f), ("experts", "embed", "expert_mlp")),
+        "wi_up": ParamSpec((h, d, f), ("experts", "embed", "expert_mlp")),
+        "wo": ParamSpec((h, f, d), ("experts", "expert_mlp", "embed_out"),
                         scale=1.0 / (math.sqrt(f) * math.sqrt(2 * cfg.n_layers))),
     }
     if m.n_shared_experts:
@@ -184,3 +188,50 @@ def moe_block(params, x, cfg, lc: LogicalConstraints = NULL_CONSTRAINTS):
     z_loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
     aux = {"expert_load": expert_load, "moe_lb_loss": lb_loss, "moe_z_loss": z_loss}
     return out.reshape(B, S, d), aux
+
+
+def moe_dropless(params, x, cfg, seq_mask=None):
+    """x: (B,S,d). Dropless MoE over the experts this chip holds.
+
+    Each token is routed over all ``n_experts`` (the router keeps its
+    published width); its (token, expert) pairs are sorted by expert, the
+    pairs of experts held elsewhere and of positions masked by
+    ``seq_mask`` last, and the held experts run as grouped matmuls
+    (``jax.lax.ragged_dot``) over exactly the pairs routed to them. No
+    capacity: no pair is dropped at any token count. A pair routed to an
+    expert not held here adds nothing; the shared expert is added once.
+
+    Returns (out, aux): ``moe_routed``, the pairs on held experts, and
+    ``moe_busiest``, the pairs of the busiest held expert (int32 scalars).
+    """
+    m = cfg.moe
+    B, S, d = x.shape
+    N, K, H = B * S, m.top_k, m.n_held
+    compute = cfg.compute_dtype
+    xt = x.reshape(N, d)
+    logits = (xt @ params["router"].astype(compute)).astype(jnp.float32)
+    weights, experts = router_topk(logits, K, m.normalize_topk)   # (N,K)
+    local = experts.reshape(-1) - m.first_held
+    held = (local >= 0) & (local < H)
+    if seq_mask is not None:
+        held &= jnp.repeat(seq_mask.reshape(N), K)
+    group = jnp.where(held, local, H)
+    order = jnp.argsort(group)
+    sizes = jnp.zeros((H + 1,), jnp.int32).at[group].add(1)[:H]
+    rows = xt[order // K].astype(compute)          # each sorted pair's token
+    act = _act("swiglu" if m.gated else "gelu")
+    g = jax.lax.ragged_dot(rows, params["wi_gate"].astype(compute), sizes)
+    if m.gated:
+        h = act(g) * jax.lax.ragged_dot(rows, params["wi_up"].astype(compute), sizes)
+    else:
+        h = act(g)
+    y = jax.lax.ragged_dot(h, params["wo"].astype(compute), sizes)
+    # back to pair order; rows past the held groups are not computed
+    y = y[jnp.argsort(order)]
+    w = jnp.where(held, weights.reshape(-1), 0.0)
+    y = jnp.where(held[:, None], y.astype(jnp.float32), 0.0) * w[:, None]
+    out = y.reshape(N, K, d).sum(axis=1)
+    if m.n_shared_experts:
+        out = out + mlp_block(params["shared"], xt, cfg).astype(jnp.float32)
+    aux = {"moe_routed": jnp.sum(sizes), "moe_busiest": jnp.max(sizes)}
+    return out.astype(compute).reshape(B, S, d), aux

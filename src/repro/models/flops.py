@@ -32,7 +32,7 @@ def _attention_fwd_flops(cfg, batch: int, seq: int) -> float:
 def _recurrent_fwd_flops(cfg, batch: int, seq: int) -> float:
     total = 0.0
     for kind in cfg.pattern:
-        if kind == "mamba2" and cfg.ssm:
+        if kind in ("mamba2", "mamba2_moe") and cfg.ssm:
             s = cfg.ssm
             h = s.n_heads(cfg.d_model)
             p, n, L = s.head_dim, s.d_state, min(s.chunk, seq)
@@ -78,7 +78,7 @@ def decode_model_flops(cfg, batch: int, cache_len: int) -> float:
         if kind in ("attn", "local_attn", "moe"):
             eff = min(cfg.window, cache_len) if (kind == "local_attn" and cfg.window) else cache_len
             flops += 4.0 * batch * eff * hq * hd * cfg.repeats
-        elif kind == "mamba2" and cfg.ssm:
+        elif kind in ("mamba2", "mamba2_moe") and cfg.ssm:
             s = cfg.ssm
             flops += 4.0 * batch * s.n_heads(cfg.d_model) * s.head_dim * s.d_state * cfg.repeats
         elif kind == "mlstm" and cfg.xlstm:
@@ -99,7 +99,7 @@ def decode_model_bytes(cfg, batch: int, cache_len: int) -> float:
         if kind in ("attn", "local_attn", "moe"):
             eff = min(cfg.window, cache_len) if (kind == "local_attn" and cfg.window) else cache_len
             cache_bytes += 2.0 * batch * eff * hkv * hd * 2  # k+v bf16
-        elif kind == "mamba2" and cfg.ssm:
+        elif kind in ("mamba2", "mamba2_moe") and cfg.ssm:
             ssm = cfg.ssm
             cache_bytes += 4.0 * batch * ssm.n_heads(cfg.d_model) * ssm.head_dim * ssm.d_state
         elif kind == "mlstm" and cfg.xlstm:

@@ -1,5 +1,6 @@
-"""Model assembly: pattern-scanned transformer covering all 10 assigned
-architectures (dense / MoE / hybrid-SSM / xLSTM / encoder / VLM backbone).
+"""Model assembly: pattern-scanned transformer covering every architecture
+in ``configs/archs.py`` (dense / MoE / hybrid-SSM / hybrid-SSM-MoE / xLSTM /
+encoder / VLM backbone).
 
 The layer stack is ``cfg.pattern`` repeated ``cfg.repeats`` times and scanned
 over repeats (compact HLO, correct trip-count accounting in the HLO cost
@@ -47,6 +48,10 @@ def _slot_params(cfg, kind: str) -> dict:
         p["moe"] = FFN.moe_params(cfg)
     elif kind == "mamba2":
         p["mamba"] = R.mamba2_params(cfg)
+    elif kind == "mamba2_moe":
+        p["mamba"] = R.mamba2_params(cfg)
+        p["norm_mlp"] = rmsnorm_params(cfg.d_model)
+        p["moe"] = FFN.moe_params(cfg)
     elif kind == "mlstm":
         p["mlstm"] = R.mlstm_params(cfg)
     elif kind == "slstm":
@@ -92,6 +97,23 @@ def model_params(cfg) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def _residual(x, o, cfg):
+    """A sublayer's output into the residual stream."""
+    if cfg.residual_multiplier != 1.0:
+        o = o * cfg.residual_multiplier
+    return x + o
+
+
+def _moe(moe_params, h, cfg, lc, *, serving, seq_mask):
+    """The MoE FFN. Serving (a cache is threaded through) and a chip that
+    holds a share of the experts take the dropless held-expert layer, so
+    that a served token never depends on its batch; training keeps the
+    grouped capacity dispatch, whose layout follows the data shards."""
+    if serving or cfg.moe.held is not None:
+        return FFN.moe_dropless(moe_params, h, cfg, seq_mask=seq_mask)
+    return FFN.moe_block(moe_params, h, cfg, lc=lc)
+
+
 def _apply_slot(
     slot_params, kind: str, x, cfg, *, positions, lc, cache=None, cache_len=None,
     seq_mask=None, cache_attend=False, block_tables=None,
@@ -121,26 +143,34 @@ def _apply_slot(
         # to reduce-scatter onto the seq-sharded residual instead of a full
         # f32 all-reduce (16x the bytes, measured on dbrx train_4k)
         o = lc(o, "batch", "seq", None)
-        x = x + o
+        x = _residual(x, o, cfg)
         h2 = rmsnorm(
             x, slot_params["norm_mlp"]["scale"], cfg.norm_eps, cfg.zero_centered_norm
         )
         if kind == "moe":
-            o2, moe_aux = FFN.moe_block(slot_params["moe"], h2, cfg, lc=lc)
+            o2, moe_aux = _moe(slot_params["moe"], h2, cfg, lc,
+                               serving=cache is not None, seq_mask=seq_mask)
             aux.update(moe_aux)
         else:
             o2 = FFN.mlp_block(slot_params["mlp"], h2, cfg, lc=lc)
         o2 = lc(o2, "batch", "seq", None)
-        x = x + o2
+        x = _residual(x, o2, cfg)
         if att_new is not None:
             new_cache = {"attn": att_new}
-    elif kind == "mamba2":
+    elif kind in ("mamba2", "mamba2_moe"):
         o, mcache = R.mamba2_block(
             slot_params["mamba"], h, cfg, lc=lc,
             cache=cache.get("mamba") if cache else None, seq_mask=seq_mask,
         )
         o = lc(o, "batch", "seq", None)
-        x = x + o
+        x = _residual(x, o, cfg)
+        if kind == "mamba2_moe":
+            h2 = rmsnorm(x, slot_params["norm_mlp"]["scale"], cfg.norm_eps,
+                         cfg.zero_centered_norm)
+            o2, moe_aux = _moe(slot_params["moe"], h2, cfg, lc,
+                               serving=cache is not None, seq_mask=seq_mask)
+            aux.update(moe_aux)
+            x = _residual(x, lc(o2, "batch", "seq", None), cfg)
         if mcache is not None:
             new_cache = {"mamba": mcache}
     elif kind == "mlstm":
@@ -148,7 +178,7 @@ def _apply_slot(
             slot_params["mlstm"], h, cfg, lc=lc,
             cache=cache.get("mlstm") if cache else None, seq_mask=seq_mask,
         )
-        x = x + o
+        x = _residual(x, o, cfg)
         if mcache is not None:
             new_cache = {"mlstm": mcache}
     elif kind == "slstm":
@@ -156,11 +186,11 @@ def _apply_slot(
             slot_params["slstm"], h, cfg, lc=lc,
             cache=cache.get("slstm") if cache else None, seq_mask=seq_mask,
         )
-        x = x + o
+        x = _residual(x, o, cfg)
         h2 = rmsnorm(
             x, slot_params["norm_mlp"]["scale"], cfg.norm_eps, cfg.zero_centered_norm
         )
-        x = x + FFN.mlp_block(slot_params["mlp"], h2, cfg, lc=lc)
+        x = _residual(x, FFN.mlp_block(slot_params["mlp"], h2, cfg, lc=lc), cfg)
         if scache is not None:
             new_cache = {"slstm": scache}
     else:
@@ -258,6 +288,8 @@ def _embed_inputs(params, batch, cfg, lc):
     x = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
     if cfg.embed_scale:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), cfg.compute_dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, cfg.compute_dtype)
     return lc(x, "batch", "seq", None)
 
 
@@ -267,6 +299,8 @@ def _logits(params, x, cfg, lc):
     else:
         head = params["head"]
     logits = x @ head.astype(cfg.compute_dtype)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     if cfg.final_softcap:
         logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
     return lc(logits, "batch", None, "vocab")
@@ -377,7 +411,7 @@ def init_cache(cfg, batch: int, max_len: int, dtype=None, *,
                     "v": jnp.zeros((cfg.repeats, batch, max_len, hkv, hd), dtype),
                 }
             }
-        elif kind == "mamba2":
+        elif kind in ("mamba2", "mamba2_moe"):
             c = {"mamba": jax.tree_util.tree_map(
                 lambda x: jnp.broadcast_to(x, (cfg.repeats,) + x.shape),
                 R.mamba2_cache(cfg, batch, dtype),
@@ -415,7 +449,7 @@ def prefill(params, batch, cfg, caches, lc: LogicalConstraints = NULL_CONSTRAINT
 def prefill_chunk(
     params, batch, cfg, caches, start, length,
     lc: LogicalConstraints = NULL_CONSTRAINTS, block_tables=None,
-    all_logits: bool = False,
+    all_logits: bool = False, with_aux: bool = False,
 ):
     """One chunk of an incremental prefill: run ``batch["tokens"]`` (B,C)
     through the stack as positions ``start .. start+length``, writing the
@@ -440,7 +474,10 @@ def prefill_chunk(
     identical to what a sequential ``decode_step`` at ``start + r`` would
     produce after consuming the same tokens. Positions past ``length``
     hold garbage (their cache writes and state advance are masked, their
-    logits are not)."""
+    logits are not).
+
+    ``with_aux=True`` appends the stack's summed per-layer counters (the
+    MoE layers' ``moe_routed`` and ``moe_busiest``) as a third output."""
     x = _embed_inputs(params, batch, cfg, lc)
     B, C, _ = x.shape
     start = jnp.broadcast_to(jnp.asarray(start, jnp.int32).reshape(-1), (B,))
@@ -448,24 +485,25 @@ def prefill_chunk(
     offs = jnp.arange(C, dtype=jnp.int32)[None, :]
     seq_mask = offs < length[:, None]
     positions = start[:, None] + offs
-    x, new_caches, _ = _run_stack(
+    x, new_caches, aux = _run_stack(
         params, x, cfg, positions=positions, lc=lc, caches=caches,
         cache_len=start + length, seq_mask=seq_mask, cache_attend=True,
         block_tables=block_tables,
     )
     x = rmsnorm(x, params["norm_f"]["scale"], cfg.norm_eps, cfg.zero_centered_norm)
     if all_logits:
-        return _logits(params, x, cfg, lc), new_caches
-    x_last = jnp.take_along_axis(
-        x, jnp.maximum(length - 1, 0)[:, None, None], axis=1
-    )  # (B,1,d)
-    logits = _logits(params, x_last, cfg, lc)
-    return logits[:, 0], new_caches
+        out = (_logits(params, x, cfg, lc), new_caches)
+    else:
+        x_last = jnp.take_along_axis(
+            x, jnp.maximum(length - 1, 0)[:, None, None], axis=1
+        )  # (B,1,d)
+        out = (_logits(params, x_last, cfg, lc)[:, 0], new_caches)
+    return out + (aux,) if with_aux else out
 
 
 def decode_step(
     params, tokens, pos, cfg, caches, lc: LogicalConstraints = NULL_CONSTRAINTS,
-    frontend=None, active=None, block_tables=None,
+    frontend=None, active=None, block_tables=None, with_aux: bool = False,
 ):
     """One decode step. tokens: (B,1) int32; pos: () scalar or (B,) vector of
     per-slot positions — continuous batching attaches requests mid-flight, so
@@ -475,17 +513,18 @@ def decode_step(
     ``block_tables``: optional (B, n_logical) int32 — paged attention-cache
     layout (``init_cache(..., paged=True)``); the slot's token writes and
     the decode attention both address the shared pool through it.
-    Returns (logits (B,V), new_caches)."""
+    Returns (logits (B,V), new_caches), and the stack's summed per-layer
+    counters with ``with_aux=True`` (see ``prefill_chunk``)."""
     batch = {"tokens": tokens, "frontend": frontend}
     x = _embed_inputs(params, batch, cfg, lc)
     B = x.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     positions = jnp.broadcast_to(pos.reshape(-1, 1), (B, 1))
     seq_mask = None if active is None else jnp.asarray(active).reshape(B, 1)
-    x, new_caches, _ = _run_stack(
+    x, new_caches, aux = _run_stack(
         params, x, cfg, positions=positions, lc=lc, caches=caches,
         cache_len=pos + 1, seq_mask=seq_mask, block_tables=block_tables,
     )
     x = rmsnorm(x, params["norm_f"]["scale"], cfg.norm_eps, cfg.zero_centered_norm)
     logits = _logits(params, x, cfg, lc)
-    return logits[:, 0], new_caches
+    return (logits[:, 0], new_caches) + ((aux,) if with_aux else ())

@@ -22,6 +22,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
+from repro.configs.base import RECURRENT_KINDS
 from repro.distributed import sharding as SH
 from repro.layers.common import LogicalConstraints
 from repro.models import transformer as T
@@ -321,6 +322,14 @@ def _sample_tokens(logits, rng_keys, positions, *, greedy, temperature,
     return jax.vmap(jax.random.categorical)(use, lg).astype(jnp.int32)
 
 
+def _moe_counters(aux) -> tuple:
+    """A MoE model's step counters (the stack's ``aux``, third output of
+    ``decode_step`` / ``prefill_chunk`` with ``with_aux=True``) as one more
+    output: a (2,) int32 ``[moe_routed, moe_busiest]`` summed over the
+    step's MoE layers (``layers.ffn.moe_dropless``)."""
+    return (jnp.stack([aux["moe_routed"], aux["moe_busiest"]]).astype(jnp.int32),)
+
+
 def make_serve_decode_step(cfg, mesh, *, paged=False, greedy=True,
                            temperature=1.0, top_k=None):
     """Continuous-batching decode: per-slot positions + active mask.
@@ -339,8 +348,10 @@ def make_serve_decode_step(cfg, mesh, *, paged=False, greedy=True,
     NaN/Inf sentinel: ``bad[i]`` is True when slot ``i``'s logits contain
     a non-finite value — it rides the deferred token readback for free
     (one extra (B,) bool per flush), and the scheduler retries flagged
-    requests instead of streaming garbage."""
+    requests instead of streaming garbage. A model with MoE layers returns
+    its step's MoE counters last (``_moe_counters``)."""
     lc = LogicalConstraints(mesh, SH.activation_rules(cfg, mesh))
+    moe = cfg.moe is not None
     sample = functools.partial(
         _sample_tokens, greedy=greedy, temperature=temperature, top_k=top_k,
         vocab=cfg.vocab,
@@ -361,22 +372,23 @@ def make_serve_decode_step(cfg, mesh, *, paged=False, greedy=True,
             (B, n_logical) int32; rng_keys: (B,2) uint32 (static per slot
             — the sampling key is folded with the position); fault_mask:
             (B,) bool."""
-            logits, new_caches = T.decode_step(
+            out = T.decode_step(
                 params, tokens, pos, cfg, caches, lc, active=active,
-                block_tables=block_tables,
+                block_tables=block_tables, with_aux=moe,
             )
-            tok, bad = _poison_and_sample(logits, fault_mask, rng_keys, pos)
-            return tok[:, None], bad, new_caches
+            tok, bad = _poison_and_sample(out[0], fault_mask, rng_keys, pos)
+            return (tok[:, None], bad, out[1]) + (_moe_counters(out[2]) if moe else ())
     else:
         def decode_step(params, tokens, pos, active, caches, rng_keys,
                         fault_mask):
             """tokens: (B,1) int32; pos: (B,) int32; active: (B,) bool;
             fault_mask: (B,) bool."""
-            logits, new_caches = T.decode_step(
-                params, tokens, pos, cfg, caches, lc, active=active
+            out = T.decode_step(
+                params, tokens, pos, cfg, caches, lc, active=active,
+                with_aux=moe,
             )
-            tok, bad = _poison_and_sample(logits, fault_mask, rng_keys, pos)
-            return tok[:, None], bad, new_caches
+            tok, bad = _poison_and_sample(out[0], fault_mask, rng_keys, pos)
+            return (tok[:, None], bad, out[1]) + (_moe_counters(out[2]) if moe else ())
 
     return decode_step
 
@@ -394,8 +406,10 @@ def make_prefill_chunk_step(cfg, mesh, *, paged=False, greedy=True,
     the other slots' state passes through untouched. Paged attention pools
     are passed whole: the chunk writes only the pages its block-table row
     owns, so it commutes with in-flight decode dispatches exactly like the
-    dense slot-sliced write does."""
+    dense slot-sliced write does. A model with MoE layers returns the
+    chunk's MoE counters last (``_moe_counters``)."""
     lc = LogicalConstraints(mesh, SH.activation_rules(cfg, mesh))
+    moe = cfg.moe is not None
     sample = functools.partial(
         _sample_tokens, greedy=greedy, temperature=temperature, top_k=top_k,
         vocab=cfg.vocab,
@@ -441,15 +455,16 @@ def make_prefill_chunk_step(cfg, mesh, *, paged=False, greedy=True,
             jax.lax.dynamic_slice_in_dim(block_tables, slot, 1, axis=0)
             if paged else None
         )
-        logits, new_slot = T.prefill_chunk(
+        out = T.prefill_chunk(
             params, {"tokens": tokens}, cfg, slot_caches, start, length, lc,
-            block_tables=tbl_row,
+            block_tables=tbl_row, with_aux=moe,
         )
+        logits = out[0]
         key_row = jax.lax.dynamic_slice_in_dim(rng_keys, slot, 1, axis=0)
         next_tok = sample(logits, key_row, start + length - 1)
         bad = ~jnp.all(jnp.isfinite(logits), axis=-1)
-        new_caches = _scatter_back(caches, new_slot, slot)
-        return next_tok, bad, new_caches
+        new_caches = _scatter_back(caches, out[1], slot)
+        return (next_tok, bad, new_caches) + (_moe_counters(out[2]) if moe else ())
 
     if paged:
         return chunk_step
@@ -614,9 +629,7 @@ def _serve_step_fns(cfg, mesh, statics: _StepStatics):
     )
     verify = None
     if statics.spec_decode:
-        two_pass = any(
-            kind in ("mamba2", "mlstm", "slstm") for kind in cfg.pattern
-        )
+        two_pass = any(kind in RECURRENT_KINDS for kind in cfg.pattern)
         verify = jax.jit(
             make_spec_verify_step(
                 cfg, mesh, greedy=statics.greedy,
@@ -631,6 +644,20 @@ def _serve_step_fns(cfg, mesh, statics: _StepStatics):
         cow,
         verify,
     )
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _reset_rows(caches, fresh, idx):
+    """Recurrent-state rows ``idx`` (slots; an index past the batch writes
+    nothing) set to ``fresh`` (one batch-1 leaf a recurrent cache entry,
+    None for the attention caches), in place."""
+    flat, treedef = jax.tree_util.tree_flatten(caches)
+    leaves = [
+        leaf if f is None
+        else leaf.at[:, idx].set(f.astype(leaf.dtype), mode="drop")
+        for leaf, f in zip(flat, fresh)
+    ]
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 class PageAllocator:
@@ -1191,6 +1218,12 @@ class BatchScheduler:
             )[0]
         ]
         self._has_recurrent = any(l is not None for l in self._fresh_state)
+        if self._has_recurrent:
+            # compiled here, in set-up, with no slot index in range: once
+            # for the caches as made, once for them as placed on the mesh,
+            # as every step hands them back, so a reuse compiles nothing
+            self._reset_slots([])
+            self._reset_slots([])
         self._dirty: set[int] = set()  # slots whose state may be non-fresh
         self.tokens = jnp.zeros((scfg.batch, 1), jnp.int32)
         self.queue: list[dict] = []    # admission queue: priority, FIFO within
@@ -1215,6 +1248,9 @@ class BatchScheduler:
         # pending readbacks: (device tokens (n,1), device bad-sentinel (n,),
         # row->request map); flushed in a single device_get
         self._pending: list[tuple[Any, Any, list[dict | None]]] = []
+        # (dispatch kind, tokens, device counters) kept while a capture
+        # runs, for the next flush's readback (``_keep_moe_counters``)
+        self._moe_counts: list[tuple[str, int, Any]] = []
         # speculative decode: per-slot next input token, host-side. Spec
         # ticks trade the deferred-readback pipeline for width — the accept
         # count must reach the host before the next tick can plan drafts,
@@ -1694,15 +1730,13 @@ class BatchScheduler:
         request's final state."""
         if not self._has_recurrent:
             return
-        idx = jnp.asarray(slots, jnp.int32)
+        # one shape whatever the number of slots (the rest of the index
+        # row is out of range and dropped): one program, compiled once
+        idx = np.full(self.scfg.batch, self.scfg.batch, np.int32)
+        idx[: len(slots)] = slots
         with compat.use_mesh(self.mesh):
-            flat, treedef = jax.tree_util.tree_flatten(self.caches)
-            leaves = [
-                leaf if fresh is None
-                else leaf.at[:, idx].set(fresh.astype(leaf.dtype))
-                for leaf, fresh in zip(flat, self._fresh_state)
-            ]
-        self.caches = jax.tree_util.tree_unflatten(treedef, leaves)
+            self.caches = _reset_rows(self.caches, self._fresh_state,
+                                      jnp.asarray(idx))
 
     # -- fault recovery (retry / quarantine / injection) -----------------
 
@@ -2230,7 +2264,8 @@ class BatchScheduler:
         )
         if self.scfg.paged:
             args += (self._tables_device(),)
-        next_tok, bad, self.caches = self.prefill(*args, self.rng_keys)
+        next_tok, bad, self.caches, *moe = self.prefill(*args, self.rng_keys)
+        self._keep_moe_counters("prefill", moe, L)
         task["done"] = start + L
         self.stats["prefill_chunks"] += 1
         if task["done"] >= len(prompt):
@@ -2329,12 +2364,26 @@ class BatchScheduler:
         with span("serve.flush"):
             self._flush_pending()
 
+    def _keep_moe_counters(self, dispatch: str, moe: list, tokens: int) -> None:
+        """While a capture runs, keep a dispatch's MoE counters (still on
+        the device) and its token count for the next flush's readback."""
+        if moe and jax.profiler.TraceAnnotation.is_enabled():
+            self._moe_counts.append((dispatch, tokens, moe[0]))
+
     def _flush_pending(self) -> None:
         pending, self._pending = self._pending, []
+        counts, self._moe_counts = self._moe_counts, []
         with span("serve.readback"):
-            host = jax.device_get(
-                [[toks, bad] for toks, bad, _ in pending]
+            host, moe = jax.device_get(
+                [[[toks, bad] for toks, bad, _ in pending],
+                 [c for _, _, c in counts]]
             )  # single transfer
+            # the MoE counters are known once the device has run: each
+            # dispatch's land here, one span each
+            for (dispatch, tokens, _), (routed, busiest) in zip(counts, moe):
+                with span("serve.moe", dispatch=dispatch, tokens=tokens,
+                          moe_routed=int(routed), moe_busiest=int(busiest)):
+                    pass
         poisoned: list[dict] = []
         poisoned_ids: set = set()
         for (toks, bad), (_, _, reqmap) in zip(host, pending):
@@ -2727,11 +2776,12 @@ class BatchScheduler:
                 pages = (self._decode_pages() if self.scfg.paged
                          and jax.profiler.TraceAnnotation.is_enabled() else {})
                 with span("serve.decode", **pages):
-                    self.tokens, bad_dev, self.caches = self.decode(
+                    self.tokens, bad_dev, self.caches, *moe = self.decode(
                         self.params, self.tokens, pos_now,
                         *args, self.rng_keys, fault_mask,
                     )
                 dispatch_s = time.perf_counter() - t0
+                self._keep_moe_counters("decode", moe, int(active.sum()))
                 self.stats["decode_steps"] += 1
                 if self.stats["prefill_chunks"] > chunks_at_tick_start:
                     # prefill work ran before this tick's decode dispatch:

@@ -53,6 +53,7 @@ def test_full_config_matches_assignment(arch):
         "command-r-35b": (40, 8192, 64, 8, 22528, 256000),
         "llava-next-mistral-7b": (32, 4096, 32, 8, 14336, 32000),
         "xlstm-350m": (24, 1024, 4, 4, 0, 50304),
+        "granite-4.0-h-small": (40, 4096, 32, 8, 768, 100352),
     }[arch]
     L, d, hq, hkv, dff, vocab = spec
     assert cfg.n_layers == L
@@ -66,6 +67,9 @@ def test_full_config_matches_assignment(arch):
         assert cfg.moe.n_experts == 128 and cfg.moe.top_k == 8
     if arch == "zamba2-2.7b":
         assert cfg.ssm.d_state == 64
+    if arch == "granite-4.0-h-small":
+        assert cfg.moe.n_experts == 72 and cfg.moe.top_k == 10
+        assert cfg.ssm.d_state == 128
 
 
 @pytest.mark.parametrize("arch", list_archs())
@@ -108,6 +112,7 @@ def test_param_counts_are_plausible():
         "xlstm-350m": (0.25e9, 0.6e9),  # full qkv vs block-diag: +0.1B
         "zamba2-2.7b": (2.2e9, 3.4e9),
         "hubert-xlarge": (0.8e9, 1.2e9),
+        "granite-4.0-h-small": (30e9, 34e9),
     }
     for arch, (lo, hi) in expectations.items():
         n = get_config(arch).param_count()

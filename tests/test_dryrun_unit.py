@@ -14,7 +14,8 @@ from repro.launch.mesh import make_host_mesh
 
 
 def test_40_cells_defined():
-    assert len(list_archs()) == 10
+    # the ten assigned architectures and granite-4.0-h-small
+    assert len(list_archs()) == 11
     assert len(SHAPES) == 4
 
 
@@ -28,12 +29,12 @@ def test_skip_rules():
     dense = get_config("tinyllama-1.1b")
     assert "full-attention" in skip_reason(dense, SHAPE_BY_NAME["long_500k"])
 
-    for arch in ("zamba2-2.7b", "xlstm-350m"):
+    for arch in ("zamba2-2.7b", "xlstm-350m", "granite-4.0-h-small"):
         assert skip_reason(get_config(arch), SHAPE_BY_NAME["long_500k"]) is None
 
 
 def test_expected_cell_counts():
-    """40 cells: count runnable vs skipped explicitly."""
+    """44 cells: count runnable vs skipped explicitly."""
     runnable = skipped = 0
     for arch in list_archs():
         cfg = get_config(arch)
@@ -42,9 +43,10 @@ def test_expected_cell_counts():
                 skipped += 1
             else:
                 runnable += 1
-    assert runnable + skipped == 40
-    # 10 train + 10 prefill + 9 decode (hubert out) + 2 long (zamba, xlstm)
-    assert runnable == 31
+    assert runnable + skipped == 44
+    # 11 train + 11 prefill + 10 decode (hubert out) + 3 long (zamba,
+    # xlstm, granite)
+    assert runnable == 35
     assert skipped == 9
 
 

@@ -412,3 +412,46 @@ def test_flash_fully_masked_block_with_negative_scores():
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-4)
         g = jax.grad(loss)(q)
         assert np.isfinite(np.asarray(g)).all()
+
+
+# ---------------------------------------------------------------------------
+# a score scale from the configuration, at GQA group 4
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_kernels_take_the_score_scale(kernel, dtype):
+    """Both paged kernels at Granite's score scale (1/128, not 1/sqrt(D))
+    and its GQA group of 4 query heads a KV head, in interpret mode,
+    against the oracles given the same scale; the decode case spans two
+    256-row blocks of the group-4 ``pages_per_step``. The default scale
+    gives another result, so the scale is not ignored."""
+    from repro.kernels.paged_attention.kernel import (
+        pages_per_step, paged_prefill_attention_pallas)
+    from repro.kernels.paged_attention.ref import paged_prefill_attention_reference
+
+    scale = 0.0078125
+    assert pages_per_step(16, 40, 2, 128, 4, 2) == 16
+    if kernel == "decode":
+        q, kp, vp, tbl, lens, qpos = _paged_case(
+            3, 8, 2, 128, 16, 40, 96, (530, 257, 17), dtype, seed=5)
+        args = dict(q_position=qpos, cache_len=lens)
+        run = lambda s: paged_attention_pallas(
+            q, kp, vp, tbl, **args, interpret=True, sm_scale=s)
+        ref = paged_attention_reference(q, kp, vp, tbl, **args, sm_scale=scale)
+    else:
+        q, kp, vp, tbl, qpos, lens = _paged_prefill_case(
+            2, 16, 8, 2, 128, 16, 6, 14, (0, 48), dtype, seed=5)
+        args = dict(q_positions=qpos, cache_len=lens, causal=True)
+        run = lambda s: paged_prefill_attention_pallas(
+            q, kp, vp, tbl, **args, interpret=True, sm_scale=s)
+        ref = paged_prefill_attention_reference(q, kp, vp, tbl, **args,
+                                                sm_scale=scale)
+    out = run(scale)
+    # one bf16 rounding of the output in the bf16 case, as above
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=tol, rtol=tol)
+    default = np.asarray(run(None), np.float32)
+    assert np.max(np.abs(default - np.asarray(out, np.float32))) > 10 * tol

@@ -25,16 +25,19 @@ from repro.kernels.paged_attention.kernel import (
 BATCH, PAGE, N_LOGICAL, POOL, CHUNK = 8, 16, 2048 // 16, 1024, 32
 
 WIDTHS = {
-    # name: (Hq, Hkv, D, window, softcap)
+    # name: (Hq, Hkv, D, window, softcap[, score scale])
     "tinyllama-1.1b": (32, 4, 64, None, None),
     "gemma2-2b": (8, 4, 256, 4096, 50.0),
     "glm4-9b": (32, 2, 128, None, None),
+    # GQA group 4 and the configuration's score scale (1/128)
+    "granite-4.0-h-small": (32, 8, 128, None, None, 0.0078125),
 }
 
 # the decode kernel at a serving cell's own shape where one is benchmarked:
 # (slots, logical pages, pool pages); GLM-4-9B's is 32 slots, max_len 5120
-# in 16-token pages and a 7,680-page pool
-DECODE_SHAPES = {"glm4-9b": (32, 5120 // 16, 7680)}
+# in 16-token pages and a 7,680-page pool, Granite's 64 slots and 20,480
+DECODE_SHAPES = {"glm4-9b": (32, 5120 // 16, 7680),
+                 "granite-4.0-h-small": (64, 5120 // 16, 20480)}
 
 
 @pytest.fixture(scope="module")
@@ -73,7 +76,7 @@ def _shape(shape, dtype, sharding):
 
 def _compile(kernel, q_len, widths, one_chip,
              shape=(BATCH, N_LOGICAL, POOL)):
-    Hq, Hkv, D, window, softcap = widths
+    Hq, Hkv, D, window, softcap, *scale = widths
     batch, n_logical, pool = shape
     pages = _shape((pool, PAGE, Hkv, D), jnp.bfloat16, one_chip)
     if kernel is paged_attention_pallas:
@@ -83,7 +86,8 @@ def _compile(kernel, q_len, widths, one_chip,
 
     def call(q, k_pages, v_pages, tables, lens, pos):
         return kernel(q, k_pages, v_pages, tables, cache_len=lens,
-                      window=window, softcap=softcap, **{pos_name: pos})
+                      window=window, softcap=softcap, **{pos_name: pos},
+                      **({"sm_scale": scale[0]} if scale else {}))
 
     return jax.jit(call).lower(
         _shape((batch, q_len, Hq, D), jnp.bfloat16, one_chip), pages, pages,
@@ -111,3 +115,24 @@ def test_paged_prefill_kernel_compiles_for_v5e(arch, one_chip, no_compile_cache)
     compiled = _compile(paged_prefill_attention_pallas, CHUNK, WIDTHS[arch],
                         one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_held_expert_layer_compiles_for_v5e(one_chip, no_compile_cache):
+    """Granite's MoE layer at its widths (4096 in, 72 routed, 10 a token,
+    18 held, experts of 768, a shared expert of 1536) over a 64-slot decode
+    batch: the held experts run as the chip's grouped matmul."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.layers import ffn as FFN
+    from repro.layers.common import abstract_params
+
+    cfg = get_config("granite-4.0-h-small")
+    cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, held=(0, 18)))
+    params = jax.tree_util.tree_map(
+        lambda a: _shape(a.shape, a.dtype, one_chip),
+        abstract_params(FFN.moe_params(cfg), jnp.bfloat16))
+    compiled = jax.jit(lambda p, x: FFN.moe_dropless(p, x, cfg)).lower(
+        params, _shape((64, 1, 4096), jnp.bfloat16, one_chip)).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
