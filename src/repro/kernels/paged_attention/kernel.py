@@ -1,6 +1,12 @@
 """Paged attention TPU kernels: decode (one query a slot) and chunked
 prefill, both reading K/V pages through the block table.
 
+Both kernels address the stacked pool the model step keeps, ``(R, Hkv,
+P, page, D)`` for ``R`` layers, in place: the layer is one more
+scalar-prefetch operand, so no layer's pages are sliced out, relaid out or
+copied back around a call. Head-major pages make one page of every KV head
+a set of contiguous ``(page, D)`` tiles.
+
 The decode kernel's grid is one step a slot. Inside it a loop walks the
 slot's live pages only — below ``cache_len[b]``, inside the sliding
 window, with an allocated (``>= 0``) table entry — ``pages_per_step`` at
@@ -21,8 +27,8 @@ each kv head's G query heads into one (G, d) q tile.
 ``paged_prefill_attention_pallas`` serves multi-token (S>1)
 chunked-prefill reads with the table as the index map: its grid is
 ``(batch, kv_heads, logical_pages)`` and the K/V BlockSpecs fetch
-``pool[table[b, p]]`` a step, pages at or past ``cache_len`` skipped with
-``pl.when``. The q tile is a whole prefill chunk per kv head and
+``pool[layer, h, table[b, p]]`` a step, pages at or past ``cache_len``
+skipped with ``pl.when``. The q tile is a whole prefill chunk per kv head and
 causality is masked per (row, key) element, so the prefill path attends
 the block table directly instead of gathering a slot's pages into a dense
 view per chunk.
@@ -74,24 +80,25 @@ def pages_per_step(page_size: int, n_logical: int, kv_heads: int,
 
 
 def _paged_kernel(
-    tbl_ref, lens_ref, qpos_ref,  # scalar-prefetch (SMEM)
+    tbl_ref, lens_ref, qpos_ref, layer_ref,  # scalar-prefetch (SMEM)
     q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref, *,
     page_size: int, n_logical: int, pps: int, window: int | None,
     softcap: float | None, sm_scale: float,
 ):
     """One slot: walk its live pages, ``pps`` a loop step.
 
-    Refs: q_ref/o_ref (Hkv, G, d) VMEM; k_hbm/v_hbm (Hkv, P, page, d) in
-    HBM; k_buf/v_buf (2, Hkv, pps*page, d) VMEM, the two halves of the
-    double buffer; sems one DMA semaphore a half. Scratch: acc (Hkv, G, d)
-    f32, m/l (Hkv, G, 1) f32. A page is live when it lies below
+    Refs: q_ref/o_ref (Hkv, G, d) VMEM; k_hbm/v_hbm (R, Hkv, P, page, d)
+    in HBM, read at layer ``layer_ref[0]``; k_buf/v_buf
+    (2, Hkv, pps*page, d) VMEM, the two halves of the double buffer; sems
+    one DMA semaphore a half. Scratch: acc (Hkv, G, d) f32, m/l (Hkv, G, 1)
+    f32. A page is live when it lies below
     ``cache_len``, inside the window, and its table entry is allocated;
     only live pages are fetched, and a block with none is not computed.
     """
     b = pl.program_id(0)
     hkv = q_ref.shape[0]
     rows = pps * page_size
-    length, qpos = lens_ref[b], qpos_ref[b]
+    length, qpos, layer = lens_ref[b], qpos_ref[b], layer_ref[0]
     hi = jnp.minimum((length + page_size - 1) // page_size, n_logical)
     lo = 0
     if window is not None and window > 0:
@@ -108,7 +115,7 @@ def _paged_kernel(
         return pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
 
     def copies(phys, j, half):
-        return [pltpu.make_async_copy(src.at[:, phys],
+        return [pltpu.make_async_copy(src.at[layer, :, phys],
                                       buf.at[half, :, rows_of(j)],
                                       sems.at[half])
                 for src, buf in ((k_hbm, k_buf), (v_hbm, v_buf))]
@@ -217,7 +224,7 @@ def _paged_kernel(
 
 
 def _paged_prefill_kernel(
-    tbl_ref, lens_ref, start_ref,  # scalar-prefetch (also feeds the index maps)
+    tbl_ref, lens_ref, start_ref, layer_ref,  # scalar-prefetch (index maps)
     q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     page_size: int, n_logical: int, n_chunk: int, causal: bool,
     window: int | None, softcap: float | None, sm_scale: float,
@@ -279,17 +286,36 @@ def _paged_prefill_kernel(
         o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
+def _lane_pools(k_pages, v_pages, layer):
+    """The pools as the kernels read them, and the layer to read. Where
+    the head dim fills whole 128-lane tiles, the stacked pools themselves,
+    addressed in place; else the layer's pages alone, lane-padded, as the
+    one layer of a copy made for this call."""
+    D = k_pages.shape[-1]
+    d_pad = -(-D // 128) * 128
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    if d_pad == D:
+        return k_pages, v_pages, layer
+
+    def pad(pages):
+        one = jax.lax.dynamic_index_in_dim(pages, layer[0], 0, keepdims=True)
+        return jnp.pad(one, ((0, 0),) * 4 + ((0, d_pad - D),))
+
+    return pad(k_pages), pad(v_pages), jnp.zeros((1,), jnp.int32)
+
+
 def paged_prefill_attention_pallas(
-    q, k_pages, v_pages, block_tables, *, q_positions, cache_len,
+    q, k_pages, v_pages, block_tables, *, layer, q_positions, cache_len,
     causal: bool = True, window: int | None = None,
     softcap: float | None = None, interpret: bool = False,
     sm_scale: float | None = None,
 ):
-    """q: (B,C,Hq,D) one prefill chunk per row; k_pages/v_pages:
-    (P, page, Hkv, D); block_tables: (B, n_logical) int32 (``-1`` =
-    unallocated); q_positions: (B,C) with row c at ``q_positions[:,0]+c``
-    (the chunked-prefill contract: chunks are contiguous); cache_len: ()
-    or (B,) written tokens incl. this chunk; sm_scale: the score scale,
+    """q: (B,C,Hq,D) one prefill chunk per row; k_pages/v_pages: the
+    stacked pools (R, Hkv, P, page, D), read at ``layer`` (() int);
+    block_tables: (B, n_logical) int32 (``-1`` = unallocated);
+    q_positions: (B,C) with row c at ``q_positions[:,0]+c`` (the
+    chunked-prefill contract: chunks are contiguous); cache_len: () or
+    (B,) written tokens incl. this chunk; sm_scale: the score scale,
     1/sqrt(D) when None. Returns (B,C,Hq,D).
 
     Grid is ``(batch, kv_heads, logical_pages)``: the chunk's queries
@@ -297,7 +323,7 @@ def paged_prefill_attention_pallas(
     instead of materializing a dense (B, Smax) view.
     """
     B, C, Hq, D = q.shape
-    P, page, Hkv, _ = k_pages.shape
+    _, Hkv, P, page, _ = k_pages.shape
     nL = block_tables.shape[-1]
     G = Hq // Hkv
     if sm_scale is None:
@@ -308,10 +334,7 @@ def paged_prefill_attention_pallas(
     # head h -> (h // G, h % G) as in the dense layout; tile row = g*C + c
     qh = qh.reshape(B, C, Hkv, G, d_pad).transpose(0, 2, 3, 1, 4)
     qh = qh.reshape(B, Hkv, G * C, d_pad)
-    kh = jnp.pad(k_pages, ((0, 0), (0, 0), (0, 0), (0, d_pad - D)))
-    vh = jnp.pad(v_pages, ((0, 0), (0, 0), (0, 0), (0, d_pad - D)))
-    kh = kh.transpose(2, 0, 1, 3)  # (Hkv, P, page, d)
-    vh = vh.transpose(2, 0, 1, 3)
+    kh, vh, layer = _lane_pools(k_pages, v_pages, layer)
 
     tbl = jnp.clip(block_tables.astype(jnp.int32), 0, P - 1)
     lens = jnp.broadcast_to(
@@ -324,27 +347,18 @@ def paged_prefill_attention_pallas(
         page_size=page, n_logical=nL, n_chunk=C, causal=causal,
         window=window, softcap=softcap, sm_scale=sm_scale,
     )
+    q_spec = pl.BlockSpec((None, None, G * C, d_pad),
+                          lambda b, h, p, *_: (b, h, 0, 0))
+    page_spec = pl.BlockSpec(
+        (None, None, None, page, d_pad),
+        lambda b, h, p, tbl, lens, start, layer: (
+            layer[0], h, tbl[b, p], 0, 0),
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, Hkv, nL),
-        in_specs=[
-            pl.BlockSpec(
-                (None, None, G * C, d_pad),
-                lambda b, h, p, tbl, lens, start: (b, h, 0, 0),
-            ),
-            pl.BlockSpec(
-                (None, None, page, d_pad),
-                lambda b, h, p, tbl, lens, start: (h, tbl[b, p], 0, 0),
-            ),
-            pl.BlockSpec(
-                (None, None, page, d_pad),
-                lambda b, h, p, tbl, lens, start: (h, tbl[b, p], 0, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (None, None, G * C, d_pad),
-            lambda b, h, p, tbl, lens, start: (b, h, 0, 0),
-        ),
+        in_specs=[q_spec, page_spec, page_spec],
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((G * C, d_pad), jnp.float32),
             pltpu.VMEM((G * C, 1), jnp.float32),
@@ -356,28 +370,29 @@ def paged_prefill_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G * C, d_pad), q.dtype),
         interpret=interpret,
-    )(tbl, lens, start, qh, kh, vh)
+    )(tbl, lens, start, layer, qh, kh, vh)
     out = out.reshape(B, Hkv, G, C, d_pad).transpose(0, 3, 1, 2, 4)
     return out.reshape(B, C, Hq, d_pad)[..., :D]
 
 
 def paged_attention_pallas(
-    q, k_pages, v_pages, block_tables, *, q_position, cache_len,
+    q, k_pages, v_pages, block_tables, *, layer, q_position, cache_len,
     window: int | None = None, softcap: float | None = None,
     interpret: bool = False, sm_scale: float | None = None,
 ):
-    """q: (B,1,Hq,D); k_pages/v_pages: (P, page, Hkv, D); block_tables:
+    """q: (B,1,Hq,D); k_pages/v_pages: the stacked pools
+    (R, Hkv, P, page, D), read at ``layer`` (() int); block_tables:
     (B, n_logical) int32 (``-1`` = unallocated); sm_scale: the score
     scale, 1/sqrt(D) when None. Returns (B,1,Hq,D); a slot with no live
     key (``cache_len`` 0, or no allocated page below it) gets zeros.
 
-    Head dim is padded to the 128-lane width; the pool is transposed to
-    (Hkv, P, page, d) so one copy fetches a page of every KV head. The
-    grid is one step a slot; inside it a loop walks the slot's live pages
-    ``pages_per_step`` at a time with double-buffered copies from HBM.
+    One copy fetches a page of every KV head straight from the stacked
+    pool. The grid is one step a slot; inside it a loop walks the slot's
+    live pages ``pages_per_step`` at a time with double-buffered copies
+    from HBM. A head dim short of the 128-lane width is padded to it.
     """
     B, _, Hq, D = q.shape
-    P, page, Hkv, _ = k_pages.shape
+    _, Hkv, _, page, _ = k_pages.shape
     nL = block_tables.shape[-1]
     G = Hq // Hkv
     if sm_scale is None:
@@ -387,10 +402,7 @@ def paged_attention_pallas(
 
     qh = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, d_pad - D)))
     qh = qh.reshape(B, Hkv, G, d_pad)  # head h -> (h // G, h % G), as dense
-    kh = jnp.pad(k_pages, ((0, 0), (0, 0), (0, 0), (0, d_pad - D)))
-    vh = jnp.pad(v_pages, ((0, 0), (0, 0), (0, 0), (0, d_pad - D)))
-    kh = kh.transpose(2, 0, 1, 3)  # (Hkv, P, page, d)
-    vh = vh.transpose(2, 0, 1, 3)
+    kh, vh, layer = _lane_pools(k_pages, v_pages, layer)
 
     tbl = block_tables.astype(jnp.int32)
     lens = jnp.broadcast_to(
@@ -407,7 +419,7 @@ def paged_attention_pallas(
     )
     qo_spec = pl.BlockSpec((None, Hkv, G, d_pad), lambda b, *_: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B,),
         in_specs=[
             qo_spec,
@@ -430,5 +442,5 @@ def paged_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, d_pad), q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(tbl, lens, qpos, qh, kh, vh)
+    )(tbl, lens, qpos, layer, qh, kh, vh)
     return out.reshape(B, Hq, d_pad)[:, None, :, :D]

@@ -29,7 +29,7 @@ from repro.kernels.paged_attention.ref import (
 )
 def paged_attention(
     q, k_pages, v_pages, block_tables, *,
-    q_position, cache_len,
+    layer, q_position, cache_len,
     window: int | None = None,
     softcap: float | None = None,
     impl: str = "auto",  # auto | pallas | interpret | reference
@@ -37,21 +37,23 @@ def paged_attention(
 ):
     """Single-position attention against a paged KV pool.
 
-    q: (B,1,Hq,D); k_pages/v_pages: (P, page_size, Hkv, D); block_tables:
-    (B, n_logical) int32, ``-1`` = unallocated; q_position/cache_len: ()
-    or (B,); ``sm_scale``: the score scale, 1/sqrt(D) when None. Returns
+    q: (B,1,Hq,D); k_pages/v_pages: the stacked pools
+    (R, Hkv, P, page_size, D), passed whole and read at ``layer`` (() int,
+    traced: one trace serves every layer); block_tables: (B, n_logical)
+    int32, ``-1`` = unallocated; q_position/cache_len: () or (B,);
+    ``sm_scale``: the score scale, 1/sqrt(D) when None. Returns
     (B,1,Hq,D) in q.dtype.
     """
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "reference"
     if impl == "reference":
         return paged_attention_reference(
-            q, k_pages, v_pages, block_tables,
+            q, k_pages, v_pages, block_tables, layer=layer,
             q_position=q_position, cache_len=cache_len,
             window=window, softcap=softcap, sm_scale=sm_scale,
         )
     return paged_attention_pallas(
-        q, k_pages, v_pages, block_tables,
+        q, k_pages, v_pages, block_tables, layer=layer,
         q_position=q_position, cache_len=cache_len,
         window=window, softcap=softcap, sm_scale=sm_scale,
         interpret=(impl == "interpret"),
@@ -65,7 +67,7 @@ def paged_attention(
 )
 def paged_prefill_attention(
     q, k_pages, v_pages, block_tables, *,
-    q_positions, cache_len,
+    layer, q_positions, cache_len,
     causal: bool = True,
     window: int | None = None,
     softcap: float | None = None,
@@ -77,8 +79,10 @@ def paged_prefill_attention(
     """Multi-token (S>1) chunked-prefill attention against a paged KV pool.
 
     q: (B,C,Hq,D) — one prefill chunk per row at positions ``q_positions``
-    (B,C) (contiguous: row c sits at ``q_positions[:,0] + c``); cache_len:
-    () or (B,) written tokens including this chunk. ``q_chunk``/``kv_chunk``
+    (B,C) (contiguous: row c sits at ``q_positions[:,0] + c``);
+    k_pages/v_pages: the stacked pools (R, Hkv, P, page_size, D), passed
+    whole and read at ``layer``; cache_len: () or (B,) written tokens
+    including this chunk. ``q_chunk``/``kv_chunk``
     are the reference path's flash chunk sizes — pass the model's so the
     reference stays bitwise identical to the dense-gather prefill (the
     sharing-on/off and paged-vs-dense token-identity guarantees); the
@@ -89,13 +93,13 @@ def paged_prefill_attention(
         impl = "pallas" if jax.default_backend() == "tpu" else "reference"
     if impl == "reference":
         return paged_prefill_attention_reference(
-            q, k_pages, v_pages, block_tables,
+            q, k_pages, v_pages, block_tables, layer=layer,
             q_positions=q_positions, cache_len=cache_len,
             causal=causal, window=window, softcap=softcap,
             q_chunk=q_chunk, kv_chunk=kv_chunk, sm_scale=sm_scale,
         )
     return paged_prefill_attention_pallas(
-        q, k_pages, v_pages, block_tables,
+        q, k_pages, v_pages, block_tables, layer=layer,
         q_positions=q_positions, cache_len=cache_len,
         causal=causal, window=window, softcap=softcap, sm_scale=sm_scale,
         interpret=(impl == "interpret"),

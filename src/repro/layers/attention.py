@@ -259,22 +259,23 @@ def _pad_axis(x, axis, size, value=0):
 
 
 def decode_attention_paged(
-    q, k_pages, v_pages, block_tables, *, q_position, cache_len,
+    q, k_pages, v_pages, block_tables, *, layer, q_position, cache_len,
     window: int | None = None, softcap: float | None = None,
     impl: str = "auto", sm_scale: float | None = None,
 ):
     """Single-position attention against a paged KV pool.
 
-    q: (B,1,Hq,D); k_pages/v_pages: (P, page_size, Hkv, D) shared pool;
-    block_tables: (B, n_logical) int32 — logical page j of slot b lives in
-    physical page ``block_tables[b, j]`` (-1 = unallocated). Routed through
+    q: (B,1,Hq,D); k_pages/v_pages: (R, Hkv, P, page_size, D) shared
+    stacked pool, read at ``layer``; block_tables: (B, n_logical) int32 —
+    logical page j of slot b lives in physical page ``block_tables[b, j]``
+    (-1 = unallocated). Routed through
     ``repro.kernels.paged_attention`` (Pallas on TPU, gather oracle
     elsewhere); the reference path is bitwise identical to
     ``decode_attention`` over the equivalent dense cache."""
     from repro.kernels.paged_attention.ops import paged_attention
 
     return paged_attention(
-        q, k_pages, v_pages, block_tables,
+        q, k_pages, v_pages, block_tables, layer=layer,
         q_position=q_position, cache_len=cache_len,
         window=window, softcap=softcap, impl=impl, sm_scale=sm_scale,
     )
@@ -343,13 +344,14 @@ def attention_block(
     params, x, cfg, *,
     positions, lc: LogicalConstraints = NULL_CONSTRAINTS,
     causal=True, window=None, cache=None, cache_len=None,
-    seq_mask=None, cache_attend=False, block_tables=None,
+    seq_mask=None, cache_attend=False, block_tables=None, layer=None,
 ):
     """Returns (out, new_cache). ``cache``: dict(k=(B,Smax,Hkv,D), v=...),
-    dict(k_pages=(P,page,Hkv,D), v_pages=...) for the paged layout (then
-    ``block_tables`` (B, n_logical) maps each row's logical pages to
-    physical pool pages), or None for full-sequence (training / prefill
-    without cache) mode.
+    dict(k_pages=(R,Hkv,P,page,D), v_pages=...) for the paged layout, or
+    None for full-sequence (training / prefill without cache) mode. The
+    paged pools come whole, every layer stacked, and this block writes and
+    reads layer ``layer`` of them in place; ``block_tables``
+    (B, n_logical) maps each row's logical pages to physical pool pages.
 
     ``positions`` is (B,S) and doubles as the per-slot cache write index —
     each batch row writes its k/v at its own offsets (continuous batching:
@@ -384,38 +386,39 @@ def attention_block(
 
     new_cache = None
     if cache is not None and "k_pages" in cache:
-        # paged layout: write through the block table into the shared pool,
-        # then attend through the table. Rows own disjoint physical pages
-        # (allocator invariant), so the flattened-pool scatter cannot
-        # collide across slots; entries that are masked, unallocated
-        # (table -1) or out of logical range push their write index past
-        # the pool end and are dropped.
+        # paged layout: write through the block table into layer ``layer``
+        # of the shared pool, in place, then attend through the table.
+        # Rows own disjoint physical pages (allocator invariant), so the
+        # scatter cannot collide across slots; entries that are masked,
+        # unallocated (table -1) or out of logical range push their page
+        # index past the pool end and are dropped.
         k_pool, v_pool = cache["k_pages"], cache["v_pages"]
-        Pp, psize = k_pool.shape[0], k_pool.shape[1]
+        Pp, psize = k_pool.shape[2], k_pool.shape[3]
         n_logical = block_tables.shape[1]
         pos0 = positions[:, 0] if positions.ndim == 2 else positions
         page_idx = positions // psize
         phys_page = jnp.take_along_axis(
             block_tables, jnp.clip(page_idx, 0, n_logical - 1), axis=1
         )
-        flat_pos = phys_page * psize + positions % psize
         valid = (phys_page >= 0) & (page_idx < n_logical)
         if seq_mask is not None:
             valid &= seq_mask
-        write_idx = jnp.where(valid, flat_pos, Pp * psize)
-        kc = k_pool.reshape(Pp * psize, hkv, hd).at[write_idx].set(
+        # index the heads too, one (B,S,Hkv) index a D-row: with the heads
+        # left as an update window, the compiler lays the pool out
+        # heads-minor and copies it to and from the kernels' layout
+        phys_page = jnp.where(valid, phys_page, Pp)[..., None]
+        row = (positions % psize)[..., None]
+        head = jnp.arange(hkv)
+        kc = k_pool.at[layer, head, phys_page, row].set(
             k.astype(k_pool.dtype), mode="drop"
         )
-        vc = v_pool.reshape(Pp * psize, hkv, hd).at[write_idx].set(
+        vc = v_pool.at[layer, head, phys_page, row].set(
             v.astype(v_pool.dtype), mode="drop"
         )
-        new_cache = {
-            "k_pages": kc.reshape(k_pool.shape),
-            "v_pages": vc.reshape(v_pool.shape),
-        }
+        new_cache = {"k_pages": kc, "v_pages": vc}
         if S == 1:
             o = decode_attention_paged(
-                q, new_cache["k_pages"], new_cache["v_pages"], block_tables,
+                q, kc, vc, block_tables, layer=layer,
                 q_position=pos0, cache_len=cache_len,
                 window=window, softcap=cfg.attn_softcap,
                 impl=cfg.paged_attn_impl, sm_scale=cfg.sm_scale,
@@ -430,7 +433,7 @@ def attention_block(
             )
 
             o = paged_prefill_attention(
-                q, new_cache["k_pages"], new_cache["v_pages"], block_tables,
+                q, kc, vc, block_tables, layer=layer,
                 q_positions=positions, cache_len=cache_len,
                 causal=causal, window=window, softcap=cfg.attn_softcap,
                 q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,

@@ -116,14 +116,16 @@ def _moe(moe_params, h, cfg, lc, *, serving, seq_mask):
 
 def _apply_slot(
     slot_params, kind: str, x, cfg, *, positions, lc, cache=None, cache_len=None,
-    seq_mask=None, cache_attend=False, block_tables=None,
+    seq_mask=None, cache_attend=False, block_tables=None, layer=None,
 ):
     """One block of the pattern. Returns (x, new_cache, aux).
 
     ``seq_mask`` (B,S) marks valid positions: masked positions neither write
     the KV cache nor advance recurrent state (continuous batching: chunk
     padding and inactive decode slots). ``cache_attend`` routes S>1 attention
-    against the written cache (chunked prefill) instead of in-chunk."""
+    against the written cache (chunked prefill) instead of in-chunk.
+    ``layer`` is the block's row of the stacked paged pools, which come
+    whole (see ``_run_stack``)."""
     aux: dict[str, Any] = {}
     h = rmsnorm(
         x, slot_params["norm_in"]["scale"], cfg.norm_eps, cfg.zero_centered_norm
@@ -137,7 +139,7 @@ def _apply_slot(
             causal=not cfg.encoder_only, window=window,
             cache=att_cache, cache_len=cache_len,
             seq_mask=seq_mask, cache_attend=cache_attend,
-            block_tables=block_tables,
+            block_tables=block_tables, layer=layer,
         )
         # constrain BEFORE the residual add: the TP partial sums then lower
         # to reduce-scatter onto the seq-sharded residual instead of a full
@@ -214,24 +216,35 @@ def _run_stack(params, x, cfg, *, positions, lc, caches=None, cache_len=None,
     """Scan pattern x repeats. caches: {slot_name: stacked cache} or None.
     ``block_tables`` (B, n_logical) selects the paged attention-cache
     layout (shared across layers — allocation is per token position).
-    Returns (x, new_caches, aux_totals)."""
+    Returns (x, new_caches, aux_totals).
+
+    Dense and recurrent caches are sliced a layer at a time (the scan's
+    ``xs``/``ys``). The paged pools ride whole in the scan's carry with the
+    layer index instead: each layer writes and reads its row of them in
+    place, so no layer's pool is sliced, written back or copied."""
     slot_names = list(params["slots"].keys())
+    caches = caches or {}
+    pools = {n: c for n, c in caches.items()
+             if "k_pages" in c.get("attn", {})}
+    rows = {n: c for n, c in caches.items() if n not in pools}
 
     def body(carry, layer_inp):
-        x = carry
-        slot_rows, cache_rows = layer_inp
-        new_cache_rows = {}
+        x, pools = carry
+        slot_rows, cache_rows, layer = layer_inp
+        pools, new_cache_rows = dict(pools), {}
         aux_tot = None
         for name in slot_names:
             kind = name.split("_", 1)[1]
             x, nc, aux = _apply_slot(
                 slot_rows[name], kind, x, cfg, positions=positions, lc=lc,
-                cache=cache_rows.get(name) if cache_rows else None,
+                cache=pools.get(name, cache_rows.get(name)),
                 cache_len=cache_len,
                 seq_mask=seq_mask, cache_attend=cache_attend,
-                block_tables=block_tables,
+                block_tables=block_tables, layer=layer,
             )
-            if nc is not None:
+            if name in pools:
+                pools[name] = nc
+            elif nc is not None:
                 new_cache_rows[name] = nc
             if aux:
                 aux_tot = aux if aux_tot is None else jax.tree_util.tree_map(
@@ -239,36 +252,38 @@ def _run_stack(params, x, cfg, *, positions, lc, caches=None, cache_len=None,
                 )
         if aux_tot is None:
             aux_tot = {}
-        return x, (new_cache_rows, aux_tot)
+        return (x, pools), (new_cache_rows, aux_tot)
 
     body = _remat(body, cfg)
 
     if cfg.scan_layers and cfg.repeats > 1:
-        xs = (params["slots"], caches if caches else {})
-        x, (new_caches, auxs) = jax.lax.scan(body, x, xs)
+        layers = jnp.arange(cfg.repeats, dtype=jnp.int32) if pools else None
+        (x, pools), (new_rows, auxs) = jax.lax.scan(
+            body, (x, pools), (params["slots"], rows, layers)
+        )
         aux = jax.tree_util.tree_map(jnp.sum, auxs) if auxs else {}
         # expert_load should stay per-expert: re-reduce over layers only
         if auxs and "expert_load" in auxs:
             aux["expert_load"] = jnp.sum(auxs["expert_load"], axis=0)
-        return x, (new_caches if caches else None), aux
+        return x, ({**new_rows, **pools} if caches else None), aux
     else:
         # unrolled path (small models / remat experiments)
-        new_caches_acc = []
+        new_rows_acc = []
         aux_acc: dict[str, Any] = {}
         for r in range(cfg.repeats):
             slot_rows = jax.tree_util.tree_map(lambda p: p[r], params["slots"])
-            cache_rows = (
-                jax.tree_util.tree_map(lambda c: c[r], caches) if caches else {}
-            )
-            x, (ncr, aux) = body(x, (slot_rows, cache_rows))
-            new_caches_acc.append(ncr)
+            cache_rows = jax.tree_util.tree_map(lambda c: c[r], rows)
+            (x, pools), (ncr, aux) = body((x, pools),
+                                          (slot_rows, cache_rows, r))
+            new_rows_acc.append(ncr)
             for k, v in aux.items():
                 aux_acc[k] = aux_acc.get(k, 0) + v
         new_caches = None
         if caches:
-            new_caches = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *new_caches_acc
+            new_rows = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *new_rows_acc
             )
+            new_caches = {**new_rows, **pools}
         return x, new_caches, aux_acc
 
 
@@ -378,12 +393,14 @@ def init_cache(cfg, batch: int, max_len: int, dtype=None, *,
 
     ``paged=True`` swaps each attention cache's dense per-slot
     ``(R, B, max_len, Hkv, hd)`` buffers for a shared pool of
-    ``num_pages`` fixed-size pages ``(R, num_pages, page_size, Hkv, hd)``
+    ``num_pages`` fixed-size pages ``(R, Hkv, num_pages, page_size, hd)``
     addressed through a per-slot block table (see ``decode_step``) — HBM
-    then scales with live tokens, not ``batch x max_len``. ``num_pages``
-    defaults to dense-equivalent capacity; serving sizes it to the
-    workload. Recurrent (conv/ssm/xLSTM) state stays dense per slot —
-    it is O(batch), not O(batch x seq)."""
+    then scales with live tokens, not ``batch x max_len``. The pool is
+    head-major, so that one page of every KV head is a set of contiguous
+    ``(page_size, hd)`` tiles, and a step passes it whole with the layer
+    index to the paged kernels. ``num_pages`` defaults to dense-equivalent
+    capacity; serving sizes it to the workload. Recurrent (conv/ssm/xLSTM)
+    state stays dense per slot — it is O(batch), not O(batch x seq)."""
     dtype = dtype or cfg.compute_dtype
     if paged and num_pages is None:
         num_pages = -(-batch * max_len // page_size)
@@ -396,10 +413,10 @@ def init_cache(cfg, batch: int, max_len: int, dtype=None, *,
                 c = {
                     "attn": {
                         "k_pages": jnp.zeros(
-                            (cfg.repeats, num_pages, page_size, hkv, hd), dtype
+                            (cfg.repeats, hkv, num_pages, page_size, hd), dtype
                         ),
                         "v_pages": jnp.zeros(
-                            (cfg.repeats, num_pages, page_size, hkv, hd), dtype
+                            (cfg.repeats, hkv, num_pages, page_size, hd), dtype
                         ),
                     }
                 }

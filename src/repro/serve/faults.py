@@ -167,18 +167,19 @@ _FLIP = {2: 0x5A5A, 4: 0x5A5A5A5A}
 
 
 def _edit_leaf(leaf, page, mode):
-    """One paged pool leaf (R, P, page, Hkv, hd): rewrite physical ``page``."""
+    """One paged pool leaf (R, Hkv, P, page, hd): rewrite physical ``page``
+    in every layer and KV head."""
     if mode == "nan":
-        return leaf.at[:, page].set(jnp.asarray(jnp.nan, leaf.dtype))
+        return leaf.at[:, :, page].set(jnp.asarray(jnp.nan, leaf.dtype))
     if mode == "zero":
-        return leaf.at[:, page].set(jnp.asarray(0, leaf.dtype))
+        return leaf.at[:, :, page].set(jnp.asarray(0, leaf.dtype))
     # "bitflip": XOR a fixed pattern through a bitcast — values stay finite
     # often enough that the NaN sentinel alone cannot catch this; only the
     # per-page checksum path does
     ubits = _UINT[leaf.dtype.itemsize]
     u = jax.lax.bitcast_convert_type(leaf, ubits)
-    u = u.at[:, page].set(u[:, page] ^ jnp.asarray(_FLIP[leaf.dtype.itemsize],
-                                                   ubits))
+    u = u.at[:, :, page].set(
+        u[:, :, page] ^ jnp.asarray(_FLIP[leaf.dtype.itemsize], ubits))
     return jax.lax.bitcast_convert_type(u, leaf.dtype)
 
 
@@ -222,7 +223,7 @@ def make_page_fingerprint_step():
             if not _is_paged(path):
                 continue
             u = jax.lax.bitcast_convert_type(leaf, _UINT[leaf.dtype.itemsize])
-            acc = acc + jnp.sum(u[:, page].astype(jnp.uint32),
+            acc = acc + jnp.sum(u[:, :, page].astype(jnp.uint32),
                                 dtype=jnp.uint32)
         return acc
 

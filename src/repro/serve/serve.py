@@ -237,11 +237,11 @@ def cache_pspec_tree(cfg, mesh, caches):
         path, leaf = path_leaf
         name = _cache_path_name(path)
         nd = len(leaf.shape)
-        if "pages" in name:  # paged pool (R, P, page, Hkv, hd): no batch dim
+        if "pages" in name:  # paged pool (R, Hkv, P, page, hd): no batch dim
             # pages are gathered by physical index, so the page axis must
             # stay unsharded; kv-heads shard over "model" like the dense
             # layout (the pool is the same bytes, just re-bucketed)
-            return P(None, None, None, "model" if kv_div else None, None)
+            return P(None, "model" if kv_div else None, None, None, None)
         b = leaf.shape[1] if nd >= 2 else 1
         batch = batch_ax(b)
         if "attn" in name:  # (R, B, Smax, Hkv, hd)
@@ -574,7 +574,7 @@ def make_cow_copy_step():
         """caches: full stacked tree; src/dst: () int32 physical pages."""
         flat = jax.tree_util.tree_flatten_with_path(caches)
         leaves = [
-            leaf.at[:, dst].set(leaf[:, src]) if _is_paged_leaf(path)
+            leaf.at[:, :, dst].set(leaf[:, :, src]) if _is_paged_leaf(path)
             else leaf
             for path, leaf in flat[0]
         ]

@@ -71,9 +71,10 @@ def test_injector_due_and_defer():
 
 
 def _tiny_caches():
-    # mimics the paged-pool pytree shape: the paged leaves carry "pages" in
-    # their path, others must pass through edits untouched
-    k = jnp.arange(2 * 4 * 8 * 2 * 4, dtype=jnp.float32).reshape(2, 4, 8, 2, 4)
+    # mimics the paged-pool pytree shape, (R, Hkv, P, page, hd): the paged
+    # leaves carry "pages" in their path, others must pass through edits
+    # untouched
+    k = jnp.arange(2 * 2 * 4 * 8 * 4, dtype=jnp.float32).reshape(2, 2, 4, 8, 4)
     return {"pages_k": k, "pages_v": k + 1.0, "state": jnp.ones((3, 3))}
 
 
@@ -81,18 +82,18 @@ def test_page_edit_nan_zero_and_bitflip_roundtrip():
     caches = _tiny_caches()
     ref = jax.tree_util.tree_map(lambda x: np.asarray(x), caches)
     nan_ed = page_edit_step("nan")(jax.tree_util.tree_map(jnp.copy, caches), 2)
-    assert np.all(np.isnan(np.asarray(nan_ed["pages_k"])[:, 2]))
-    np.testing.assert_array_equal(np.asarray(nan_ed["pages_k"])[:, 1],
-                                  ref["pages_k"][:, 1])
+    assert np.all(np.isnan(np.asarray(nan_ed["pages_k"])[:, :, 2]))
+    np.testing.assert_array_equal(np.asarray(nan_ed["pages_k"])[:, :, 1],
+                                  ref["pages_k"][:, :, 1])
     np.testing.assert_array_equal(np.asarray(nan_ed["state"]), ref["state"])
     zeroed = page_edit_step("zero")(nan_ed, 2)
-    assert np.all(np.asarray(zeroed["pages_k"])[:, 2] == 0)
+    assert np.all(np.asarray(zeroed["pages_k"])[:, :, 2] == 0)
     # bitflip is an XOR: applying it twice restores the page exactly
     once = page_edit_step("bitflip")(
         jax.tree_util.tree_map(jnp.copy, caches), 1
     )
-    assert not np.array_equal(np.asarray(once["pages_v"])[:, 1],
-                              ref["pages_v"][:, 1])
+    assert not np.array_equal(np.asarray(once["pages_v"])[:, :, 1],
+                              ref["pages_v"][:, :, 1])
     twice = page_edit_step("bitflip")(once, 1)
     np.testing.assert_array_equal(np.asarray(twice["pages_k"]),
                                   ref["pages_k"])
@@ -108,6 +109,36 @@ def test_page_fingerprint_moves_on_any_edit():
         jax.tree_util.tree_map(jnp.copy, caches), 1
     )
     assert int(fp(flipped, 1)) != base, "a bit flip must move the checksum"
+
+
+@pytest.mark.parametrize("edit", ["cow", "nan", "zero", "bitflip"])
+def test_page_edit_reaches_every_layer_and_head(edit):
+    """A copy-on-write copy and a fault edit of physical page p rewrite
+    page p in every layer and every KV head of each paged pool, and
+    nothing else: not another page, not a non-paged leaf."""
+    from repro.serve.serve import make_cow_copy_step
+
+    caches = _tiny_caches()
+    ref = jax.tree_util.tree_map(np.asarray, caches)
+    fresh = jax.tree_util.tree_map(jnp.copy, caches)
+    if edit == "cow":
+        out = jax.jit(make_cow_copy_step())(fresh, 1, 3)
+    else:
+        out = page_edit_step(edit)(fresh, 3)
+    np.testing.assert_array_equal(np.asarray(out["state"]), ref["state"])
+    for name in ("pages_k", "pages_v"):
+        got, was = np.asarray(out[name]), ref[name]
+        others = np.arange(got.shape[2]) != 3
+        np.testing.assert_array_equal(got[:, :, others], was[:, :, others])
+        page = got[:, :, 3]
+        if edit == "cow":
+            np.testing.assert_array_equal(page, was[:, :, 1])
+        elif edit == "nan":
+            assert np.isnan(page).all()
+        elif edit == "zero":
+            assert (page == 0).all()
+        else:  # the XOR pattern moves every value of the page
+            assert (page != was[:, :, 3]).all()
 
 
 # ---------------------------------------------------------------------------

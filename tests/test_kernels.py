@@ -86,9 +86,21 @@ def test_flash_matches_model_flash_path():
 # ---------------------------------------------------------------------------
 
 
+def _pools(key, P, psize, Hkv, D, dtype, layers, layer):
+    """Random stacked K and V pools (layers, Hkv, P, psize, D), as the
+    model step keeps them; every layer but ``layer`` is NaN, so that a
+    read of another layer cannot pass."""
+    k1, k2 = jax.random.split(key)
+    shape = (layers, Hkv, P, psize, D)
+    other = (jnp.arange(layers) != layer)[:, None, None, None, None]
+    return [jnp.where(other, jnp.nan, jax.random.normal(k, shape, jnp.float32)
+                      ).astype(dtype) for k in (k1, k2)]
+
+
 def _paged_case(B, Hq, Hkv, D, psize, nL, P, lens, dtype, seed=0, free=(),
-                deal=False, poison=(), hole=None):
+                deal=False, poison=(), hole=None, layers=1, layer=0):
     """Random pool + a scrambled (non-identity) block table + ragged lens.
+    The pool has ``layers`` layers and the case reads ``layer``.
 
     Slots in ``free`` keep their ``lens`` but own no page (a table of -1,
     as a released slot). ``deal`` hands the shuffled pages out one at a
@@ -100,8 +112,7 @@ def _paged_case(B, Hq, Hkv, D, psize, nL, P, lens, dtype, seed=0, free=(),
     rng = np.random.default_rng(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, 1, Hq, D), jnp.float32).astype(dtype)
-    k_pages = jax.random.normal(ks[1], (P, psize, Hkv, D), jnp.float32).astype(dtype)
-    v_pages = jax.random.normal(ks[2], (P, psize, Hkv, D), jnp.float32).astype(dtype)
+    k_pages, v_pages = _pools(ks[1], P, psize, Hkv, D, dtype, layers, layer)
     perm = iter(rng.permutation(P))
     need = [0 if b in free else -(-ln // psize) for b, ln in enumerate(lens)]
     tbl = np.full((B, nL), -1, np.int32)
@@ -115,7 +126,7 @@ def _paged_case(B, Hq, Hkv, D, psize, nL, P, lens, dtype, seed=0, free=(),
             tbl[b, :n] = [next(perm) for _ in range(n)]
     for b in poison:
         bad = tbl[b, :need[b]]
-        v_pages = v_pages.at[bad[bad != 0]].set(jnp.nan)
+        v_pages = v_pages.at[:, :, bad[bad != 0]].set(jnp.nan)
     if hole is not None:
         tbl[hole] = -1
     lens = jnp.asarray(lens, jnp.int32)
@@ -147,6 +158,11 @@ PAGED_CASES = [
     (2, 4, 2, 64, 16, 40, 64, (640, 40), None, None, {"poison": (0,)}),
     # a -1 entry below cache_len inside a block: not fetched, not attended
     (2, 4, 2, 64, 16, 24, 64, (300, 150), None, None, {"hole": (0, 5)}),
+    # layer 2 of 3 read in place, and layer 1 of 2 with the head dim
+    # padded: the other layers are NaN
+    (2, 8, 2, 128, 16, 24, 48, (310, 45), None, None,
+     {"layers": 3, "layer": 2}),
+    (2, 4, 2, 100, 8, 6, 16, (40, 13), 11, 50.0, {"layers": 2, "layer": 1}),
 ]
 
 
@@ -164,10 +180,11 @@ def test_paged_attention_matches_reference(case, dtype):
     opt = opt[0] if opt else {}
     q, kp, vp, tbl, lens, qpos = _paged_case(B, Hq, Hkv, D, psize, nL, P,
                                              lens, dtype, **opt)
+    layer = opt.get("layer", 0)
     owned = np.asarray(tbl)[np.asarray(tbl) >= 0]
     assert len(set(owned.tolist())) == len(owned)  # no page in two slots
     out = paged_attention_pallas(
-        q, kp, vp, tbl, q_position=qpos, cache_len=lens,
+        q, kp, vp, tbl, layer=layer, q_position=qpos, cache_len=lens,
         window=window, softcap=softcap, interpret=True,
     )
     ref_tbl, ref_lens = np.array(tbl), np.array(lens)
@@ -179,7 +196,7 @@ def test_paged_attention_matches_reference(case, dtype):
         ref_tbl[b] = np.append(np.delete(ref_tbl[b], j), -1)
         ref_lens[b] -= psize
     ref = paged_attention_reference(
-        q, kp, vp, jnp.asarray(ref_tbl), q_position=qpos,
+        q, kp, vp, jnp.asarray(ref_tbl), layer=layer, q_position=qpos,
         cache_len=jnp.asarray(ref_lens), window=window, softcap=softcap,
     )
     assert out.shape == ref.shape and out.dtype == ref.dtype
@@ -204,11 +221,11 @@ def test_paged_reference_bitwise_matches_dense_decode_attention():
             3, 4, 2, 64, 4, 4, 12, (6, 3, 11), jnp.float32, seed=2
         )
         ref = paged_attention_reference(
-            q, kp, vp, tbl, q_position=qpos, cache_len=lens,
+            q, kp, vp, tbl, layer=0, q_position=qpos, cache_len=lens,
             window=window, softcap=softcap,
         )
         dense = decode_attention(
-            q, gather_pages(kp, tbl), gather_pages(vp, tbl),
+            q, gather_pages(kp, tbl, 0), gather_pages(vp, tbl, 0),
             q_position=qpos, cache_len=lens, window=window, softcap=softcap,
         )
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(dense))
@@ -220,8 +237,9 @@ def test_paged_ops_wrapper_routes_to_reference_on_cpu():
     q, kp, vp, tbl, lens, qpos = _paged_case(
         2, 4, 2, 64, 4, 4, 10, (9, 5), jnp.float32, seed=3
     )
-    out = paged_attention(q, kp, vp, tbl, q_position=qpos, cache_len=lens)
-    ref = paged_attention_reference(q, kp, vp, tbl, q_position=qpos,
+    out = paged_attention(q, kp, vp, tbl, layer=0, q_position=qpos,
+                          cache_len=lens)
+    ref = paged_attention_reference(q, kp, vp, tbl, layer=0, q_position=qpos,
                                     cache_len=lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
 
@@ -231,15 +249,15 @@ def test_paged_ops_wrapper_routes_to_reference_on_cpu():
 # ---------------------------------------------------------------------------
 
 
-def _paged_prefill_case(B, C, Hq, Hkv, D, psize, nL, P, starts, dtype, seed=0):
+def _paged_prefill_case(B, C, Hq, Hkv, D, psize, nL, P, starts, dtype, seed=0,
+                        layers=1, layer=0):
     """A prefill chunk of C tokens per sequence at ragged start offsets,
     over a random pool + scrambled block table (like ``_paged_case`` but
     with multi-row queries: the serve path's chunked-prefill reads)."""
     rng = np.random.default_rng(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed + 11), 3)
     q = jax.random.normal(ks[0], (B, C, Hq, D), jnp.float32).astype(dtype)
-    k_pages = jax.random.normal(ks[1], (P, psize, Hkv, D), jnp.float32).astype(dtype)
-    v_pages = jax.random.normal(ks[2], (P, psize, Hkv, D), jnp.float32).astype(dtype)
+    k_pages, v_pages = _pools(ks[1], P, psize, Hkv, D, dtype, layers, layer)
     perm = rng.permutation(P)
     lens = [s + C for s in starts]
     tbl = np.full((B, nL), -1, np.int32)
@@ -260,6 +278,9 @@ PREFILL_CASES = [
     (2, 8, 2, 1, 64, 4, 8, 18, (4, 12), 6, None),       # window crosses pages
     (2, 8, 8, 2, 32, 8, 3, 7, (0, 16), None, 30.0),     # softcap (gemma2)
     (1, 8, 2, 2, 100, 8, 4, 5, (8,), 5, 50.0),          # D padding + win + cap
+    # layer 2 of 3 read in place; layer 1 of 2 with the head dim padded
+    (2, 8, 4, 2, 128, 4, 6, 14, (0, 8), None, None, {"layers": 3, "layer": 2}),
+    (1, 8, 2, 2, 100, 8, 4, 6, (8,), 5, 50.0, {"layers": 2, "layer": 1}),
 ]
 
 
@@ -274,16 +295,18 @@ def test_paged_prefill_matches_reference(case, dtype):
     from repro.kernels.paged_attention.kernel import paged_prefill_attention_pallas
     from repro.kernels.paged_attention.ref import paged_prefill_attention_reference
 
-    B, C, Hq, Hkv, D, psize, nL, P, starts, window, softcap = case
+    B, C, Hq, Hkv, D, psize, nL, P, starts, window, softcap, *opt = case
+    opt = opt[0] if opt else {}
     q, kp, vp, tbl, qpos, lens = _paged_prefill_case(
-        B, C, Hq, Hkv, D, psize, nL, P, starts, dtype
+        B, C, Hq, Hkv, D, psize, nL, P, starts, dtype, **opt
     )
+    layer = opt.get("layer", 0)
     out = paged_prefill_attention_pallas(
-        q, kp, vp, tbl, q_positions=qpos, cache_len=lens,
+        q, kp, vp, tbl, layer=layer, q_positions=qpos, cache_len=lens,
         causal=True, window=window, softcap=softcap, interpret=True,
     )
     ref = paged_prefill_attention_reference(
-        q, kp, vp, tbl, q_positions=qpos, cache_len=lens,
+        q, kp, vp, tbl, layer=layer, q_positions=qpos, cache_len=lens,
         causal=True, window=window, softcap=softcap,
     )
     assert out.shape == ref.shape and out.dtype == ref.dtype
@@ -307,10 +330,10 @@ def test_paged_prefill_reference_bitwise_matches_dense_flash():
             2, 8, 4, 2, 64, 4, 6, 14, (0, 8), jnp.float32, seed=5
         )
         ref = paged_prefill_attention_reference(
-            q, kp, vp, tbl, q_positions=qpos, cache_len=lens,
+            q, kp, vp, tbl, layer=0, q_positions=qpos, cache_len=lens,
             window=window, softcap=softcap, q_chunk=64, kv_chunk=64,
         )
-        k_dense, v_dense = gather_pages(kp, tbl), gather_pages(vp, tbl)
+        k_dense, v_dense = gather_pages(kp, tbl, 0), gather_pages(vp, tbl, 0)
         Smax = k_dense.shape[1]
         kpos = jnp.broadcast_to(jnp.arange(Smax)[None], (q.shape[0], Smax))
         dense = model_flash(
@@ -328,13 +351,13 @@ def test_paged_prefill_ops_wrapper_routes_to_reference_on_cpu():
     q, kp, vp, tbl, qpos, lens = _paged_prefill_case(
         2, 8, 4, 2, 64, 4, 6, 14, (0, 8), jnp.float32, seed=6
     )
-    out = paged_prefill_attention(q, kp, vp, tbl, q_positions=qpos,
+    out = paged_prefill_attention(q, kp, vp, tbl, layer=0, q_positions=qpos,
                                   cache_len=lens)
-    ref = paged_prefill_attention_reference(q, kp, vp, tbl, q_positions=qpos,
-                                            cache_len=lens)
+    ref = paged_prefill_attention_reference(q, kp, vp, tbl, layer=0,
+                                            q_positions=qpos, cache_len=lens)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     # and the interpret route runs the kernel end to end through the wrapper
-    interp = paged_prefill_attention(q, kp, vp, tbl, q_positions=qpos,
+    interp = paged_prefill_attention(q, kp, vp, tbl, layer=0, q_positions=qpos,
                                      cache_len=lens, impl="interpret")
     np.testing.assert_allclose(np.asarray(interp), np.asarray(ref), atol=2e-5)
 
@@ -436,14 +459,14 @@ def test_paged_kernels_take_the_score_scale(kernel, dtype):
     if kernel == "decode":
         q, kp, vp, tbl, lens, qpos = _paged_case(
             3, 8, 2, 128, 16, 40, 96, (530, 257, 17), dtype, seed=5)
-        args = dict(q_position=qpos, cache_len=lens)
+        args = dict(layer=0, q_position=qpos, cache_len=lens)
         run = lambda s: paged_attention_pallas(
             q, kp, vp, tbl, **args, interpret=True, sm_scale=s)
         ref = paged_attention_reference(q, kp, vp, tbl, **args, sm_scale=scale)
     else:
         q, kp, vp, tbl, qpos, lens = _paged_prefill_case(
             2, 16, 8, 2, 128, 16, 6, 14, (0, 48), dtype, seed=5)
-        args = dict(q_positions=qpos, cache_len=lens, causal=True)
+        args = dict(layer=0, q_positions=qpos, cache_len=lens, causal=True)
         run = lambda s: paged_prefill_attention_pallas(
             q, kp, vp, tbl, **args, interpret=True, sm_scale=s)
         ref = paged_prefill_attention_reference(q, kp, vp, tbl, **args,

@@ -8,7 +8,9 @@ chip time. The topology is described inside a fixture, never while a
 module is imported; every test that needs it lives in this one file.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,8 +23,9 @@ from repro.kernels.paged_attention.kernel import (
 )
 
 # serving shapes: 8 slots, max_len 2048 in 16-token pages, a 1024-page
-# pool, 32-token prefill chunks (the ServeConfig default)
-BATCH, PAGE, N_LOGICAL, POOL, CHUNK = 8, 16, 2048 // 16, 1024, 32
+# pool, 32-token prefill chunks (the ServeConfig default); the kernels read
+# one layer of a stacked pool of LAYERS
+BATCH, PAGE, N_LOGICAL, POOL, CHUNK, LAYERS = 8, 16, 2048 // 16, 1024, 32, 2
 
 WIDTHS = {
     # name: (Hq, Hkv, D, window, softcap[, score scale])
@@ -78,20 +81,21 @@ def _compile(kernel, q_len, widths, one_chip,
              shape=(BATCH, N_LOGICAL, POOL)):
     Hq, Hkv, D, window, softcap, *scale = widths
     batch, n_logical, pool = shape
-    pages = _shape((pool, PAGE, Hkv, D), jnp.bfloat16, one_chip)
+    pages = _shape((LAYERS, Hkv, pool, PAGE, D), jnp.bfloat16, one_chip)
     if kernel is paged_attention_pallas:
         pos_name, pos_shape = "q_position", (batch,)
     else:
         pos_name, pos_shape = "q_positions", (batch, q_len)
 
-    def call(q, k_pages, v_pages, tables, lens, pos):
-        return kernel(q, k_pages, v_pages, tables, cache_len=lens,
+    def call(q, k_pages, v_pages, tables, layer, lens, pos):
+        return kernel(q, k_pages, v_pages, tables, layer=layer, cache_len=lens,
                       window=window, softcap=softcap, **{pos_name: pos},
                       **({"sm_scale": scale[0]} if scale else {}))
 
     return jax.jit(call).lower(
         _shape((batch, q_len, Hq, D), jnp.bfloat16, one_chip), pages, pages,
         _shape((batch, n_logical), jnp.int32, one_chip),
+        _shape((), jnp.int32, one_chip),
         _shape((batch,), jnp.int32, one_chip),
         _shape(pos_shape, jnp.int32, one_chip),
     ).compile()
@@ -136,3 +140,76 @@ def test_held_expert_layer_compiles_for_v5e(one_chip, no_compile_cache):
         params, _shape((64, 1, 4096), jnp.bfloat16, one_chip)).compile()
     text = compiled.as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
+
+
+# glm4-chat's serving shapes: GLM-4-9B at 20 layers, 32 slots, max_len 5120
+# in 16-token pages, a 7,680-page pool
+GLM4_CHAT = dict(layers=20, slots=32, max_len=5120, pages=7680)
+
+
+def _glm4_steps(one_chip):
+    """glm4-chat's compiled paged decode step (caches donated) and its
+    prefill-chunk step, with the Pallas kernels forced: off the chip,
+    ``impl="auto"`` would take the gather oracle."""
+    from repro import compat
+    from repro.configs import get_config
+    from repro.launch.mesh import make_host_mesh
+    from repro.layers.common import abstract_params
+    from repro.models import transformer as T
+    from repro.serve.serve import make_prefill_chunk_step, make_serve_decode_step
+
+    g = GLM4_CHAT
+    cfg = get_config("glm4-9b").replace(repeats=g["layers"],
+                                        paged_attn_impl="pallas")
+    mesh = make_host_mesh(devices=[one_chip.device_set.pop()])
+    placed = lambda tree: jax.tree_util.tree_map(
+        lambda a: _shape(a.shape, a.dtype, one_chip), tree)
+    params = placed(abstract_params(T.model_params(cfg), cfg.param_dtype))
+    caches = placed(jax.eval_shape(lambda: T.init_cache(
+        cfg, g["slots"], g["max_len"], paged=True, page_size=PAGE,
+        num_pages=g["pages"])))
+    B, n_logical = g["slots"], g["max_len"] // PAGE
+    i32 = lambda *shape: _shape(shape, jnp.int32, one_chip)
+    flags = _shape((B,), jnp.bool_, one_chip)
+    keys = _shape((B, 2), jnp.uint32, one_chip)
+    with compat.use_mesh(mesh):
+        decode = jax.jit(make_serve_decode_step(cfg, mesh, paged=True),
+                         donate_argnums=(4,)).lower(
+            params, i32(B, 1), i32(B), flags, caches, i32(B, n_logical), keys,
+            flags).compile()
+        chunk = jax.jit(make_prefill_chunk_step(cfg, mesh, paged=True),
+                        donate_argnums=(5,)).lower(
+            params, i32(1, CHUNK), i32(1), i32(1), i32(), caches,
+            i32(B, n_logical), keys).compile()
+    return cfg, decode.as_text(), chunk.as_text()
+
+
+def test_glm4_steps_address_the_paged_pool_in_place(one_chip, no_compile_cache):
+    """The stacked paged pool rides through the layer scan whole and the
+    kernels read their layer of it in place: no copy, dynamic slice or
+    dynamic update slice in either compiled step moves a buffer the size
+    of one layer's pool or of the whole stacked pool, synchronously or
+    not (at glm4-chat's shapes 63 MB or 1.26 GB; per dispatch such moves
+    cost far more than the attention itself). The kernels are still
+    there, one each."""
+    cfg, decode, chunk = _glm4_steps(one_chip)
+    layer = GLM4_CHAT["pages"] * PAGE * cfg.n_kv_heads * cfg.head_dim_
+    sizes = {layer, layer * GLM4_CHAT["layers"]}
+    # name = result type(s) opcode(operands...)
+    op = re.compile(r"\s*(?:ROOT )?%?([\w.-]+) = (.*?) ([\w-]+)\(")
+    movers = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice")
+    for text, kernel in ((decode, "paged_attention"),
+                         (chunk, "paged_prefill_attention")):
+        moved = []
+        for line in text.splitlines():
+            m = op.match(line)
+            if m and m.group(3) in movers and any(
+                    math.prod(int(d) for d in dims.split(",") if d) in sizes
+                    for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(2))):
+                moved.append(m.group(1))
+        assert not moved, (kernel, moved)
+        calls = [line.split(" = ", 1)[0].strip().lstrip("%")
+                 for line in text.splitlines()
+                 if "tpu_custom_call" in line and " = " in line]
+        assert len(calls) == 1 and re.fullmatch(
+            kernel + r"(\.\d+)?", calls[0]), calls
