@@ -7,19 +7,16 @@ Prints ``name,us_per_call,derived`` CSV lines.
   tables67_*  weak/strong scaling-efficiency tables   (paper Tables 6/7)
   figure7_*   regression detect + explain             (paper Figure 7)
   roofline_*  §Roofline aggregation from the dry-run artifacts
-  serve_*     overlapped vs stop-the-world serving    (BENCH_serve.json)
+  serve_*     serving throughput on the CPU           (BENCH_serve.json)
 
 ``--check`` is the CI gate: it runs the tier-1 suite
 (``PYTHONPATH=src python -m pytest -x -q``) plus a cold-vs-cached
 ``analyze_hlo`` timing assertion (so the HLO parse cache cannot silently
 regress even if the equivalent unit test is edited away) plus the cheap
-shape of ``benchmarks/serve_throughput.py`` (paged and dense KV layouts
-must keep producing identical tokens — greedy AND sampled — overlapped
-chunked prefill must keep producing identical tokens with no decode gap
-while prefilling, the paged pool footprint must stay strictly below the
-dense buffers, and cross-request prefix sharing must keep tokens bitwise
-identical on/off in both decode modes while strictly lowering peak live
-pages and skipping prefill chunks). It also forces a preemption (tiny
+shape of ``benchmarks/serve_throughput.py`` (overlapped chunked prefill
+must leave no decode gap while prefilling, and cross-request prefix
+sharing must keep tokens bitwise identical on/off in both decode modes
+while strictly lowering peak live pages and skipping prefill chunks). It also forces a preemption (tiny
 page pool vs ample pool) and asserts the recompute-resumed token streams
 are bitwise identical — greedy AND sampled — with ``preemptions > 0`` and
 zero allocator pages leaked after drain, plus a goodput sanity pass of
@@ -109,9 +106,9 @@ def check() -> int:
     try:
         print(serve_throughput.check())
     except AssertionError as e:
-        print(f"[check] serve paged/overlap: {e}", file=sys.stderr)
+        print(f"[check] serve: {e}", file=sys.stderr)
         return 1
-    print("[check] tier-1 suite green, hlo cache OK, serve paged+overlap OK")
+    print("[check] tier-1 suite green, hlo cache OK, serve OK")
     return 0
 
 

@@ -1,40 +1,35 @@
-"""Serving throughput A/B — paged vs dense KV cache, overlap vs stop-world.
+"""Serving throughput of ``serve.BatchScheduler`` on a request trace.
 
-Runs the same request trace through ``serve.BatchScheduler`` three ways —
-paged+overlapped (the production configuration), paged+stop-the-world, and
-dense+overlapped — and measures what the ISSUE's acceptance criteria name:
+Runs a staggered request trace (half the requests arrive while the first
+half decodes) through the scheduler's one path — paged KV pool, prefill
+chunks overlapped with decode — and reports:
 
   tokens/sec            end-to-end generated-token throughput
   ttft                  time from submit to the first-token dispatch
                         (prefill completion), per request
   decode max gap        longest wall-clock gap between consecutive decode
-                        dispatches while a prefill was in flight — the
-                        "decode stall" a stop-the-world prefill causes
-  peak KV bytes         attention-cache HBM footprint: the full dense
-                        buffers vs the paged pool (sized to the workload's
-                        concurrent-token peak), plus the pool's live-page
-                        peak and utilization
+                        dispatches while a prefill was in flight
+  KV bytes              the paged pool's footprint (sized to the workload's
+                        concurrent-token peak), its live-page peak and
+                        utilization
   overlap guarantee     scheduler-level invariant: every tick with an
                         in-flight prefill and >=1 decoding slot also
                         dispatched a decode (no gap > one tick)
-  identical tokens      paged == dense, and overlap on/off, token for token
 
-A fourth section runs the shared-prefix workload (one long system prompt,
+A second section runs the shared-prefix workload (one long system prompt,
 divergent tails) with the cross-request prefix cache on vs off on the SAME
 warm-first schedule, reporting TTFT, tokens/sec, peak live pages and the
 prefill chunks the trie hits skipped — plus bitwise token identity between
 the two sides.
 
 Emits ``BENCH_serve.json`` (default ``results/BENCH_serve.json``) so the
-repo carries a serve-path perf trajectory next to the TALP records; the
+repo carries a serve-path trajectory next to the TALP records; the
 ``--check`` shape in ``benchmarks/run.py`` runs the tiny variant and
-asserts paged/dense token identity (greedy AND sampled), the overlap
-guarantee, that the paged pool footprint lands strictly below dense for
-the mixed-length trace, and that prefix sharing keeps tokens bitwise
-identical (greedy AND sampled) while strictly lowering peak live pages
-and skipping prefill chunks.
+asserts the overlap guarantee, and that prefix sharing keeps tokens
+bitwise identical (greedy AND sampled) while strictly lowering peak live
+pages and skipping prefill chunks.
 
-A fifth section (``"traffic"``) replays the open-loop harness from
+A third section (``"traffic"``) replays the open-loop harness from
 ``repro.serve.traffic`` — the same seeded workload under Poisson and
 Markov-modulated bursty arrivals against a deliberately tight page pool —
 and reports goodput, p50/p95/p99 TTFT, queue depth and the scheduler's
@@ -43,7 +38,7 @@ a preemption (tiny pool vs ample pool) and asserts the recompute-resume
 token streams are bitwise identical, greedy AND sampled, with zero pages
 leaked after drain.
 
-A sixth section (``--chaos`` / ``run_chaos``) is the fault-injection
+A fourth section (``--chaos`` / ``run_chaos``) is the fault-injection
 soak: the same seeded burst workload replayed fault-free and under a
 seeded ``repro.serve.faults`` schedule (NaN logits, page corruption,
 allocator spikes, dispatch hangs), reporting the recovery counters,
@@ -53,7 +48,7 @@ every fault kind injected at least once), quarantine-works (a request
 whose faults exhaust ``max_retries`` ends terminal ``failed`` while its
 neighbors stay bitwise intact) and zero pages leaked after drain.
 
-A seventh section (``"speculation"`` / ``run_spec`` / ``--spec``) is the
+A fifth section (``"speculation"`` / ``run_spec`` / ``--spec``) is the
 speculative-decode A/B: the same trace with ``spec_decode`` on vs off, on
 a repetitive trace (residual-zeroed "copy regime" weights whose greedy
 decode provably cycles — the prompt-lookup drafter's home turf) and a
@@ -115,9 +110,9 @@ def _request_trace(cfg, n_requests: int, seed: int = 0):
             for n in rng.integers(8, 24, size=n_requests)]
 
 
-def run_mode(cfg, mesh, params, prompts, *, overlap: bool, max_new: int,
+def run_mode(cfg, mesh, params, prompts, *, max_new: int,
              batch: int, prefill_chunk: int, max_len: int = 128,
-             paged: bool = True, page_size: int = 16,
+             page_size: int = 16,
              num_pages: int | None = None, prefix_cache: bool = False,
              greedy: bool = True, temperature: float = 1.0,
              top_k: int | None = None, sample_seed: int = 0,
@@ -136,8 +131,7 @@ def run_mode(cfg, mesh, params, prompts, *, overlap: bool, max_new: int,
         sched = BatchScheduler(
             cfg, mesh,
             ServeConfig(max_len=max_len, batch=batch,
-                        prefill_chunk=prefill_chunk, overlap=overlap,
-                        paged=paged, page_size=page_size,
+                        prefill_chunk=prefill_chunk, page_size=page_size,
                         num_pages=num_pages, prefix_cache=prefix_cache,
                         greedy=greedy, temperature=temperature, top_k=top_k,
                         sample_seed=sample_seed, spec_decode=spec_decode,
@@ -186,8 +180,6 @@ def run_mode(cfg, mesh, params, prompts, *, overlap: bool, max_new: int,
         wall = time.perf_counter() - t0
     tokens = sum(len(r["generated"]) for r in sched.completed)
     return {
-        "overlap": overlap,
-        "paged": paged,
         "requests": len(prompts),
         "completed": len(sched.completed),
         "ticks": ticks,
@@ -199,8 +191,7 @@ def run_mode(cfg, mesh, params, prompts, *, overlap: bool, max_new: int,
         "decode_max_gap_during_prefill_s": round(
             max(gaps_during_prefill, default=0.0), 4
         ),
-        # overall stall: the stop-the-world mode pays its prefills *between*
-        # decode dispatches (host-blocked inside attach), which this catches
+        # overall stall, whether or not a prefill was in flight
         "decode_max_gap_s": round(
             max((b - a for a, b in zip(decode_times, decode_times[1:])),
                 default=0.0), 4
@@ -238,7 +229,7 @@ def run_prefix(cfg, mesh, params, *, n_requests: int, prefix_len: int,
     pages per side."""
     prompts = _shared_prefix_trace(cfg, n_requests, prefix_len)
     num_pages = _workload_pages(prompts, max_new, batch, page_size)
-    kw = dict(overlap=True, max_new=max_new, batch=batch,
+    kw = dict(max_new=max_new, batch=batch,
               prefill_chunk=prefill_chunk, max_len=max_len,
               page_size=page_size, num_pages=num_pages, warm_first=True,
               greedy=greedy, temperature=temperature, top_k=top_k)
@@ -302,7 +293,7 @@ def run_traffic(cfg, mesh, params, *, arrival: str, n_requests: int = 10,
         sched = BatchScheduler(
             cfg, mesh,
             ServeConfig(max_len=max_len, batch=batch,
-                        prefill_chunk=prefill_chunk, paged=True,
+                        prefill_chunk=prefill_chunk,
                         page_size=page_size, num_pages=num_pages,
                         preempt_policy=preempt_policy),
             params,
@@ -333,7 +324,7 @@ def _forced_preempt(cfg, mesh, params, *, num_pages: int,
     with compat.use_mesh(mesh):
         sched = BatchScheduler(
             cfg, mesh,
-            ServeConfig(max_len=64, batch=2, prefill_chunk=4, paged=True,
+            ServeConfig(max_len=64, batch=2, prefill_chunk=4,
                         page_size=8, num_pages=num_pages, **kw),
             params,
         )
@@ -411,7 +402,7 @@ def run_chaos(cfg, mesh, params, *, arrival: str = "burst",
             sched = BatchScheduler(
                 cfg, mesh,
                 ServeConfig(max_len=64, batch=2, prefill_chunk=4,
-                            paged=True, page_size=8, num_pages=10,
+                            page_size=8, num_pages=10,
                             prefix_cache=True, watchdog_deadline_s=0.05),
                 params,
             )
@@ -461,7 +452,7 @@ def _chaos_batch(cfg, mesh, params, *, greedy: bool, fault_cfg=None,
     with compat.use_mesh(mesh):
         sched = BatchScheduler(
             cfg, mesh,
-            ServeConfig(max_len=64, batch=4, prefill_chunk=4, paged=True,
+            ServeConfig(max_len=64, batch=4, prefill_chunk=4,
                         page_size=8, num_pages=24, prefix_cache=True,
                         watchdog_deadline_s=0.05, **kw),
             params, fault_injector=injector,
@@ -582,7 +573,7 @@ def run_spec(cfg_name: str = "tinyllama-1.1b", *, spec_k: int = 4,
     the sequential path). Reports bitwise token identity, tokens/sec
     speedup, TTFT, acceptance rate and pages leaked after drain."""
     cfg, mesh, params = _build(cfg_name)
-    kw = dict(overlap=True, batch=4, prefill_chunk=16, max_len=256,
+    kw = dict(batch=4, prefill_chunk=16, max_len=256,
               page_size=16, spec_k=spec_k)
     if not greedy:
         kw.update(greedy=False, temperature=0.8, top_k=20, sample_seed=3)
@@ -635,18 +626,10 @@ def run(n_requests: int = 6, max_new: int = 16, batch: int = 4,
     num_pages = _workload_pages(prompts, max_new, batch, page_size)
     kw = dict(max_new=max_new, batch=batch, prefill_chunk=prefill_chunk,
               max_len=max_len, page_size=page_size)
-    # warmup: compile BOTH layouts' decode + prefill traces outside the
-    # measured passes (the jitted pairs are keyed on paged vs dense)
-    run_mode(cfg, mesh, params, prompts[:2], overlap=True, paged=True,
-             num_pages=num_pages, **{**kw, "max_new": 2})
-    run_mode(cfg, mesh, params, prompts[:2], overlap=True, paged=False,
+    # warmup: compile the decode + prefill traces outside the measured pass
+    run_mode(cfg, mesh, params, prompts[:2], num_pages=num_pages,
              **{**kw, "max_new": 2})
-    paged_ov = run_mode(cfg, mesh, params, prompts, overlap=True, paged=True,
-                        num_pages=num_pages, **kw)
-    paged_sw = run_mode(cfg, mesh, params, prompts, overlap=False, paged=True,
-                        num_pages=num_pages, **kw)
-    dense_ov = run_mode(cfg, mesh, params, prompts, overlap=True, paged=False,
-                        **kw)
+    serve = run_mode(cfg, mesh, params, prompts, num_pages=num_pages, **kw)
     # shared-prefix A/B: longest page-aligned system prompt that still leaves
     # room for the divergent tail + generation inside max_len
     prefix_len = max(page_size,
@@ -655,8 +638,7 @@ def run(n_requests: int = 6, max_new: int = 16, batch: int = 4,
                         prefix_len=prefix_len, max_new=max_new, batch=batch,
                         prefill_chunk=prefill_chunk, max_len=max_len,
                         page_size=page_size)
-    gen_po, gen_ps = paged_ov.pop("generated"), paged_sw.pop("generated")
-    gen_do = dense_ov.pop("generated")
+    serve.pop("generated")
     # open-loop traffic: the same seeded workload under memoryless and
     # bursty arrivals, against a pool tight enough that bursts queue and
     # preempt — goodput and TTFT tails are the load-dependent numbers a
@@ -668,17 +650,12 @@ def run(n_requests: int = 6, max_new: int = 16, batch: int = 4,
     # speculative decode A/B at its own tuned shape (the strict-speedup
     # comparison needs enough decode steps that dispatch savings dominate)
     speculation = run_spec(cfg_name)
-    ostats = paged_ov["stats"]
-    kv_paged, kv_dense = paged_ov["kv"], dense_ov["kv"]
+    ostats = serve["stats"]
     return {
         "arch": cfg_name,
         "config": {"requests": n_requests, "max_new": max_new, "batch": batch,
                    "prefill_chunk": prefill_chunk, "max_len": max_len,
                    "page_size": page_size, "num_pages": num_pages},
-        # overlap on/off bitwise token identity (on the paged layout)
-        "identical_tokens": gen_po == gen_ps,
-        # paged vs dense bitwise token identity (the tentpole guarantee)
-        "paged_matches_dense": gen_po == gen_do,
         # prefill and decode genuinely co-existed (overlap_ticks > 0) and no
         # tick's decode dispatch ever waited behind prefill work — "no
         # decode gap > one tick while a prefill is in progress"
@@ -686,18 +663,7 @@ def run(n_requests: int = 6, max_new: int = 16, batch: int = 4,
             ostats["overlap_ticks"] > 0
             and ostats["decode_after_prefill_ticks"] == 0
         ),
-        "kv": {
-            "paged": kv_paged,
-            "dense": kv_dense,
-            # the memory win: pool footprint strictly below the dense buffers
-            "paged_below_dense": kv_paged["kv_bytes"] < kv_dense["kv_bytes"],
-            "savings_ratio": round(
-                kv_dense["kv_bytes"] / max(kv_paged["kv_bytes"], 1), 3
-            ),
-        },
-        "paged_overlap": paged_ov,
-        "paged_stop_world": paged_sw,
-        "dense_overlap": dense_ov,
+        "serve": serve,
         "prefix": prefix,
         "traffic": traffic,
         "speculation": speculation,
@@ -708,34 +674,12 @@ def check(out_path: str | None = None) -> str:
     """The cheap CI shape: tiny trace, asserts the acceptance criteria."""
     result = run(n_requests=3, max_new=6, batch=2, prefill_chunk=4,
                  max_len=64)
-    if not result["identical_tokens"]:
-        raise AssertionError(
-            "overlapped chunked prefill changed generated tokens vs "
-            "stop-the-world prefill"
-        )
-    if not result["paged_matches_dense"]:
-        raise AssertionError(
-            "paged KV cache changed generated tokens vs the dense layout"
-        )
     if not result["overlap_no_decode_gap"]:
         raise AssertionError(
             "decode gap while a prefill was in flight: "
-            f"{result['paged_overlap']['stats']}"
+            f"{result['serve']['stats']}"
         )
-    if not result["kv"]["paged_below_dense"]:
-        raise AssertionError(
-            "paged pool footprint not below dense KV bytes: "
-            f"{result['kv']}"
-        )
-    ov, sw = result["paged_overlap"], result["paged_stop_world"]
-    # only enforce the wall-clock comparison when stop-the-world stalled
-    # measurably (tiny CI shapes on loaded runners are jitter-prone)
-    if sw["decode_max_gap_s"] > 0.05 and (
-            ov["decode_max_gap_s"] >= sw["decode_max_gap_s"]):
-        raise AssertionError(
-            f"overlap did not beat stop-the-world on decode stall: "
-            f"{ov['decode_max_gap_s']}s >= {sw['decode_max_gap_s']}s"
-        )
+    ov = result["serve"]
     prefix = result["prefix"]
     if not prefix["identical_tokens"]:
         raise AssertionError(
@@ -761,18 +705,6 @@ def check(out_path: str | None = None) -> str:
     if not sampled["identical_tokens"]:
         raise AssertionError(
             "prefix sharing changed sampled tokens (temperature=0.8, top_k=5)"
-        )
-    # ...and the S>1 paged prefill read must match the dense layout under
-    # sampling as well as greedy (the greedy case is gated above)
-    sprompts = _request_trace(cfg, 3)
-    skw = dict(overlap=True, max_new=6, batch=2, prefill_chunk=4, max_len=64,
-               page_size=16, greedy=False, temperature=0.8, top_k=5)
-    spaged = run_mode(cfg, mesh, params, sprompts, paged=True,
-                      num_pages=_workload_pages(sprompts, 6, 2, 16), **skw)
-    sdense = run_mode(cfg, mesh, params, sprompts, paged=False, **skw)
-    if spaged["generated"] != sdense["generated"]:
-        raise AssertionError(
-            "paged KV cache changed sampled tokens vs the dense layout"
         )
     # forced-preemption identity (greedy AND sampled) + no-leak gate
     _check_preemption(cfg, mesh, params)
@@ -838,8 +770,8 @@ def check(out_path: str | None = None) -> str:
     return csv_line(
         "check_serve_paged",
         ov["wall_s"] * 1e6 / max(ov["ticks"], 1),
-        f"tok/s={ov['tokens_per_sec']};kv_savings={result['kv']['savings_ratio']}x;"
-        f"pool_util={result['kv']['paged']['pool_utilization']};"
+        f"tok/s={ov['tokens_per_sec']};"
+        f"pool_util={ov['kv']['pool_utilization']};"
         f"prefix_chunks_saved={prefix['prefill_chunks_saved']};"
         f"traffic_goodput={result['traffic']['burst']['goodput_tokens_per_sec']};"
         f"spec_speedup={rep['tokens_per_sec_speedup']}x;"
@@ -860,28 +792,18 @@ def _save(result: dict, out_path: str | None = None) -> str:
 
 
 def _lines(result: dict, path: str) -> list[str]:
-    po, do = result["paged_overlap"], result["dense_overlap"]
-    sw = result["paged_stop_world"]
+    po = result["serve"]
     pf = result["prefix"]
     pon, poff = pf["sharing_on"], pf["sharing_off"]
     tag = result["arch"]
     return [
-        csv_line(f"serve_paged_overlap[{tag}]",
+        csv_line(f"serve[{tag}]",
                  po["wall_s"] * 1e6 / max(po["ticks"], 1),
                  f"tok/s={po['tokens_per_sec']};ttft={po['ttft_mean_s']}s;"
                  f"kv_bytes={po['kv']['kv_bytes']};"
-                 f"pool_util={po['kv']['pool_utilization']}"),
-        csv_line(f"serve_paged_stop_world[{tag}]",
-                 sw["wall_s"] * 1e6 / max(sw["ticks"], 1),
-                 f"tok/s={sw['tokens_per_sec']};ttft={sw['ttft_mean_s']}s"),
-        csv_line(f"serve_dense_overlap[{tag}]",
-                 do["wall_s"] * 1e6 / max(do["ticks"], 1),
-                 f"tok/s={do['tokens_per_sec']};kv_bytes={do['kv']['kv_bytes']}"),
-        csv_line(f"serve_identity[{tag}]", 0.0,
-                 f"overlap_identical={result['identical_tokens']};"
-                 f"paged_matches_dense={result['paged_matches_dense']};"
+                 f"pool_util={po['kv']['pool_utilization']};"
                  f"no_decode_gap={result['overlap_no_decode_gap']};"
-                 f"kv_savings={result['kv']['savings_ratio']}x;json={path}"),
+                 f"json={path}"),
         csv_line(f"serve_prefix_sharing_on[{tag}]",
                  pon["wall_s"] * 1e6 / max(pon["ticks"], 1),
                  f"tok/s={pon['tokens_per_sec']};ttft={pon['ttft_mean_s']}s;"

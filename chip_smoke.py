@@ -140,7 +140,7 @@ def decode_step_text(run: dict) -> str:
     sched, scfg = run["scheduler"], run["scfg"]
     batch = scfg.batch
     step = jax.jit(
-        make_serve_decode_step(run["config"], run["mesh"], paged=True),
+        make_serve_decode_step(run["config"], run["mesh"]),
         donate_argnums=(4,),
     )
     with compat.use_mesh(run["mesh"]):

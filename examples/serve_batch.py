@@ -3,8 +3,7 @@ chunked prefill-on-attach overlapped with in-flight decode + TALP
 monitoring of the serving loop through ``repro.session``, emitting a run
 record suitable for the same CI report as training runs.
 
-    PYTHONPATH=src python examples/serve_batch.py            # paged (default)
-    PYTHONPATH=src python examples/serve_batch.py --dense    # dense baseline
+    PYTHONPATH=src python examples/serve_batch.py
     PYTHONPATH=src python examples/serve_batch.py --shared-prefix
         # cross-request prefix cache: requests share a system prompt whose
         # KV pages are prefilled once and mapped into every later request's
@@ -29,13 +28,11 @@ record suitable for the same CI report as training runs.
         # demo runs the same trace spec on and off and prints the
         # acceptance rate, dispatches saved, and bitwise token identity
 
-The paged layout (``ServeConfig.paged``, the ``--paged`` default here and
-in ``repro.launch.serve``) keeps attention KV in a shared pool of
-``page_size``-token pages addressed through per-slot block tables —
-``num_pages`` below sizes the pool to this workload's concurrent-token
-peak, well under the dense ``batch x max_len`` equivalent, and the demo
-prints the pool accounting to show it. Generated tokens are bitwise
-identical either way.
+The scheduler keeps attention KV in a shared pool of ``page_size``-token
+pages addressed through per-slot block tables — ``num_pages`` below sizes
+the pool to this workload's concurrent-token peak, well under the
+``batch x max_len`` a slot-major cache would hold, and the demo prints the
+pool accounting to show it.
 
 The scheduler takes the session directly — every decode dispatch is a visit
 of its ``decode`` region and every prefill chunk a visit of its ``prefill``
@@ -65,14 +62,10 @@ from repro.serve.serve import BatchScheduler, ServeConfig
 
 
 def main():
-    paged = "--dense" not in sys.argv[1:]
     shared_prefix = "--shared-prefix" in sys.argv[1:]
     traffic = "--traffic" in sys.argv[1:]
     chaos = "--chaos" in sys.argv[1:]
     spec = "--spec" in sys.argv[1:]
-    if (shared_prefix or traffic or chaos or spec) and not paged:
-        raise SystemExit("--shared-prefix/--traffic/--chaos/--spec need the "
-                         "paged layout")
     if traffic or chaos:
         return main_traffic(chaos=chaos)
     if spec:
@@ -92,12 +85,11 @@ def main():
         sched = BatchScheduler(
             cfg, mesh,
             # pool sized to the workload: 4 slots x ceil((10+8)/16) pages,
-            # vs the dense equivalent of 4 x 128/16 = 32 pages (the shared-
-            # prefix run carries 48 extra prompt tokens per request, shared
-            # after the first — plus the trie's pinned copy)
+            # vs 4 x 128/16 = 32 pages for every slot at max_len (the
+            # shared-prefix run carries 48 extra prompt tokens per request,
+            # shared after the first — plus the trie's pinned copy)
             ServeConfig(max_len=128, batch=4, prefill_chunk=16,
-                        paged=paged, page_size=16,
-                        num_pages=(16 if shared_prefix else 8) if paged else None,
+                        page_size=16, num_pages=16 if shared_prefix else 8,
                         prefix_cache=shared_prefix),
             params, session=session,
         )
@@ -119,19 +111,16 @@ def main():
           f"({sched.stats['decode_steps']} decode steps, "
           f"{sched.stats['prefill_chunks']} prefill chunks)")
     kv = sched.kv_cache_stats()
-    if kv["layout"] == "paged":
-        print(f"paged KV pool: {kv['kv_bytes']} bytes "
-              f"({kv['num_pages']} pages x {kv['page_size']} tokens), "
-              f"peak {kv['peak_used_pages']} pages live, "
-              f"utilization {kv['pool_utilization']}")
-        if "prefix_cache" in kv:
-            pc = kv["prefix_cache"]
-            print(f"prefix cache: {pc['pages_saved_by_sharing']} pages saved "
-                  f"by sharing, {pc['prefill_tokens_skipped']} prefill tokens "
-                  f"skipped, hit rate {pc['hit_rate']} "
-                  f"({pc['cow_copies']} copy-on-write pages)")
-    else:
-        print(f"dense KV cache: {kv['kv_bytes']} bytes")
+    print(f"paged KV pool: {kv['kv_bytes']} bytes "
+          f"({kv['num_pages']} pages x {kv['page_size']} tokens), "
+          f"peak {kv['peak_used_pages']} pages live, "
+          f"utilization {kv['pool_utilization']}")
+    if "prefix_cache" in kv:
+        pc = kv["prefix_cache"]
+        print(f"prefix cache: {pc['pages_saved_by_sharing']} pages saved "
+              f"by sharing, {pc['prefill_tokens_skipped']} prefill tokens "
+              f"skipped, hit rate {pc['hit_rate']} "
+              f"({pc['cow_copies']} copy-on-write pages)")
     for req in sched.completed[:3]:
         print(f"  request {req['id']}: generated {req['generated']}")
     if run is None:
@@ -178,7 +167,7 @@ def main_traffic(chaos: bool = False):
             cfg, mesh,
             # 2 slots x 3 pages: bursts must queue, long requests must
             # preempt — graceful degradation instead of a RuntimeError
-            ServeConfig(max_len=64, batch=2, prefill_chunk=8, paged=True,
+            ServeConfig(max_len=64, batch=2, prefill_chunk=8,
                         page_size=8, num_pages=6,
                         watchdog_deadline_s=0.05 if chaos else None),
             params, session=session,
@@ -226,7 +215,7 @@ def main_spec():
             sched = BatchScheduler(
                 cfg, mesh,
                 ServeConfig(max_len=256, batch=4, prefill_chunk=16,
-                            paged=True, page_size=16, num_pages=44,
+                            page_size=16, num_pages=44,
                             spec_decode=spec_on, spec_k=4),
                 params,
             )

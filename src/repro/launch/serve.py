@@ -1,7 +1,6 @@
-"""Serving launcher: continuous-batching decode over a paged KV cache
-(``--dense`` for the baseline layout), chunked prefill-on-attach overlapped
-with in-flight decode, optional temperature/top-k sampling, and monitoring
-of both phases.
+"""Serving launcher: continuous-batching decode over a paged KV cache,
+chunked prefill-on-attach overlapped with in-flight decode, optional
+temperature/top-k sampling, and monitoring of both phases.
 
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
         --smoke --requests 8 --max-new 8 --prefill-chunk 16 \
@@ -27,7 +26,7 @@ the recovery counters next to the pressure stats:
         --smoke --arrival burst --requests 12 --chaos --fault-seed 3 \
         --watchdog-deadline 0.1 --checksum-pages
 
-``--spec`` turns on speculative decoding (paged only): an n-gram
+``--spec`` turns on speculative decoding: an n-gram
 drafter proposes up to ``--spec-k`` tokens from each request's own
 history and one batched verify dispatch scores them all, emitting every
 accepted token — bitwise identical to plain decode, with the acceptance
@@ -52,20 +51,13 @@ def main(argv=None) -> int:
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--prefill-chunk", type=int, default=32,
                     help="prefill token budget per scheduler tick")
-    ap.add_argument("--no-overlap", action="store_true",
-                    help="stop-the-world prefill on attach (A/B baseline)")
     ap.add_argument("--eos-id", type=int, default=None,
                     help="retire requests early on this token id")
-    ap.add_argument("--paged", dest="paged", action="store_true", default=True,
-                    help="paged KV cache (the default): shared page pool + "
-                         "per-slot block tables")
-    ap.add_argument("--dense", dest="paged", action="store_false",
-                    help="dense (batch x max_len) KV cache — the A/B baseline")
     ap.add_argument("--page-size", type=int, default=16,
                     help="tokens per KV page (must divide --max-len)")
     ap.add_argument("--num-pages", type=int, default=None,
-                    help="KV pool size in pages (default: dense-equivalent "
-                         "capacity; size to the expected concurrent-token "
+                    help="KV pool size in pages (default: every slot at "
+                         "--max-len; size to the expected concurrent-token "
                          "peak for the memory win)")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="cross-request prefix sharing: map previously "
@@ -108,8 +100,8 @@ def main(argv=None) -> int:
                          "priority requests are shed (default: never)")
     ap.add_argument("--spec", action="store_true",
                     help="speculative decoding: n-gram self-drafting + one "
-                         "batched verify dispatch per tick (paged only; "
-                         "tokens stay bitwise identical to plain decode)")
+                         "batched verify dispatch per tick (tokens stay "
+                         "bitwise identical to plain decode)")
     ap.add_argument("--spec-k", type=int, default=4,
                     help="max draft tokens proposed per verify dispatch")
     ap.add_argument("--spec-min-match", type=int, default=2,
@@ -161,8 +153,7 @@ def main(argv=None) -> int:
             cfg, mesh,
             ServeConfig(max_len=args.max_len, batch=args.batch,
                         prefill_chunk=args.prefill_chunk,
-                        overlap=not args.no_overlap, eos_id=args.eos_id,
-                        paged=args.paged, page_size=args.page_size,
+                        eos_id=args.eos_id, page_size=args.page_size,
                         num_pages=args.num_pages,
                         prefix_cache=args.prefix_cache,
                         prefix_trie_capacity=args.prefix_trie_capacity,
@@ -222,22 +213,19 @@ def main(argv=None) -> int:
               f"queue depth peak {metrics['queue_depth_peak']} "
               f"(mean {metrics['queue_depth_mean']})")
     kv = sched.kv_cache_stats()
-    if kv["layout"] == "paged":
-        print(f"[serve] paged KV: {kv['kv_bytes']} pool bytes, "
-              f"{kv['num_pages']} pages x {kv['page_size']} tokens, "
-              f"peak {kv['peak_used_pages']} pages in use "
-              f"(utilization {kv['pool_utilization']})")
-        if "prefix_cache" in kv:
-            pc = kv["prefix_cache"]
-            print(f"[serve] prefix cache: hit rate {pc['hit_rate']} "
-                  f"({pc['hits']}/{pc['hits'] + pc['misses']} attaches), "
-                  f"{pc['prefill_tokens_skipped']} prefill tokens skipped, "
-                  f"{pc['pages_saved_by_sharing']} pages saved by sharing, "
-                  f"{pc['cow_copies']} copy-on-write pages, "
-                  f"{pc['trie_pages']} pages cached "
-                  f"({pc['evicted_pages']} evicted)")
-    else:
-        print(f"[serve] dense KV: {kv['kv_bytes']} bytes")
+    print(f"[serve] paged KV: {kv['kv_bytes']} pool bytes, "
+          f"{kv['num_pages']} pages x {kv['page_size']} tokens, "
+          f"peak {kv['peak_used_pages']} pages in use "
+          f"(utilization {kv['pool_utilization']})")
+    if "prefix_cache" in kv:
+        pc = kv["prefix_cache"]
+        print(f"[serve] prefix cache: hit rate {pc['hit_rate']} "
+              f"({pc['hits']}/{pc['hits'] + pc['misses']} attaches), "
+              f"{pc['prefill_tokens_skipped']} prefill tokens skipped, "
+              f"{pc['pages_saved_by_sharing']} pages saved by sharing, "
+              f"{pc['cow_copies']} copy-on-write pages, "
+              f"{pc['trie_pages']} pages cached "
+              f"({pc['evicted_pages']} evicted)")
     pr = kv["pressure"]
     print(f"[serve] pressure: {pr['preemptions']} preemptions "
           f"({pr['pages_freed_by_preempt']} pages freed), "

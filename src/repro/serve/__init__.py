@@ -5,7 +5,6 @@ from repro.serve.serve import (
     make_prefill_chunk_step,
     make_serve_decode_step,
     make_spec_verify_step,
-    serve_cache_pspecs,
     BatchScheduler,
     RequestHandle,
 )
@@ -27,7 +26,7 @@ __all__ = [
     "ServeConfig", "make_decode_step", "make_prefill_step",
     "make_prefill_chunk_step", "make_serve_decode_step",
     "make_spec_verify_step", "draft_tokens",
-    "serve_cache_pspecs", "BatchScheduler", "RequestHandle",
+    "BatchScheduler", "RequestHandle",
     "TrafficConfig", "TrafficRequest", "generate_workload", "replay",
     "FaultConfig", "FaultEvent", "FaultInjector", "generate_faults",
 ]

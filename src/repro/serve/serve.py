@@ -33,7 +33,7 @@ from repro.session import span
 # ServeConfig.step_statics() for what belongs here (and what must not)
 _StepStatics = collections.namedtuple(
     "_StepStatics",
-    ["paged", "greedy", "temperature", "top_k",
+    ["greedy", "temperature", "top_k",
      "prefix_cache", "prefix_trie_capacity",
      "spec_decode", "spec_k", "spec_min_match"],
 )
@@ -50,39 +50,34 @@ class ServeConfig:
     # (fold_in(PRNGKey(sample_seed), request_tag)), folded with the sampled
     # position each step — a request's stream is a pure function of
     # (params, prompt, request_id, sample_seed), never of co-resident
-    # traffic, the overlap schedule, who occupied the slot before, or which
-    # slot the request (re)attaches into — which is what makes a preempted
-    # request's resumed stream identical in any slot.
+    # traffic, who occupied the slot before, or which slot the request
+    # (re)attaches into — which is what makes a preempted request's resumed
+    # stream identical in any slot.
     temperature: float = 1.0
     greedy: bool = True
     top_k: int | None = None
     sample_seed: int = 0
     # chunked prefill-on-attach: token budget (= chunk size) the scheduler
-    # spends on prefill per tick. With ``overlap=True`` (the default) chunks
-    # are dispatched asynchronously BETWEEN decode dispatches, so attaching a
-    # queued request never stalls the in-flight decode pipeline; overlap=False
-    # is the stop-the-world baseline (whole prompt prefilled synchronously on
-    # attach) kept for benchmarks/serve_throughput.py.
+    # spends on prefill per tick. Chunks are dispatched asynchronously
+    # BETWEEN decode dispatches, so attaching a queued request never stalls
+    # the in-flight decode pipeline.
     prefill_chunk: int = 32
-    overlap: bool = True
     # early stop: retire a request when it emits ``eos_id``. EOS needs token
     # *values* on the host, so pending readbacks are additionally flushed
     # every ``eos_check_every`` ticks (bounded detection latency without
     # paying one transfer per step).
     eos_id: int | None = None
     eos_check_every: int = 8
-    # paged KV cache (the default): attention caches live in a shared pool
-    # of ``page_size``-token pages addressed through per-slot block tables,
+    # paged KV cache: attention caches live in a shared pool of
+    # ``page_size``-token pages addressed through per-slot block tables,
     # allocated as prefill/decode actually write and freed on retire — HBM
-    # scales with live tokens instead of batch x max_len. ``num_pages=None``
-    # sizes the pool at dense-equivalent capacity (batch*max_len/page_size);
-    # real deployments size it to the expected concurrent-token peak.
-    # Tokens are bitwise identical paged vs dense. paged=False keeps the
-    # dense (B, max_len) layout (the A/B baseline).
-    paged: bool = True
+    # scales with live tokens instead of batch x max_len. ``max_len`` must
+    # be a multiple of ``page_size``. ``num_pages=None`` sizes the pool for
+    # every slot at max_len (batch*max_len/page_size); real deployments size
+    # it to the expected concurrent-token peak.
     page_size: int = 16
     num_pages: int | None = None
-    # cross-request prefix cache (opt-in, paged only): a radix trie keyed
+    # cross-request prefix cache (opt-in): a radix trie keyed
     # on prompt-token pages maps previously prefilled prompt prefixes into
     # a new request's block table read-only (refcount bump; that part of
     # chunked prefill is skipped), and the first partially-shared page is
@@ -91,7 +86,7 @@ class ServeConfig:
     # still evicts LRU entries nobody else reads).
     prefix_cache: bool = False
     prefix_trie_capacity: int | None = None
-    # preemption under memory pressure (paged only): when the page pool
+    # preemption under memory pressure: when the page pool
     # exhausts, pick a victim request by policy, release its pages, and park
     # it for recompute-resume — re-prefill is cheap through the paged cache
     # (and the PrefixCache/CoW path when enabled), and the resumed stream is
@@ -124,7 +119,7 @@ class ServeConfig:
     # (identity is preserved) and the targeted request retries so a wedged
     # dispatch path cannot stall its stream forever. None = off.
     watchdog_deadline_s: float | None = None
-    # per-page content checksums (paged + prefix_cache only): fingerprint
+    # per-page content checksums (prefix_cache only): fingerprint
     # each prompt page at trie insert and validate before mapping it into
     # a new request's block table — a silently corrupted shared page
     # (bitflip, not NaN) is evicted and re-prefilled fresh instead of
@@ -136,7 +131,7 @@ class ServeConfig:
     # a clear rejection instead of unbounded queueing under sustained
     # pressure or fault rate. None = never shed.
     shed_queue_depth: int | None = None
-    # speculative multi-token decoding (opt-in, paged only): each tick a
+    # speculative multi-token decoding (opt-in): each tick a
     # deterministic prompt-lookup drafter (repro.serve.spec — a pure
     # function of the request's prompt + emitted tokens, no second model)
     # proposes up to ``spec_k`` draft tokens, and ONE batched verify
@@ -153,35 +148,21 @@ class ServeConfig:
     spec_min_match: int = 2
 
     def __post_init__(self):
-        if self.checksum_pages and not (self.paged and self.prefix_cache):
+        if self.checksum_pages and not self.prefix_cache:
             raise ValueError(
-                "checksum_pages=True requires paged=True and "
-                "prefix_cache=True: checksums guard pages shared across "
-                "requests through the prefix trie"
+                "checksum_pages=True requires prefix_cache=True: checksums "
+                "guard pages shared across requests through the prefix trie"
             )
         if self.max_retries < 0 or self.retry_backoff_base < 1 \
                 or self.retry_backoff_cap < 1:
             raise ValueError(
                 "max_retries must be >= 0 and retry backoff base/cap >= 1"
             )
-        if self.prefix_cache and not self.paged:
-            raise ValueError(
-                "prefix_cache=True requires paged=True: prefix sharing maps "
-                "pool pages into multiple slots' block tables, which the "
-                "dense (batch, max_len) layout cannot express"
-            )
         if self.preempt_policy not in ("priority", "pages", "progress",
                                        "never"):
             raise ValueError(
                 f"preempt_policy must be one of priority|pages|progress|never,"
                 f" got {self.preempt_policy!r}"
-            )
-        if self.spec_decode and not self.paged:
-            raise ValueError(
-                "spec_decode=True requires paged=True: the batched verify "
-                "step scores K+1 positions through the paged pool's "
-                "block-table reads, and rollback of rejected positions "
-                "relies on the pool's masked scatter writes"
             )
         if self.spec_decode and (self.spec_k < 1 or self.spec_min_match < 1):
             raise ValueError(
@@ -200,7 +181,7 @@ class ServeConfig:
         shape on its own, and excluding them lets A/B benchmark pairs
         (ample vs tight pool, eos on/off) share compiled traces."""
         return _StepStatics(
-            self.paged, self.greedy, self.temperature, self.top_k,
+            self.greedy, self.temperature, self.top_k,
             self.prefix_cache, self.prefix_trie_capacity,
             self.spec_decode, self.spec_k, self.spec_min_match,
         )
@@ -260,16 +241,6 @@ def cache_pspec_tree(cfg, mesh, caches):
     return jax.tree_util.tree_unflatten(treedef, specs)
 
 
-def serve_cache_pspecs(cfg, mesh, batch: int, max_len: int, *,
-                       paged: bool = False, page_size: int = 16,
-                       num_pages: int | None = None):
-    caches = jax.eval_shape(
-        lambda: T.init_cache(cfg, batch, max_len, paged=paged,
-                             page_size=page_size, num_pages=num_pages)
-    )
-    return cache_pspec_tree(cfg, mesh, caches)
-
-
 def make_prefill_step(cfg, mesh):
     lc = LogicalConstraints(mesh, SH.activation_rules(cfg, mesh))
 
@@ -306,8 +277,8 @@ def _sample_tokens(logits, rng_keys, positions, *, greedy, temperature,
     the key ``fold_in(rng_keys[i], positions[i])`` — STATELESS per step,
     so a request's sampled stream is a pure function of (params, prompt,
     request_id, sample_seed): it cannot depend on co-resident requests'
-    decode traffic, the overlap schedule, who occupied the slot before,
-    or which slot it (re)attaches into.
+    decode traffic, who occupied the slot before, or which slot it
+    (re)attaches into.
     Padded vocab ids are masked out. Returns tokens (N,) int32."""
     if greedy:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -330,15 +301,15 @@ def _moe_counters(aux) -> tuple:
     return (jnp.stack([aux["moe_routed"], aux["moe_busiest"]]).astype(jnp.int32),)
 
 
-def make_serve_decode_step(cfg, mesh, *, paged=False, greedy=True,
-                           temperature=1.0, top_k=None):
+def make_serve_decode_step(cfg, mesh, *, greedy=True, temperature=1.0,
+                           top_k=None):
     """Continuous-batching decode: per-slot positions + active mask.
 
-    Inactive slots (empty, or mid-prefill — their cache lines belong to the
+    Inactive slots (empty, or mid-prefill — their pages belong to the
     concurrently dispatched prefill chunks) neither write the KV cache nor
     advance recurrent state; their sampled tokens are garbage and ignored.
-    ``paged=True`` adds a ``block_tables`` argument routing attention-cache
-    writes and reads through the shared page pool.
+    Attention-cache writes and reads go through the shared page pool by
+    the ``block_tables`` argument.
 
     The trailing ``fault_mask`` (B,) bool argument poisons the masked
     slots' logits with NaN *before* the finiteness sentinel — the chaos
@@ -365,30 +336,18 @@ def make_serve_decode_step(cfg, mesh, *, paged=False, greedy=True,
                                  logits.shape[:1])
         return sample(logits, rng_keys, pos_v), bad
 
-    if paged:
-        def decode_step(params, tokens, pos, active, caches, block_tables,
-                        rng_keys, fault_mask):
-            """tokens: (B,1); pos: (B,); active: (B,) bool; block_tables:
-            (B, n_logical) int32; rng_keys: (B,2) uint32 (static per slot
-            — the sampling key is folded with the position); fault_mask:
-            (B,) bool."""
-            out = T.decode_step(
-                params, tokens, pos, cfg, caches, lc, active=active,
-                block_tables=block_tables, with_aux=moe,
-            )
-            tok, bad = _poison_and_sample(out[0], fault_mask, rng_keys, pos)
-            return (tok[:, None], bad, out[1]) + (_moe_counters(out[2]) if moe else ())
-    else:
-        def decode_step(params, tokens, pos, active, caches, rng_keys,
-                        fault_mask):
-            """tokens: (B,1) int32; pos: (B,) int32; active: (B,) bool;
-            fault_mask: (B,) bool."""
-            out = T.decode_step(
-                params, tokens, pos, cfg, caches, lc, active=active,
-                with_aux=moe,
-            )
-            tok, bad = _poison_and_sample(out[0], fault_mask, rng_keys, pos)
-            return (tok[:, None], bad, out[1]) + (_moe_counters(out[2]) if moe else ())
+    def decode_step(params, tokens, pos, active, caches, block_tables,
+                    rng_keys, fault_mask):
+        """tokens: (B,1); pos: (B,); active: (B,) bool; block_tables:
+        (B, n_logical) int32; rng_keys: (B,2) uint32 (static per slot —
+        the sampling key is folded with the position); fault_mask: (B,)
+        bool."""
+        out = T.decode_step(
+            params, tokens, pos, cfg, caches, lc, active=active,
+            block_tables=block_tables, with_aux=moe,
+        )
+        tok, bad = _poison_and_sample(out[0], fault_mask, rng_keys, pos)
+        return (tok[:, None], bad, out[1]) + (_moe_counters(out[2]) if moe else ())
 
     return decode_step
 
@@ -397,17 +356,16 @@ def _is_paged_leaf(path) -> bool:
     return "pages" in _cache_path_name(path)
 
 
-def make_prefill_chunk_step(cfg, mesh, *, paged=False, greedy=True,
-                            temperature=1.0, top_k=None):
-    """One chunk of one request's prompt into ONE slot's cache lines.
+def make_prefill_chunk_step(cfg, mesh, *, greedy=True, temperature=1.0,
+                            top_k=None):
+    """One chunk of one request's prompt into ONE slot's pages and state.
 
     The slot's recurrent-state rows are sliced out of the stacked cache
     pytree, run through ``T.prefill_chunk`` at batch 1, and scattered back —
     the other slots' state passes through untouched. Paged attention pools
     are passed whole: the chunk writes only the pages its block-table row
-    owns, so it commutes with in-flight decode dispatches exactly like the
-    dense slot-sliced write does. A model with MoE layers returns the
-    chunk's MoE counters last (``_moe_counters``)."""
+    owns, so it commutes with in-flight decode dispatches. A model with MoE
+    layers returns the chunk's MoE counters last (``_moe_counters``)."""
     lc = LogicalConstraints(mesh, SH.activation_rules(cfg, mesh))
     moe = cfg.moe is not None
     sample = functools.partial(
@@ -444,17 +402,13 @@ def make_prefill_chunk_step(cfg, mesh, *, paged=False, greedy=True,
                    rng_keys):
         """tokens: (1,C) int32 (padded); start/length: (1,) int32;
         slot: () int32; caches: full stacked tree; block_tables: the full
-        (B, n_logical) table (or None when dense); rng_keys: (B,2) static
-        per-slot base keys. Returns (next_tok (1,) sampled at the last
-        valid position, bad (1,) — the NaN/Inf finiteness sentinel over
-        the chunk's logits, catching a corrupted shared page read during
-        prefill the same way the decode sentinel catches it — and
-        new_caches)."""
+        (B, n_logical) table; rng_keys: (B,2) static per-slot base keys.
+        Returns (next_tok (1,) sampled at the last valid position, bad
+        (1,) — the NaN/Inf finiteness sentinel over the chunk's logits,
+        catching a corrupted shared page read during prefill the same way
+        the decode sentinel catches it — and new_caches)."""
         slot_caches = _slot_slice(caches, slot)
-        tbl_row = (
-            jax.lax.dynamic_slice_in_dim(block_tables, slot, 1, axis=0)
-            if paged else None
-        )
+        tbl_row = jax.lax.dynamic_slice_in_dim(block_tables, slot, 1, axis=0)
         out = T.prefill_chunk(
             params, {"tokens": tokens}, cfg, slot_caches, start, length, lc,
             block_tables=tbl_row, with_aux=moe,
@@ -466,15 +420,7 @@ def make_prefill_chunk_step(cfg, mesh, *, paged=False, greedy=True,
         new_caches = _scatter_back(caches, out[1], slot)
         return (next_tok, bad, new_caches) + (_moe_counters(out[2]) if moe else ())
 
-    if paged:
-        return chunk_step
-
-    def chunk_step_dense(params, tokens, start, length, slot, caches,
-                         rng_keys):
-        return chunk_step(params, tokens, start, length, slot, caches, None,
-                          rng_keys)
-
-    return chunk_step_dense
+    return chunk_step
 
 
 def make_spec_verify_step(cfg, mesh, *, greedy=True, temperature=1.0,
@@ -609,9 +555,9 @@ def make_encoder_step(cfg, mesh):
 # speculation knobs, so two distinct configurations can never collide on
 # one entry (a collision would hand a spec scheduler a triple with no
 # verify step, or a prefix scheduler one with no CoW step). 32 entries
-# cover every concurrent A/B pattern in the repo (paged/dense x sampling x
-# prefix x spec x arch) without thrashing; an evicted entry merely
-# recompiles on the next scheduler construction.
+# cover every concurrent pattern in the repo (sampling x prefix x spec x
+# arch) without thrashing; an evicted entry merely recompiles on the next
+# scheduler construction.
 @functools.lru_cache(maxsize=32)
 def _serve_step_fns(cfg, mesh, statics: _StepStatics):
     """Shared jitted (decode, prefill-chunk, cow-copy, spec-verify) tuple
@@ -621,11 +567,11 @@ def _serve_step_fns(cfg, mesh, statics: _StepStatics):
     None unless spec decoding is on (its trace depends on the arch —
     recurrent/hybrid patterns verify in two passes so state advances
     over exactly the accepted tokens)."""
-    kw = dict(paged=statics.paged, greedy=statics.greedy,
-              temperature=statics.temperature, top_k=statics.top_k)
+    kw = dict(greedy=statics.greedy, temperature=statics.temperature,
+              top_k=statics.top_k)
     cow = (
         jax.jit(make_cow_copy_step(), donate_argnums=(0,))
-        if statics.paged and statics.prefix_cache else None
+        if statics.prefix_cache else None
     )
     verify = None
     if statics.spec_decode:
@@ -995,31 +941,30 @@ class BatchScheduler:
 
     Every slot carries its own position (``pos`` is a (B,) vector): a request
     attached mid-flight decodes at *its* sequence position, not the batch's.
-    Attaching runs a real prefill — the prompt is written into the slot's KV
-    cache in fixed ``prefill_chunk``-token chunks, ONE chunk dispatched per
+    Attaching runs a real prefill — the prompt is written into the slot's
+    pages in fixed ``prefill_chunk``-token chunks, ONE chunk dispatched per
     tick *after* that tick's decode dispatch, so the decode pipeline never
-    waits on a prefill (``overlap=True``; ``overlap=False`` prefills the
-    whole prompt synchronously on attach — the stop-the-world baseline).
-    Decode and prefill commute on the cache: inactive/prefilling slots are
-    masked out of the decode step's cache writes and recurrent-state
-    advance, and a prefill chunk only touches its own slot's cache lines —
-    so the generated tokens are bitwise identical with overlap on or off.
+    waits on a prefill. Decode and prefill commute on the cache:
+    inactive/prefilling slots are masked out of the decode step's cache
+    writes and recurrent-state advance, and a prefill chunk only touches
+    its own slot's pages and state rows — so the generated tokens are
+    bitwise those of a one-shot prefill followed by sequential decode.
     Reattaching a freed slot restores its recurrent-state carries to their
-    initial values (stale attention KV is already masked by the visible
-    window), so a reused slot behaves exactly like a fresh one.
+    initial values (stale attention KV is already out of its block table),
+    so a reused slot behaves exactly like a fresh one.
 
-    Attention KV lives in a **paged cache** by default (``scfg.paged``): a
-    shared pool of ``page_size``-token pages plus a per-slot block table.
-    Pages are allocated exactly as prefill chunks / decode steps write them
-    and freed when the request retires, so KV HBM scales with *live tokens*
-    instead of ``batch x max_len``; decode attention gathers K/V through
-    the table (``kernels.paged_attention`` — Pallas on TPU, a gather oracle
-    elsewhere that is bitwise identical to the dense layout). Exhausting
+    Attention KV lives in a **paged cache**: a shared pool of
+    ``page_size``-token pages plus a per-slot block table. Pages are
+    allocated exactly as prefill chunks / decode steps write them and freed
+    when the request retires, so KV HBM scales with *live tokens* instead
+    of ``batch x max_len``; attention reads K/V through the table
+    (``kernels.paged_attention`` — Pallas on TPU, a gather oracle elsewhere
+    that is bitwise identical to the model's dense cache). An attention-free
+    stack has no pool on the device and is booked the same way. Exhausting
     the pool raises a clean error before any page is handed out —
-    neighbors' pages are never remapped. ``paged=False`` keeps the dense
-    layout; generated tokens are bitwise identical either way.
+    neighbors' pages are never remapped.
 
-    With ``scfg.prefix_cache`` (opt-in, paged only) a **cross-request
+    With ``scfg.prefix_cache`` (opt-in) a **cross-request
     prefix cache** rides on the pool: completed prefills insert their
     prompts' full pages into a radix trie keyed on page-token tuples, and
     attach walks the trie, maps every fully-matched resident page into the
@@ -1115,11 +1060,11 @@ class BatchScheduler:
                     f"prefill_chunk={scfg.prefill_chunk} must be <= the "
                     f"recurrent chunk {inner} or a multiple of it"
                 )
-        if scfg.paged and scfg.max_len % scfg.page_size:
+        if scfg.max_len % scfg.page_size:
             raise ValueError(
-                f"paged serving needs max_len ({scfg.max_len}) divisible by "
-                f"page_size ({scfg.page_size}) so the paged and dense layouts "
-                f"stay bitwise interchangeable"
+                f"max_len ({scfg.max_len}) must be divisible by page_size "
+                f"({scfg.page_size}): each slot's block table holds "
+                f"max_len / page_size pages"
             )
         # default: off, but env-activatable (TALP_ENABLE=1) like every other
         # entry point; the caller owns finalize() (also via self.session)
@@ -1172,37 +1117,31 @@ class BatchScheduler:
         # allocator. Tables are host-authored (numpy, -1 = unallocated) and
         # mirrored to device lazily — one small upload per tick at most,
         # only when an allocation or a free actually changed them.
-        if scfg.paged:
-            self._max_pages = scfg.max_len // scfg.page_size
-            n_pages = scfg.num_pages
-            if n_pages is None:
-                n_pages = scfg.batch * self._max_pages
-            self._alloc: PageAllocator | None = PageAllocator(n_pages)
-            self._tables = np.full((scfg.batch, self._max_pages), -1, np.int32)
-            self._slot_pages: list[list[int]] = [[] for _ in range(scfg.batch)]
-            self._tables_dirty = True
-            self._tables_dev = None
-            self.caches = T.init_cache(
-                cfg, scfg.batch, scfg.max_len, paged=True,
-                page_size=scfg.page_size, num_pages=n_pages,
-            )
-            self._prefix: PrefixCache | None = (
-                PrefixCache(scfg.page_size, self._alloc,
-                            capacity=scfg.prefix_trie_capacity)
-                if scfg.prefix_cache else None
-            )
-        else:
-            self._alloc = None
-            self._prefix = None
-            self.caches = T.init_cache(cfg, scfg.batch, scfg.max_len)
+        self._max_pages = scfg.max_len // scfg.page_size
+        n_pages = scfg.num_pages
+        if n_pages is None:
+            n_pages = scfg.batch * self._max_pages
+        self._alloc = PageAllocator(n_pages)
+        self._tables = np.full((scfg.batch, self._max_pages), -1, np.int32)
+        self._slot_pages: list[list[int]] = [[] for _ in range(scfg.batch)]
+        self._tables_dirty = True
+        self._tables_dev = None
+        self.caches = T.init_cache(
+            cfg, scfg.batch, scfg.max_len, paged=True,
+            page_size=scfg.page_size, num_pages=n_pages,
+        )
+        self._prefix: PrefixCache | None = (
+            PrefixCache(scfg.page_size, self._alloc,
+                        capacity=scfg.prefix_trie_capacity)
+            if scfg.prefix_cache else None
+        )
         # per-slot sampling key rows, carried on device. In sampled mode the
         # attach overwrites the slot's row with the REQUEST's key
         # (fold_in(base_key, request_tag)), and each sampling step folds that
         # with the sampled position — so a request's stream is a pure
         # function of (params, prompt, request_id, sample_seed), independent
-        # of slot placement (a preempted request may resume elsewhere),
-        # co-resident traffic, and the overlap schedule (greedy never reads
-        # the keys)
+        # of slot placement (a preempted request may resume elsewhere) and
+        # co-resident traffic (greedy never reads the keys)
         self._base_key = jax.random.PRNGKey(scfg.sample_seed)
         self.rng_keys = jax.random.split(self._base_key, scfg.batch)
         # fresh-state template for slot reuse: unlike attention KV (stale
@@ -1291,9 +1230,8 @@ class BatchScheduler:
             # two phases actually co-existed); "decode_after_prefill_ticks"
             # counts ticks whose decode dispatch only happened AFTER prefill
             # work ran in the same tick — i.e. the decode pipeline waited on
-            # a prefill. The overlap guarantee benchmarks/serve_throughput.py
-            # asserts is overlap_ticks > 0 and decode_after_prefill_ticks
-            # == 0; the stop-the-world baseline trips the latter.
+            # a prefill. The no-decode-gap guarantee is overlap_ticks > 0
+            # under mixed traffic and decode_after_prefill_ticks == 0.
             "overlap_ticks": 0, "decode_after_prefill_ticks": 0,
             # pressure accounting (the serving-under-load counters surfaced
             # by kv_cache_stats()["pressure"] and launch/serve.py)
@@ -1340,20 +1278,19 @@ class BatchScheduler:
                 f"(prompt {len(prompt)}, max_new {max_new}) but "
                 f"max_len={self.scfg.max_len}"
             )
-        if self._alloc is not None:
-            # a request that cannot fit even with the pool to itself would
-            # otherwise park forever under the preemption policy (and the
-            # admission queue hides the old immediate RuntimeError) — reject
-            # it at the door like the max_len check above
-            pages = -(-need // self.scfg.page_size)
-            if pages > self._alloc.num_pages:
-                raise ValueError(
-                    f"request {request_id!r} needs {pages} page(s) "
-                    f"(prompt {len(prompt)}, max_new {max_new}, page_size "
-                    f"{self.scfg.page_size}) but the pool only holds "
-                    f"{self._alloc.num_pages}; raise ServeConfig.num_pages "
-                    f"(--num-pages)"
-                )
+        # a request that cannot fit even with the pool to itself would
+        # otherwise park forever under the preemption policy (and the
+        # admission queue hides the old immediate RuntimeError) — reject it
+        # at the door like the max_len check above
+        pages = -(-need // self.scfg.page_size)
+        if pages > self._alloc.num_pages:
+            raise ValueError(
+                f"request {request_id!r} needs {pages} page(s) "
+                f"(prompt {len(prompt)}, max_new {max_new}, page_size "
+                f"{self.scfg.page_size}) but the pool only holds "
+                f"{self._alloc.num_pages}; raise ServeConfig.num_pages "
+                f"(--num-pages)"
+            )
         req = {
             "id": request_id, "prompt": prompt, "max_new": max_new,
             "generated": [], "_pending": 0, "priority": int(priority),
@@ -1528,8 +1465,6 @@ class BatchScheduler:
         return req["_not_before"] <= self.stats["ticks"]
 
     def _resume_fits(self, req) -> bool:
-        if self._alloc is None:
-            return True
         need = len(req["prompt"]) + max(len(req["generated"]), 1)
         need = -(-need // self.scfg.page_size)
         avail = self._alloc.free_pages
@@ -1693,7 +1628,7 @@ class BatchScheduler:
         if req is None:
             return  # the flush retired it — pressure already relieved
         with self.session.region("preempt"):
-            freed = len(self._slot_pages[slot]) if self._alloc else 0
+            freed = len(self._slot_pages[slot])
             self._detach(slot)
             req["_status"] = "preempted"
             self._parked.append(req)
@@ -1721,13 +1656,12 @@ class BatchScheduler:
     def _reset_slots(self, slots: list[int]) -> None:
         """Restore reused slots' recurrent-state cache rows (SSM/conv/xLSTM
         carries) to their initial values before the new request runs.
-        Attention KV needs no reset — stale lines never enter the visible
-        window (dense: cache_len masking; paged: freed pages leave the
-        block table) — and the sampling keys are stateless (folded with
-        the position per step), but recurrent state carries
-        unconditionally, so without this the first prefill chunk (or
-        decode step) of a reattached slot would continue from the retired
-        request's final state."""
+        Attention KV needs no reset — freed pages leave the block table,
+        so stale lines never enter the visible window — and the sampling
+        keys are stateless (folded with the position per step), but
+        recurrent state carries unconditionally, so without this the first
+        prefill chunk (or decode step) of a reattached slot would continue
+        from the retired request's final state."""
         if not self._has_recurrent:
             return
         # one shape whatever the number of slots (the rest of the index
@@ -1858,7 +1792,7 @@ class BatchScheduler:
                 self._hang_pending = self.faults.fcfg.hang_s
                 self.faults.record(e.kind)
             elif e.kind == "alloc_spike":
-                if self._alloc is None or self._alloc.free_pages == 0:
+                if self._alloc.free_pages == 0:
                     self.faults.defer(e, tick)
                     continue
                 n = min(self.faults.fcfg.spike_pages,
@@ -1869,8 +1803,6 @@ class BatchScheduler:
                 )
                 self.faults.record(e.kind)
             elif e.kind == "page_corrupt":
-                if self._alloc is None:
-                    continue  # dense layout: no pages to corrupt; drop
                 mode = self.faults.fcfg.corrupt_mode
                 cand = self._fault_page_candidates(mode, e.request_id)
                 if not cand:
@@ -1982,8 +1914,7 @@ class BatchScheduler:
 
     def _ensure_pages(self, slot: int, last_pos: int, req: dict) -> None:
         """Grow ``slot``'s block table so position ``last_pos`` (inclusive)
-        is backed by a physical page; no-op when already covered (and in
-        dense mode).
+        is backed by a physical page; no-op when already covered.
 
         Pages are acquired ONE AT A TIME so each gets the full
         ``_alloc_pages`` escalation (trie eviction, victim preemption)
@@ -1992,8 +1923,6 @@ class BatchScheduler:
         already taken are unwound page-by-page (freed, table row restored
         to -1) before the pressure propagates, so a failed grow can never
         leak a partial allocation."""
-        if self._alloc is None:
-            return
         need = last_pos // self.scfg.page_size + 1
         have = len(self._slot_pages[slot])
         if need <= have:
@@ -2122,7 +2051,7 @@ class BatchScheduler:
         return chain, donor, donor_rows
 
     def _release_slot_pages(self, slot: int) -> None:
-        if self._alloc is None or not self._slot_pages[slot]:
+        if not self._slot_pages[slot]:
             return
         if self._corrupted_pages:
             self._scrub_slot(slot)
@@ -2147,45 +2076,42 @@ class BatchScheduler:
     def kv_cache_stats(self) -> dict:
         """KV-memory accounting for benchmarks and reports.
 
-        ``kv_bytes`` is the attention-cache HBM footprint as allocated
-        (dense: the full (B, max_len) buffers; paged: the pool). Paged
-        additionally reports live-token peaks and pool utilization."""
+        ``kv_bytes`` is the attention-cache HBM footprint as allocated (the
+        whole pool), beside live-page peaks and pool utilization."""
         attn_bytes = 0
         for path, leaf in jax.tree_util.tree_flatten_with_path(self.caches)[0]:
             name = _cache_path_name(path)
             if "attn" in name:
                 attn_bytes += leaf.size * leaf.dtype.itemsize
-        out = {"layout": "paged" if self.scfg.paged else "dense",
-               "kv_bytes": int(attn_bytes)}
-        if self._alloc is not None:
-            per_page = attn_bytes / max(self._alloc.num_pages, 1)
-            out.update(
-                page_size=self.scfg.page_size,
-                num_pages=self._alloc.num_pages,
-                pages_in_use=self._alloc.used,
-                peak_used_pages=self._alloc.peak_used,
-                peak_live_kv_bytes=int(self._alloc.peak_used * per_page),
-                pool_utilization=round(
-                    self._alloc.peak_used / max(self._alloc.num_pages, 1), 4
-                ),
-                refcounted_pages=len(self._alloc.refs),
-                shared_pages=self._alloc.shared_pages,
-            )
-            if self._prefix is not None:
-                st = self._prefix.stats
-                lookups = st["hits"] + st["misses"]
-                out["prefix_cache"] = {
-                    "trie_pages": self._prefix.size,
-                    "hits": st["hits"],
-                    "misses": st["misses"],
-                    "hit_rate": round(st["hits"] / lookups, 4) if lookups else 0.0,
-                    "hit_tokens": st["hit_tokens"],
-                    "prefill_tokens_skipped": st["prefill_tokens_skipped"],
-                    "pages_saved_by_sharing": st["pages_shared"],
-                    "cow_copies": st["cow_copies"],
-                    "inserted_pages": st["inserted_pages"],
-                    "evicted_pages": st["evicted_pages"],
-                }
+        per_page = attn_bytes / max(self._alloc.num_pages, 1)
+        out = {
+            "kv_bytes": int(attn_bytes),
+            "page_size": self.scfg.page_size,
+            "num_pages": self._alloc.num_pages,
+            "pages_in_use": self._alloc.used,
+            "peak_used_pages": self._alloc.peak_used,
+            "peak_live_kv_bytes": int(self._alloc.peak_used * per_page),
+            "pool_utilization": round(
+                self._alloc.peak_used / max(self._alloc.num_pages, 1), 4
+            ),
+            "refcounted_pages": len(self._alloc.refs),
+            "shared_pages": self._alloc.shared_pages,
+        }
+        if self._prefix is not None:
+            st = self._prefix.stats
+            lookups = st["hits"] + st["misses"]
+            out["prefix_cache"] = {
+                "trie_pages": self._prefix.size,
+                "hits": st["hits"],
+                "misses": st["misses"],
+                "hit_rate": round(st["hits"] / lookups, 4) if lookups else 0.0,
+                "hit_tokens": st["hit_tokens"],
+                "prefill_tokens_skipped": st["prefill_tokens_skipped"],
+                "pages_saved_by_sharing": st["pages_shared"],
+                "cow_copies": st["cow_copies"],
+                "inserted_pages": st["inserted_pages"],
+                "evicted_pages": st["evicted_pages"],
+            }
         out["pressure"] = {
             k: self.stats[k]
             for k in ("preemptions", "resumes", "cancellations",
@@ -2247,24 +2173,21 @@ class BatchScheduler:
         prompt = task["prompt"]
         chunk = np.zeros((1, C), np.int32)
         chunk[0, :L] = prompt[start : start + L]
-        if self.scfg.paged:
-            # back the chunk's positions [start, start+L) with pool pages
-            # before anything writes them; pool pressure here may preempt a
-            # victim, park this request, or (fatal) unwind and raise —
-            # either way this chunk does not dispatch
-            try:
-                self._ensure_pages(task["slot"], start + L - 1, task["req"])
-            except _PoolPressure as e:
-                self._handle_pressure(task["slot"], e)
-                return
-        args = (
+        # back the chunk's positions [start, start+L) with pool pages before
+        # anything writes them; pool pressure here may preempt a victim,
+        # park this request, or (fatal) unwind and raise — either way this
+        # chunk does not dispatch
+        try:
+            self._ensure_pages(task["slot"], start + L - 1, task["req"])
+        except _PoolPressure as e:
+            self._handle_pressure(task["slot"], e)
+            return
+        next_tok, bad, self.caches, *moe = self.prefill(
             self.params, jnp.asarray(chunk),
             jnp.asarray([start], jnp.int32), jnp.asarray([L], jnp.int32),
             jnp.asarray(task["slot"], jnp.int32), self.caches,
+            self._tables_device(), self.rng_keys,
         )
-        if self.scfg.paged:
-            args += (self._tables_device(),)
-        next_tok, bad, self.caches, *moe = self.prefill(*args, self.rng_keys)
         self._keep_moe_counters("prefill", moe, L)
         task["done"] = start + L
         self.stats["prefill_chunks"] += 1
@@ -2472,7 +2395,7 @@ class BatchScheduler:
                     f"[kv_cache_stats: {self.kv_cache_stats()}]"
                 )
         self._flush()
-        if self._spike_holds and self._alloc is not None:
+        if self._spike_holds:
             # the workload finished while an injected spike still held
             # pool pages: give them back — a chaos run must end with the
             # same zero-leak guarantee as any other drain
@@ -2537,21 +2460,19 @@ class BatchScheduler:
         immediately (a few small arrays), amortized over up to
         ``spec_k+1`` tokens."""
         plans = self._plan_drafts()
-        if self.scfg.paged:
-            with span("serve.pages"):
-                for slot, plan in plans.items():
-                    req = self.active[slot]
-                    if req is None:
-                        continue  # a pressure round below evicted this slot
-                    try:
-                        self._ensure_pages(
-                            slot, int(self.pos[slot]) + len(plan["drafts"]),
-                            req,
-                        )
-                    except _PoolPressure as e:
-                        self._handle_pressure(slot, e)
-                if any(r is not None for r in self.active):
-                    self._tables_device()  # the upload, for the verify
+        with span("serve.pages"):
+            for slot, plan in plans.items():
+                req = self.active[slot]
+                if req is None:
+                    continue  # a pressure round below evicted this slot
+                try:
+                    self._ensure_pages(
+                        slot, int(self.pos[slot]) + len(plan["drafts"]), req,
+                    )
+                except _PoolPressure as e:
+                    self._handle_pressure(slot, e)
+            if any(r is not None for r in self.active):
+                self._tables_device()  # the upload, for the verify
         decoding = list(self.active)
         plans = {s: p for s, p in plans.items() if decoding[s] is not None}
         if bool(self._prefills) and plans:
@@ -2672,7 +2593,7 @@ class BatchScheduler:
     def _tick_counters(self) -> dict:
         """Waiting requests (queued and parked), in-flight prefills, prompt
         tokens not yet prefilled over all of them, decoding slots and free
-        pages (paged only)."""
+        pages."""
         waiting = self.queue + self._parked
         out = {
             "queued": len(waiting),
@@ -2680,9 +2601,8 @@ class BatchScheduler:
             "backlog_tok": sum(len(r["prompt"]) for r in waiting) + sum(
                 len(t["prompt"]) - t["done"] for t in self._prefills),
             "decoding": sum(r is not None for r in self.active),
+            "free_pages": self._alloc.free_pages,
         }
-        if self._alloc is not None:
-            out["free_pages"] = self._alloc.free_pages
         return out
 
     def _decode_pages(self) -> dict:
@@ -2706,23 +2626,14 @@ class BatchScheduler:
             self._apply_faults()
         chunks_at_tick_start = self.stats["prefill_chunks"]
         with compat.use_mesh(self.mesh):
-            if not self.scfg.overlap:
-                # stop-the-world baseline: complete every pending prefill
-                # before this tick's decode may proceed
-                while self._prefills:
-                    self._dispatch_prefill_chunk()
-                if self._seeds:
-                    self._apply_seeds()
-                    jax.block_until_ready(self.tokens)
-            else:
-                self._apply_seeds()  # seeds collected since last tick
+            self._apply_seeds()  # seeds collected since last tick
             if self.scfg.spec_decode:
                 # speculative tick: draft windows + ONE batched verify
                 # replace the one-token decode dispatch entirely (page
                 # ensuring moves inside — the window's extent is per-plan)
                 self._spec_tick(chunks_at_tick_start)
                 decoding: list[dict | None] = [None] * self.scfg.batch
-            elif self.scfg.paged:
+            else:
                 # this step writes each active slot's K/V at pos[slot]: back
                 # any page boundary being crossed BEFORE snapshotting the
                 # active set — pool pressure here can preempt (remove) a
@@ -2737,17 +2648,13 @@ class BatchScheduler:
                                 self._handle_pressure(slot, e)
                     if any(r is not None for r in self.active):
                         self._tables_device()  # the upload, for the decode
-            if not self.scfg.spec_decode:
                 decoding = list(self.active)
             if bool(self._prefills) and any(r is not None for r in decoding):
                 self.stats["overlap_ticks"] += 1
             if any(r is not None for r in decoding):
                 active = np.asarray([r is not None for r in decoding])
-                if self.scfg.paged:
-                    args = (jnp.asarray(active), self.caches,
-                            self._tables_device())
-                else:
-                    args = (jnp.asarray(active), self.caches)
+                args = (jnp.asarray(active), self.caches,
+                        self._tables_device())
                 # snapshot pos: jnp.asarray can zero-copy alias an aligned
                 # numpy buffer on CPU, and the async decode would then read
                 # the ``self.pos`` mutations below (and next tick's attach
@@ -2773,8 +2680,8 @@ class BatchScheduler:
                     time.sleep(self._hang_pending)
                     self._hang_pending = 0.0
                 # counted only while a capture records them, as the tick's
-                pages = (self._decode_pages() if self.scfg.paged
-                         and jax.profiler.TraceAnnotation.is_enabled() else {})
+                pages = (self._decode_pages()
+                         if jax.profiler.TraceAnnotation.is_enabled() else {})
                 with span("serve.decode", **pages):
                     self.tokens, bad_dev, self.caches, *moe = self.decode(
                         self.params, self.tokens, pos_now,
@@ -2824,7 +2731,7 @@ class BatchScheduler:
                         self._seeds[slot] = hist.pop(0)
                         if not hist:
                             del self._replay[slot]
-            if self.scfg.overlap and self._prefills:
+            if self._prefills:
                 self._dispatch_prefill_chunk()
         flush_due = any(
             req is not None

@@ -164,7 +164,7 @@ def _chaos_run(cfg, mesh, params, *, events=None, fcfg=None, greedy=True,
     injector = None
     if events is not None or fcfg is not None:
         injector = FaultInjector(fcfg, events=events)
-    kw = dict(max_len=64, batch=2, prefill_chunk=4, paged=True, page_size=8,
+    kw = dict(max_len=64, batch=2, prefill_chunk=4, page_size=8,
               num_pages=16, watchdog_deadline_s=0.05)
     if not greedy:
         kw.update(greedy=False, temperature=0.8, top_k=20, sample_seed=3)
@@ -243,7 +243,7 @@ def test_shed_queue_depth_drops_lowest_priority_youngest():
     with mesh:
         sched = BatchScheduler(
             cfg, mesh,
-            ServeConfig(max_len=64, batch=1, prefill_chunk=4, paged=True,
+            ServeConfig(max_len=64, batch=1, prefill_chunk=4,
                         page_size=8, num_pages=16, shed_queue_depth=2),
             params,
         )
@@ -279,7 +279,7 @@ def test_checksum_catches_bitflip_and_evicts_subtree():
     with mesh:
         sched = BatchScheduler(
             cfg, mesh,
-            ServeConfig(max_len=64, batch=2, prefill_chunk=4, paged=True,
+            ServeConfig(max_len=64, batch=2, prefill_chunk=4,
                         page_size=8, num_pages=16, prefix_cache=True,
                         checksum_pages=True),
             params, fault_injector=injector,

@@ -64,7 +64,7 @@ def serve_spans(tmp_path_factory):
     params = init_params(T.model_params(cfg), jax.random.PRNGKey(0), cfg.param_dtype)
     with mesh:
         sched = BatchScheduler(cfg, mesh, ServeConfig(
-            max_len=128, batch=2, prefill_chunk=32, paged=True, page_size=16), params)
+            max_len=128, batch=2, prefill_chunk=32, page_size=16), params)
 
         def run():
             for rid, n in enumerate((40, 70)):
@@ -134,7 +134,7 @@ def test_spec_readbacks_nest(tmp_path):
     params = init_params(T.model_params(cfg), jax.random.PRNGKey(0), cfg.param_dtype)
     with mesh:
         sched = BatchScheduler(cfg, mesh, ServeConfig(
-            max_len=64, batch=2, prefill_chunk=8, paged=True, page_size=8,
+            max_len=64, batch=2, prefill_chunk=8, page_size=8,
             spec_decode=True, spec_k=2), params)
 
         def run():

@@ -101,7 +101,7 @@ def _copy_regime(params):
 
 def _run_sched(cfg, mesh, params, prompts, *, spec, greedy=True, max_new=6,
                num_pages=32, spec_k=4, injector=None, **over):
-    kw = dict(max_len=64, batch=2, prefill_chunk=4, paged=True, page_size=8,
+    kw = dict(max_len=64, batch=2, prefill_chunk=4, page_size=8,
               num_pages=num_pages)
     if not greedy:
         kw.update(greedy=False, temperature=0.8, top_k=20, sample_seed=3)
@@ -133,7 +133,7 @@ _PROMPTS = [[5, 9, 13, 7] * 3, list(range(20, 28))]
 
 def test_serve_step_fns_keys_on_full_statics_no_collision():
     cfg, mesh, _ = _fixtures()
-    kw = dict(max_len=64, batch=2, prefill_chunk=4, paged=True, page_size=8,
+    kw = dict(max_len=64, batch=2, prefill_chunk=4, page_size=8,
               num_pages=8)
     plain = _serve_step_fns(cfg, mesh, ServeConfig(**kw).step_statics())
     assert plain[3] is None  # no verify step without spec_decode
@@ -285,7 +285,7 @@ def test_ensure_pages_unwinds_partial_alloc_on_exhaustion():
     with mesh:
         sched = BatchScheduler(
             cfg, mesh,
-            ServeConfig(max_len=64, batch=1, prefill_chunk=4, paged=True,
+            ServeConfig(max_len=64, batch=1, prefill_chunk=4,
                         page_size=8, num_pages=8, preempt_policy="never",
                         spec_decode=True, spec_k=4),
             params,

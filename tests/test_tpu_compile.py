@@ -173,11 +173,11 @@ def _glm4_steps(one_chip):
     flags = _shape((B,), jnp.bool_, one_chip)
     keys = _shape((B, 2), jnp.uint32, one_chip)
     with compat.use_mesh(mesh):
-        decode = jax.jit(make_serve_decode_step(cfg, mesh, paged=True),
+        decode = jax.jit(make_serve_decode_step(cfg, mesh),
                          donate_argnums=(4,)).lower(
             params, i32(B, 1), i32(B), flags, caches, i32(B, n_logical), keys,
             flags).compile()
-        chunk = jax.jit(make_prefill_chunk_step(cfg, mesh, paged=True),
+        chunk = jax.jit(make_prefill_chunk_step(cfg, mesh),
                         donate_argnums=(5,)).lower(
             params, i32(1, CHUNK), i32(1), i32(1), i32(), caches,
             i32(B, n_logical), keys).compile()

@@ -90,7 +90,7 @@ def test_replay_end_to_end_under_pressure():
         with mesh:
             sched = BatchScheduler(
                 cfg, mesh,
-                ServeConfig(max_len=64, batch=2, prefill_chunk=4, paged=True,
+                ServeConfig(max_len=64, batch=2, prefill_chunk=4,
                             page_size=8, num_pages=num_pages), params,
             )
             metrics = replay(sched, workload)
@@ -149,7 +149,7 @@ def test_chaos_replay_composes_faults_with_workload():
         with mesh:
             sched = BatchScheduler(
                 cfg, mesh,
-                ServeConfig(max_len=64, batch=2, prefill_chunk=4, paged=True,
+                ServeConfig(max_len=64, batch=2, prefill_chunk=4,
                             page_size=8, num_pages=8), params,
             )
             m = replay(sched, workload,
